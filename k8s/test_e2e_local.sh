@@ -2,8 +2,7 @@
 # Docker-free end-to-end run: the REAL k8s/entrypoint.sh drives the REAL
 # CLI as two "pods", then k8s/assertions.sh is applied to the produced
 # logs and artifacts — the closest executable thing to k8s/test_e2e.sh on
-# a host with no Docker daemon (this image ships no docker/kind/kubectl;
-# see RESULTS.md "K8s E2E"). What is real here: the entrypoint's
+# a host with no Docker daemon (this image ships no docker/kind/kubectl). What is real here: the entrypoint's
 # JOB_COMPLETION_INDEX/NUM_PROCESSES contract, coordinator discovery
 # through the Kubernetes API codepath (curl + serviceaccount files —
 # stubbed at the network edge only), the 2-process JAX rendezvous, the
@@ -127,7 +126,7 @@ for IDX in 0 1; do
         LLMTRAIN_DISCOVERY_SLEEP=1 \
         JAX_PLATFORMS=cpu \
         XLA_FLAGS="--xla_force_host_platform_device_count=4" \
-        LLMTRAIN_COMPILATION_CACHE="${LLMTRAIN_COMPILATION_CACHE:-$HOME/.cache/llmtrain_tpu/jax-tests}" \
+        JAX_COMPILATION_CACHE_DIR="${JAX_COMPILATION_CACHE_DIR:-$PWD/.cache/jax-tests}" \
         PYTHONPATH="$PWD" \
         bash k8s/entrypoint.sh > "$OUT/logs/pod$IDX.log" 2>&1 &
     PIDS+=($!)

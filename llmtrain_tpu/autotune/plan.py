@@ -120,6 +120,10 @@ class ModelCaps:
     # unknown (pure unit tests constructing ModelCaps directly) — tier
     # specs then pass through unvalidated.
     n_layers: int = 0
+    # Resolved cross-entropy implementation (config_loss_impl): fused_ce
+    # contracts hidden states against the WHOLE [V, d] lm-head per token
+    # shard, so it cannot ride a vocab-sharding tensor axis.
+    loss_impl: str = "dense"
 
 
 def caps_from_config(cfg: Any, adapter: Any | None = None) -> ModelCaps:
@@ -127,6 +131,12 @@ def caps_from_config(cfg: Any, adapter: Any | None = None) -> ModelCaps:
     class/instance for the ``supports_pipeline`` flag — registry lookup is
     the caller's job so this module stays import-light)."""
     extra = dict(cfg.model.extra or {})
+    # Only adapters that read the knob can resolve to a streamed CE; the
+    # auto-selection must not charge e.g. dummy_gpt with fused_ce.
+    known = getattr(adapter, "known_extra_keys", None)
+    reads_loss_impl = "loss_impl" in extra or (
+        known is not None and "loss_impl" in known
+    )
     return ModelCaps(
         n_heads=int(cfg.model.n_heads),
         block_size=int(cfg.model.block_size),
@@ -136,6 +146,7 @@ def caps_from_config(cfg: Any, adapter: Any | None = None) -> ModelCaps:
         n_experts=int(extra.get("n_experts", 0) or 0),
         pipeline_microbatches=int(extra.get("pipeline_microbatches", 4) or 4),
         n_layers=int(cfg.model.n_layers),
+        loss_impl=config_loss_impl(cfg)[0] if reads_loss_impl else "dense",
     )
 
 
@@ -259,6 +270,9 @@ def resolve_plan(
     * ``expert > 1`` on a MoE model needs ``n_experts % expert == 0``
       (models/moe.py layout); on a dense model the axis only carries
       batch shards and is always legal;
+    * ``tensor > 1`` with the resolved ``loss_impl: fused_ce`` is
+      rejected: the Pallas kernel needs the whole ``[V, d]`` lm-head per
+      token shard and ``tensor`` shards its vocab (ops/fused_ce.py);
     * ``zero_stage`` in {0, 1, 2} (trainer.zero.stage).
     """
     axes = resolve_axis_sizes(mesh_sizes, device_count)
@@ -321,6 +335,14 @@ def resolve_plan(
                 f"model.extra.n_kv_heads ({caps.n_kv_heads}) must be "
                 f"divisible by the mesh tensor axis ({tp}) — K/V heads "
                 "shard over tensor parallelism like query heads do"
+            )
+        if caps.loss_impl == "fused_ce":
+            raise MeshPlanError(
+                "model.extra.loss_impl resolves to 'fused_ce' but the mesh "
+                f"tensor axis is {tp}: the fused lm-head + CE kernel "
+                "contracts each token shard against the whole [V, d] "
+                "matrix, which tensor parallelism shards over vocab — set "
+                "model.extra.loss_impl: chunked_ce (or dense), or tensor: 1"
             )
 
     # `expert` with a dense model is legal — the axis then only carries

@@ -1662,8 +1662,12 @@ def _build_decode_stack(cfg, logger, label: str = ""):
     so they stay bit-identical; raises with the actionable remediation
     when the model needs a vocab size the absent tokenizer would supply.
     """
+    from .distributed import resolve_devices
     from .models.lora import build_adapter
 
+    # Same rule as the Trainer: run.device names the platform, so an
+    # inference command never decodes on the CPU under the name "tpu".
+    resolve_devices(cfg.run.device)
     adapter = build_adapter(cfg)
     tokenizer = None
     try:
@@ -2710,6 +2714,7 @@ def _handle_serve_bench(args: argparse.Namespace) -> int:
             from .generation import generate
 
             mismatched = 0
+            mismatches: list[dict[str, Any]] = []
             for req in requests:
                 if req.finish_reason not in ("eos", "length"):
                     continue
@@ -2729,6 +2734,18 @@ def _handle_serve_bench(args: argparse.Namespace) -> int:
                     ref = ref[: ref.index(req.eos_token_id) + 1]
                 if ref != req.tokens:
                     mismatched += 1
+                    if len(mismatches) < 8:
+                        # Enough for a caller to judge the divergence at
+                        # the logits (chip_smoke.py); the check itself
+                        # stays bitwise.
+                        mismatches.append(
+                            {
+                                "request_id": req.request_id,
+                                "prompt_ids": [int(t) for t in req.prompt_ids],
+                                "served": list(req.tokens),
+                                "reference": ref,
+                            }
+                        )
                     logger.warning(
                         "parity mismatch on request %s: served %s != "
                         "generate() %s",
@@ -2742,6 +2759,8 @@ def _handle_serve_bench(args: argparse.Namespace) -> int:
                 "mismatched": mismatched,
                 "bitwise_identical": mismatched == 0 and checked > 0,
             }
+            if mismatches:
+                block["parity"]["mismatches"] = mismatches
             if mismatched:
                 failures.append(
                     f"{mismatched}/{checked} requests diverged from "

@@ -35,11 +35,12 @@ class RunSectionConfig(BaseModel):
     device: Literal["cpu", "tpu"] = "cpu"
     deterministic: bool = True
     notes: str | None = None
-    # Persistent JAX compilation-cache directory. None = the library
-    # default (~/.cache/llmtrain_tpu/jax); the LLMTRAIN_COMPILATION_CACHE
-    # env var overrides either (and "off" disables caching entirely) —
-    # see llmtrain_tpu.distributed.resolve_compilation_cache_dir. On k8s,
-    # point this (or the env var) at a mounted cache volume so
+    # Persistent JAX compilation-cache directory. None = the fixed
+    # in-checkout default (<repo>/.cache/jax). JAX's own
+    # JAX_COMPILATION_CACHE_DIR env var, where set, places the cache from
+    # outside and this field is ignored — see
+    # llmtrain_tpu.distributed.resolve_compilation_cache_dir. On k8s the
+    # manifests point that variable at a mounted cache volume so
     # podFailurePolicy retries skip the minutes-long recompile.
     compilation_cache_dir: str | None = None
 

@@ -15,10 +15,12 @@ config/activation_tiers.py for the grammar) onto flax block wrappers:
 
 Offload needs a ``pinned_host`` memory space on the backend.  The CPU
 emulation backend exposes only ``unpinned_host`` (which *is* device memory
-there), so :func:`resolve_activation_tiers` downgrades ``offload`` ->
-``full`` with a once-per-process warning — the same capability-probe
-discipline as ``trainer.zero.host_offload`` (parallel/sharding.py
-``host_memory_kind``) and ``resolve_matmul_precision`` (ops/quant.py).
+there), so OFF the chip :func:`resolve_activation_tiers` downgrades
+``offload`` -> ``full`` with a once-per-process warning — the same
+capability-probe discipline as ``trainer.zero.host_offload``
+(parallel/sharding.py ``host_memory_kind``) and
+``resolve_matmul_precision`` (ops/quant.py). On platform ``tpu`` a
+requested tier that cannot run is an error, never a downgrade.
 """
 
 from __future__ import annotations
@@ -53,10 +55,17 @@ def offload_supported() -> bool:
 
 
 def resolve_activation_tiers(tiers: tuple[str, ...]) -> tuple[str, ...]:
-    """Downgrade ``offload`` to ``full`` when the backend has no
-    ``pinned_host`` memory space, warning once per process."""
+    """Downgrade ``offload`` to ``full`` when an off-chip backend has no
+    ``pinned_host`` memory space, warning once per process; on platform
+    tpu the same gap raises."""
     if "offload" not in tiers or offload_supported():
         return tiers
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            "activation_tiers: 'offload' requested on platform tpu but the "
+            "device exposes no pinned_host memory space; use 'full' — a "
+            "requested tier is never swapped for another on the chip"
+        )
     if "offload" not in _FALLBACK_WARNED:
         _FALLBACK_WARNED.add("offload")
         n = sum(1 for t in tiers if t == "offload")

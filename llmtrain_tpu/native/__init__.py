@@ -3,8 +3,9 @@
 ``fastbpe`` accelerates the BPE tokenizer's cold-word merge loop
 (data/bpe.py) — the dominant cost when tokenizing high-entropy corpora
 (source code) where the Python per-word memo rarely hits. The shared
-object is compiled once per source hash with the host C compiler into
-``~/.cache/llmtrain_tpu/native/`` and loaded via ctypes; any failure
+object is compiled once per source hash with the host C compiler, from
+the tracked source into the checkout's git-ignored ``.cache/native/``,
+and loaded via ctypes; any failure
 (no compiler, sandboxed filesystem) silently falls back to the pure
 Python implementation, so nothing here is load-bearing for correctness.
 
@@ -27,10 +28,9 @@ _lib_tried = False
 
 
 def _cache_dir() -> Path:
-    root = os.environ.get("XDG_CACHE_HOME") or os.path.join(
-        os.path.expanduser("~"), ".cache"
-    )
-    return Path(root) / "llmtrain_tpu" / "native"
+    # Beside the compile cache (distributed.DEFAULT_COMPILATION_CACHE_DIR):
+    # nothing of this repo writes outside its checkout.
+    return _SRC.resolve().parents[2] / ".cache" / "native"
 
 
 def _compiler() -> str | None:

@@ -1,4 +1,4 @@
-"""Flash attention dispatch: Pallas kernels on TPU, blockwise everywhere.
+"""Flash attention dispatch: Pallas kernels on TPU, blockwise elsewhere.
 
 New TPU capability beyond the reference (full-matrix attention only,
 reference models/gpt.py:56-69). Training differentiates through a
@@ -13,6 +13,13 @@ reference models/gpt.py:56-69). Training differentiates through a
 Both paths are O(T) memory — no (T, T) materialization. Set
 ``LLMTRAIN_FLASH_BWD=blockwise`` to force the recompute backward on TPU
 (the A/B knob for benchmarking fused vs recompute).
+
+The platform decides, never the shape: on ``tpu`` a sequence length the
+kernels cannot tile is an error (no silent blockwise), and
+``resolved_attention_impl`` names what a run will execute for its report.
+On a mesh of more than one device the call wraps itself in ``shard_map``
+(batch over data×fsdp×expert, heads over tensor) — GSPMD cannot partition
+a Mosaic kernel — so each chip runs the kernel on its own shard.
 
 Key-padding masks are applied INSIDE attention on every path — flash
 here, ring/ulysses in their own modules — matching the reference
@@ -35,16 +42,17 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ..parallel.sharding import BATCH_AXES, kernel_mesh, shard_axes
 from .blockwise_attention import blockwise_attention
 
 
 def _auto_block(t: int) -> int | None:
     """Largest legal tile for sequence length ``t``.
 
-    512 measured fastest on v5e at GPT-2-small shapes (fwd 9.67 ms vs
-    10.10 at 256, bwd 11.93 vs 13.19 — RESULTS.md); smaller tiles keep odd
-    lengths like 384 or 768 on the Pallas path instead of falling back.
+    512 was fastest in older hand-taken v5e figures at GPT-2-small
+    shapes; smaller tiles keep lengths like 384 or 768 on the Pallas path.
     """
     for block in (512, 256, 128):
         if t >= block and t % block == 0:
@@ -53,7 +61,27 @@ def _auto_block(t: int) -> int | None:
 
 
 def _use_pallas(t: int) -> bool:
-    return jax.default_backend() == "tpu" and _auto_block(t) is not None
+    """Pallas on platform ``tpu``, blockwise off it. A length the kernels
+    cannot tile is an error on the chip, not a quiet change of path."""
+    if jax.default_backend() != "tpu":
+        return False
+    if _auto_block(t) is None:
+        raise ValueError(
+            f"attention 'flash' on platform tpu needs a sequence length "
+            f"that is a multiple of 128 (got T={t}); pad the sequence or "
+            "use attention: dense — the Pallas kernel is never swapped "
+            "for the blockwise reference on the chip"
+        )
+    return True
+
+
+def resolved_attention_impl(attention: str) -> str:
+    """What ``model.attention`` executes on this platform, for the run
+    report: ``flash`` is the Pallas kernels on tpu and the XLA blockwise
+    twin elsewhere; every other value runs as named."""
+    if attention != "flash":
+        return attention
+    return "pallas_flash" if jax.default_backend() == "tpu" else "blockwise"
 
 
 def _pallas_bwd_enabled() -> bool:
@@ -187,6 +215,21 @@ def flash_attention(
         if window:
             raise ValueError("sliding window requires causal attention")
         return blockwise_attention(q, k, v, causal=False, key_mask=attention_mask)
-    if attention_mask is None:
-        return _flash(int(window), q, k, v)
-    return _flash_masked(int(window), q, k, v, attention_mask.astype(jnp.float32))
+    fn = functools.partial(
+        _flash if attention_mask is None else _flash_masked, int(window)
+    )
+    args = (q, k, v)
+    if attention_mask is not None:
+        args += (attention_mask.astype(jnp.float32),)
+    mesh = kernel_mesh()
+    if mesh is None:
+        return fn(*args)
+    # Attention is independent per batch row and per head, so the shards
+    # need no collective: batch over the batch axes, heads over tensor
+    # (q and the possibly-narrower GQA k/v must both divide), full T.
+    batch = shard_axes(mesh, BATCH_AXES, q.shape[0])
+    spec = P(batch, None, shard_axes(mesh, ("tensor",), q.shape[2], k.shape[2]), None)
+    in_specs = (spec, spec, spec) + ((P(batch, None),) if len(args) == 4 else ())
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False
+    )(*args)

@@ -21,12 +21,19 @@ kernel that computes per-token CE (+ PaLM z-loss) directly from
 Neither pass ever writes a logits tile to HBM: the only [*, V]-shaped
 traffic left in the step is the weight matrix itself.
 
-Selection: ``model.extra.loss_impl: fused_ce`` (models/gpt.py). On a
-backend without Pallas TPU support the explicit knob degrades to
-chunked_ce with a once-per-process warning (the ``fp8_supported()``
-pattern from ops/quant.py); ``model.extra.pallas_interpret: true``
-forces the ``interpret=True`` emulation path so CPU runs — including
-tier-1 parity tests on this container — execute the real kernel logic.
+Selection: ``model.extra.loss_impl: fused_ce`` (models/gpt.py). OFF the
+chip the explicit knob degrades to chunked_ce with a once-per-process
+warning (the ``fp8_supported()`` pattern from ops/quant.py) and
+``model.extra.pallas_interpret: true`` forces the ``interpret=True``
+emulation path so CPU runs — including tier-1 parity tests — execute the
+real kernel logic. ON platform ``tpu`` nothing degrades: interpret mode
+is an error there, and the run report names the impl that executed.
+
+On a mesh of more than one device ``fused_ce_per_token`` wraps itself in
+``shard_map`` (tokens over the batch and sequence axes, the ``[V, d]``
+operand gathered, its gradient summed over the token shards) — GSPMD
+cannot partition a Mosaic kernel. A vocab-sharded lm-head (``tensor`` >
+1) is rejected at plan time (autotune/plan.py).
 
 Block sizes via ``model.extra.fused_ce_block_t`` / ``fused_ce_block_v``
 (defaults 256 / 512: a (512, d) f32 weight tile plus the (256, 512)
@@ -35,7 +42,6 @@ logits tile stay well under the ~16 MB/core VMEM budget up to d≈4k).
 
 from __future__ import annotations
 
-import functools
 import logging
 from functools import partial
 
@@ -43,6 +49,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from ..parallel.sharding import BATCH_AXES, kernel_mesh, shard_axes
 
 logger = logging.getLogger(__name__)
 
@@ -70,6 +79,18 @@ def pallas_ce_supported() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def check_pallas_interpret(interpret: bool) -> None:
+    """``model.extra.pallas_interpret`` exists so CPU runs execute the
+    kernel logic; on the chip it would run the emulation in place of the
+    compiled kernel under the kernel's name — an error, not a mode."""
+    if interpret and pallas_ce_supported():
+        raise ValueError(
+            "model.extra.pallas_interpret: true on platform tpu — interpret "
+            "mode is the CPU emulation of the Pallas kernels; remove the "
+            "key to run the compiled kernels on the chip"
+        )
+
+
 def resolve_loss_impl(
     requested: str | None,
     *,
@@ -79,15 +100,18 @@ def resolve_loss_impl(
 ) -> str:
     """The single selection authority for ``model.extra.loss_impl``.
 
-    Explicit knob always wins (unknown value raises); ``fused_ce`` on a
-    backend without Pallas support degrades to chunked_ce with a
-    once-per-process warning rather than failing the run (the
-    fp8-fallback contract from ops/quant.py). Unset auto-selects at
+    Explicit knob always wins (unknown value raises); ``fused_ce`` OFF
+    the chip without interpret mode degrades to chunked_ce with a
+    once-per-process warning (the fp8-fallback contract from
+    ops/quant.py) — on platform tpu the compiled kernel always runs and
+    interpret mode raises (:func:`check_pallas_interpret`). Unset
+    auto-selects at
     ``vocab_size >= ce_auto_vocab``: fused on TPU, chunked elsewhere.
     Used by the GPT adapter family at build time and by the autotune
     planner so `llmtrain plan` verdicts assume the same impl training
     will materialize.
     """
+    check_pallas_interpret(interpret)
     if requested is not None:
         if requested not in LOSS_IMPLS:
             raise ValueError(
@@ -283,21 +307,10 @@ def _forward(hidden, w_vocab, labels, block_t, block_v, compute_dtype, z_loss, i
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def fused_ce_per_token(
-    hidden: jax.Array,
-    w_vocab: jax.Array,
-    labels: jax.Array,
-    block_t: int = DEFAULT_BLOCK_T,
-    block_v: int = DEFAULT_BLOCK_V,
-    compute_dtype: jnp.dtype | None = None,
-    z_loss: float = 0.0,
-    interpret: bool = False,
-) -> jax.Array:
-    """Per-token CE loss, f32, shape (B, T) — drop-in for
-    ops/chunked_ce.py:chunked_ce_per_token, computed by the Pallas
-    kernels above. Same operand layout: ``w_vocab`` is (V, d) embedding
-    layout (tied ``token_embedding.embedding`` directly, untied
-    ``lm_head.kernel`` transposed)."""
+def _fused_ce_local(
+    hidden, w_vocab, labels, block_t, block_v, compute_dtype, z_loss, interpret
+):
+    """The kernels on ONE device's operands (differentiable)."""
     loss, _ = _forward(
         hidden, w_vocab, labels, block_t, block_v, compute_dtype, z_loss, interpret
     )
@@ -365,7 +378,46 @@ def _bwd(block_t, block_v, compute_dtype, z_loss, interpret, res, g):
     return dh, dw[:v].astype(w_vocab.dtype), None
 
 
-fused_ce_per_token.defvjp(_fwd, _bwd)
+_fused_ce_local.defvjp(_fwd, _bwd)
+
+
+def fused_ce_per_token(
+    hidden: jax.Array,
+    w_vocab: jax.Array,
+    labels: jax.Array,
+    block_t: int = DEFAULT_BLOCK_T,
+    block_v: int = DEFAULT_BLOCK_V,
+    compute_dtype: jnp.dtype | None = None,
+    z_loss: float = 0.0,
+    interpret: bool = False,
+) -> jax.Array:
+    """Per-token CE loss, f32, shape (B, T) — drop-in for
+    ops/chunked_ce.py:chunked_ce_per_token, computed by the Pallas
+    kernels above. Same operand layout: ``w_vocab`` is (V, d) embedding
+    layout (tied ``token_embedding.embedding`` directly, untied
+    ``lm_head.kernel`` transposed).
+
+    Under a multi-device mesh each chip runs the kernels on its own token
+    shard against the gathered ``w_vocab``; shard_map's transpose sums
+    ``dW`` over the token shards."""
+
+    def local(h, w, lab):
+        return _fused_ce_local(
+            h, w, lab, block_t, block_v, compute_dtype, z_loss, interpret
+        )
+
+    mesh = kernel_mesh()
+    if mesh is None:
+        return local(hidden, w_vocab, labels)
+    b, t = labels.shape
+    tok = P(shard_axes(mesh, BATCH_AXES, b), shard_axes(mesh, ("sequence",), t))
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(P(*tok, None), P(None, None), tok),
+        out_specs=tok,
+        check_vma=False,
+    )(hidden, w_vocab, labels)
 
 
 def fused_ce_components(
@@ -396,6 +448,7 @@ __all__ = [
     "fused_ce_per_token",
     "fused_ce_components",
     "resolve_loss_impl",
+    "check_pallas_interpret",
     "pallas_ce_supported",
     "LOSS_IMPLS",
     "DEFAULT_BLOCK_T",

@@ -22,7 +22,10 @@ costs nothing extra.
 
 Wired per-block in models/gpt.py behind ``model.extra.fused_norm``;
 ``model.extra.pallas_interpret: true`` runs the emulated kernel on CPU
-(tier-1 parity tests). Parameter names/shapes match ``nn.LayerNorm``
+(tier-1 parity tests) and is an error on platform tpu. On a mesh of more
+than one device both entry points wrap themselves in ``shard_map``
+(tokens over the batch and sequence axes, ``scale``/``bias`` gathered,
+their gradients summed over the token shards). Parameter names/shapes match ``nn.LayerNorm``
 (``scale``/``bias`` of shape (d,)) so checkpoints are interchangeable
 with the unfused path.
 """
@@ -35,6 +38,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from ..parallel.sharding import BATCH_AXES, kernel_mesh, shard_axes
 
 logger = logging.getLogger(__name__)
 
@@ -43,11 +49,13 @@ _FALLBACK_WARNED: set[str] = set()
 
 
 def resolve_fused_norm(requested: bool, *, interpret: bool = False) -> bool:
-    """fp8-style degrade: fused_norm on a backend without Pallas TPU
-    support silently (warn-once) reverts to the unfused nn.LayerNorm
-    path instead of failing the run."""
-    from .fused_ce import pallas_ce_supported
+    """fp8-style degrade OFF the chip: fused_norm without Pallas TPU
+    support (warn-once) reverts to the unfused nn.LayerNorm path. On
+    platform tpu the compiled kernel always runs and interpret mode
+    raises (ops/fused_ce.py:check_pallas_interpret)."""
+    from .fused_ce import check_pallas_interpret, pallas_ce_supported
 
+    check_pallas_interpret(interpret)
     if requested and not (pallas_ce_supported() or interpret):
         if "fused_norm" not in _FALLBACK_WARNED:
             _FALLBACK_WARNED.add("fused_norm")
@@ -181,16 +189,7 @@ def _run_backward(s2, scale, mu, rstd, gy, shape, n, eps, block_t, interpret):
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def fused_layer_norm(
-    x: jax.Array,
-    scale: jax.Array,
-    bias: jax.Array,
-    eps: float = 1e-6,
-    block_t: int = DEFAULT_BLOCK_T,
-    interpret: bool = False,
-) -> jax.Array:
-    """LayerNorm over the last axis — the no-residual flavor (block
-    input norm ln_1 / final ln_f sites)."""
+def _layer_norm_local(x, scale, bias, eps, block_t, interpret):
     _, _, y, _, _, _ = _run_forward(x, None, scale, bias, eps, block_t, interpret)
     return y
 
@@ -212,22 +211,11 @@ def _ln_bwd(eps, block_t, interpret, res, gy):
     return dx.astype(gy.dtype), dsc, db.astype(scale.dtype)
 
 
-fused_layer_norm.defvjp(_ln_fwd, _ln_bwd)
+_layer_norm_local.defvjp(_ln_fwd, _ln_bwd)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def fused_add_layer_norm(
-    x: jax.Array,
-    residual: jax.Array,
-    scale: jax.Array,
-    bias: jax.Array,
-    eps: float = 1e-6,
-    block_t: int = DEFAULT_BLOCK_T,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """``(LN(x + residual), x + residual)`` in one HBM pass — the
-    post-attention pre-MLP site: the first output feeds the sublayer,
-    the second is the updated residual stream."""
+def _add_layer_norm_local(x, residual, scale, bias, eps, block_t, interpret):
     _, _, y, s, _, _ = _run_forward(x, residual, scale, bias, eps, block_t, interpret)
     return y, s
 
@@ -253,7 +241,67 @@ def _aln_bwd(eps, block_t, interpret, res, g):
     return dx, dx, dsc, db.astype(scale.dtype)
 
 
-fused_add_layer_norm.defvjp(_aln_fwd, _aln_bwd)
+_add_layer_norm_local.defvjp(_aln_fwd, _aln_bwd)
+
+
+def _token_sharded(local, n_act: int, *args):
+    """Run ``local(*acts, scale, bias)`` per token shard under the ambient
+    mesh: the leading ``n_act`` (B, T, d) activations — and as many
+    outputs — shard over the batch/sequence axes, the (d,) params arrive
+    gathered and shard_map's transpose sums their gradients over the
+    token shards."""
+    mesh = kernel_mesh()
+    x = args[0]
+    if mesh is None or x.ndim != 3:
+        return local(*args)
+    tok = P(
+        shard_axes(mesh, BATCH_AXES, x.shape[0]),
+        shard_axes(mesh, ("sequence",), x.shape[1]),
+        None,
+    )
+    return jax.shard_map(
+        local,
+        mesh=mesh,
+        in_specs=(tok,) * n_act + (P(None), P(None)),
+        out_specs=tok if n_act == 1 else (tok,) * n_act,
+        check_vma=False,
+    )(*args)
+
+
+def fused_layer_norm(
+    x: jax.Array,
+    scale: jax.Array,
+    bias: jax.Array,
+    eps: float = 1e-6,
+    block_t: int = DEFAULT_BLOCK_T,
+    interpret: bool = False,
+) -> jax.Array:
+    """LayerNorm over the last axis — the no-residual flavor (block
+    input norm ln_1 / final ln_f sites)."""
+    return _token_sharded(
+        lambda x_, s_, b_: _layer_norm_local(x_, s_, b_, eps, block_t, interpret),
+        1, x, scale, bias,
+    )
+
+
+def fused_add_layer_norm(
+    x: jax.Array,
+    residual: jax.Array,
+    scale: jax.Array,
+    bias: jax.Array,
+    eps: float = 1e-6,
+    block_t: int = DEFAULT_BLOCK_T,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """``(LN(x + residual), x + residual)`` in one HBM pass — the
+    post-attention pre-MLP site: the first output feeds the sublayer,
+    the second is the updated residual stream."""
+    return _token_sharded(
+        lambda x_, r_, s_, b_: _add_layer_norm_local(
+            x_, r_, s_, b_, eps, block_t, interpret
+        ),
+        2, x, residual, scale, bias,
+    )
 
 
 __all__ = [

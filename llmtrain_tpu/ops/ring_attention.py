@@ -140,23 +140,12 @@ def attention_shard_map(
         specs.append(
             P(_ax(dim_axes[0]), None if mask_replicated else _ax(dim_axes[1]))
         )
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            local_fn,
-            mesh=mesh,
-            in_specs=tuple(specs),
-            out_specs=spec,
-            check_vma=False,
-        )
-    # jax < 0.5: top-level alias and the check_vma spelling don't exist yet.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    return jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=tuple(specs),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -84,8 +84,8 @@ def ulysses_attention(
         # Narrow K/V: q and the stacked k/v exchange separately — two
         # collectives moving H + 2*Hkv head-widths instead of one moving
         # 3*H. Fewer bytes for any group factor > 1, at the cost of one
-        # extra collective's latency; taken unconditionally (unmeasured
-        # on ICI — see RESULTS.md pending list).
+        # extra collective's latency; taken unconditionally (on chip: not
+        # measured).
         qh = jax.lax.all_to_all(q, axis_name, split_axis=2, concat_axis=1, tiled=True)
         kv = jnp.stack((k, v))  # (2, B, T_local, Hkv, D)
         kv = jax.lax.all_to_all(

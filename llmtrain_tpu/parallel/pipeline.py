@@ -30,14 +30,10 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.8
-    from jax.experimental.shard_map import shard_map
-
-BATCH_AXES = ("data", "fsdp", "expert")
+from .sharding import BATCH_AXES
 
 
 def pipeline_degree(mesh: jax.sharding.Mesh | None) -> int:
@@ -230,12 +226,7 @@ def gpipe_apply(
         # The carry varies over `axis` (each stage computes different
         # values), but the zero init doesn't — declare it varying so the
         # scan carry types line up under shard_map's vma tracking.
-        if hasattr(jax.lax, "pcast"):
-            mark_varying = lambda a: jax.lax.pcast(a, (axis,), to="varying")  # noqa: E731
-        elif hasattr(jax.lax, "pvary"):  # older jax spells it pvary
-            mark_varying = lambda a: jax.lax.pvary(a, (axis,))  # noqa: E731
-        else:  # pre-vma jax (< 0.5): no varying-type tracking to satisfy
-            mark_varying = lambda a: a  # noqa: E731
+        mark_varying = lambda a: jax.lax.pcast(a, (axis,), to="varying")  # noqa: E731
         # v == 1 never banks (round 0 reads fresh microbatches only), so the
         # return buffer shrinks to one slot; out-of-range dynamic indices
         # clamp per XLA semantics and the clamped reads are never selected.

@@ -18,6 +18,7 @@ the physical mesh axes (data/fsdp/tensor/sequence/pipeline/expert):
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -73,6 +74,44 @@ def ambient_mesh() -> Mesh | None:
     return None if physical.empty else physical
 
 
+# Mesh axes the activation ``batch`` dim shards over (the "batch" rule
+# above) — also what token-wise Pallas kernels shard their rows over.
+BATCH_AXES = ("data", "fsdp", "expert")
+
+
+def kernel_mesh() -> Mesh | None:
+    """The ambient mesh a Pallas call must be ``shard_map``-ped over, or
+    None when the bare call is right.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so inside a jitted step on a mesh of
+    more than one device every kernel call site wraps itself in
+    ``jax.shard_map`` over the axes its operands are sharded on and each
+    chip runs the kernel on its own shard only. None on a one-device
+    mesh, outside any ``with mesh:``, and inside an enclosing shard_map
+    (pipeline stages, ring/ulysses bodies), where operands already are
+    per-shard.
+    """
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    if jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return mesh
+
+
+def shard_axes(mesh: Mesh, axes: tuple[str, ...], *dims: int):
+    """PartitionSpec entry sharding a dim over those of ``axes`` the mesh
+    actually splits (size > 1) — or None (replicated) when there are none
+    or any of ``dims`` does not divide by their product. The (1, T)
+    param-init probe batch is the designed replicated case."""
+    used = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+    n = math.prod(mesh.shape[a] for a in used)
+    if not used or any(d % n for d in dims):
+        return None
+    return used if len(used) > 1 else used[0]
+
+
 def data_parallel_degree(mesh: Mesh) -> int:
     """Number of batch shards = product of the axes 'batch' maps onto.
 
@@ -85,10 +124,9 @@ def data_parallel_degree(mesh: Mesh) -> int:
 
 def batch_sharding(mesh: Mesh, *, with_accum_dim: bool = False) -> NamedSharding:
     """Sharding for (accum, B, T) or (B, T) token batches."""
-    batch_axes = ("data", "fsdp", "expert")
     if with_accum_dim:
-        return NamedSharding(mesh, P(None, batch_axes, "sequence"))
-    return NamedSharding(mesh, P(batch_axes, "sequence"))
+        return NamedSharding(mesh, P(None, BATCH_AXES, "sequence"))
+    return NamedSharding(mesh, P(BATCH_AXES, "sequence"))
 
 
 # Leaves whose unsatisfiable sharding spec was already repaired (and warned
@@ -167,7 +205,7 @@ def state_shardings(mesh: Mesh, abstract_tree: Any, rules=DEFAULT_LOGICAL_AXIS_R
 
 # Axes whose product is the data-parallel degree — the replicas that hold
 # redundant optimizer-state copies, i.e. the ZeRO partitioning dimension.
-ZERO_PARTITION_AXES = ("data", "fsdp", "expert")
+ZERO_PARTITION_AXES = BATCH_AXES
 
 
 def opt_state_shardings(
@@ -266,9 +304,10 @@ def _leaf_size(shape: tuple) -> int:
 def host_memory_kind(mesh: Mesh) -> str | None:
     """``"pinned_host"`` when the mesh devices expose a host memory space
     jit shardings can target (TPU backends with the memories API), else
-    None — callers fall back to an explicit host round-trip. The CPU
-    backend only exposes ``unpinned_host``, which IS device memory there,
-    so offloading to it would be a no-op pretending otherwise."""
+    None — callers fall back to an explicit host round-trip.
+    ``unpinned_host`` alone does not count: on the CPU backend it IS device
+    memory, so offloading to it would be a no-op pretending otherwise (the
+    installed jax 0.9.0 gives the CPU backend a ``pinned_host`` space too)."""
     try:
         device = mesh.devices.flat[0]
         kinds = {m.kind for m in device.addressable_memories()}

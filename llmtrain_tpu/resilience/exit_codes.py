@@ -97,6 +97,7 @@ def exit_code_for_exception(exc: BaseException) -> int:
     """
     # Local imports: keep this module importable without jax/pydantic.
     from ..autotune.plan import MeshPlanError
+    from ..distributed import PlatformError
     from .elastic import TopologyMismatchError
     from .faults import InjectedFault
     from .guard import NonFiniteLossError
@@ -108,7 +109,9 @@ def exit_code_for_exception(exc: BaseException) -> int:
         # burn restarts on it. An infeasible mesh plan (axis sizes vs
         # device count / capability rules, autotune/plan.py) is the same
         # class: deterministic from config, restarting cannot help.
-        if isinstance(node, (TopologyMismatchError, MeshPlanError)):
+        # So is run.device naming a platform JAX did not select (no chip
+        # on this machine): the same pod on the same node replays it.
+        if isinstance(node, (TopologyMismatchError, MeshPlanError, PlatformError)):
             return EXIT_CONFIG_ERROR
     for node in _exception_chain(exc):
         # Deterministic divergence beats any wrapped transient error.

@@ -2,7 +2,7 @@
 
 PR 4's telemetry stack measures *wall-clock* (timeline spans, step times,
 HBM highwater) but attributes nothing against the hardware's peak — so
-"0.48 MFU" (RESULTS.md) cannot answer *why not 0.6*: is the step compute-,
+"0.48 MFU" cannot answer *why not 0.6*: is the step compute-,
 memory-, or comms-bound?  This module closes that gap with the accounting
 discipline Megatron-LM uses to make MFU claims defensible (Narayanan et
 al., arXiv:2104.04473):
@@ -78,26 +78,30 @@ def resolve_peaks(
     """Peak figures for ``device_kind`` (substring match, like
     utils/hw.py), with config overrides merged on top.
 
-    ``device_kind`` None reads the first local jax device; any lookup
-    failure falls back to the cpu row — attribution must degrade, never
-    raise.
+    ``device_kind`` None reads the first local jax device. Off the chip
+    an unmatched kind takes the nominal cpu row; on platform ``tpu`` (or
+    for any kind that names a TPU) an unknown kind raises — a made-up
+    peak would make every roofline share derived from it fiction.
     """
-    kind = device_kind
-    if kind is None:
-        try:
-            import jax
+    import jax
 
-            kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001 — no backend is a degraded profile, not an error
-            kind = "cpu"
-    kind = (kind or "cpu").lower()
-    peaks = dict(DEVICE_PEAKS["cpu"])
+    platform = jax.default_backend() if device_kind is None else None
+    kind = (
+        jax.devices()[0].device_kind if device_kind is None else device_kind
+    ).lower()
     # Longest matching key wins so "v5 lite" beats "v5" styles of kind.
-    best = ""
-    for key, row in DEVICE_PEAKS.items():
-        if key in kind and len(key) > len(best):
-            best = key
-            peaks = dict(row)
+    best = max(
+        (key for key in DEVICE_PEAKS if key in kind), key=len, default=None
+    )
+    if best is None:
+        if platform == "tpu" or "tpu" in kind:
+            raise ValueError(
+                f"no peaks known for TPU device_kind {kind!r}; add its row "
+                "to telemetry/profiling.py DEVICE_PEAKS "
+                f"(known: {sorted(DEVICE_PEAKS)})"
+            )
+        best = "cpu"
+    peaks = dict(DEVICE_PEAKS[best])
     peaks["device_kind"] = kind  # type: ignore[assignment]
     for key in _PEAK_KEYS:
         if overrides and key in overrides and overrides[key]:

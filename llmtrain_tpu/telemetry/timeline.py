@@ -47,28 +47,18 @@ logger = get_logger()
 
 
 def step_annotation(step: int, *, enabled: bool = True):
-    """``jax.profiler.StepTraceAnnotation`` for optimizer step ``step``.
-
-    Best-effort: profiling alignment must never be able to kill a step, so
-    any failure (old jax, no profiler backend) degrades to a nullcontext.
-    """
+    """``jax.profiler.StepTraceAnnotation`` for optimizer step ``step``."""
     if not enabled:
         return nullcontext()
-    try:
-        import jax
+    import jax
 
-        return jax.profiler.StepTraceAnnotation("train", step_num=step)
-    except Exception:  # noqa: BLE001 — alignment is optional, training is not
-        return nullcontext()
+    return jax.profiler.StepTraceAnnotation("train", step_num=step)
 
 
 def _trace_annotation(name: str):
-    try:
-        import jax
+    import jax
 
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        return nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 class EventTimeline:

@@ -36,7 +36,7 @@ from flax.linen import meta as nn_meta
 from ..config.schemas import RunConfig
 from ..data.prefetch import BatchPrefetcher
 from ..data.sampler import DeterministicSampler
-from ..distributed import DistState, build_mesh
+from ..distributed import DistState, build_mesh, resolve_devices
 from ..parallel.sharding import (
     DEFAULT_LOGICAL_AXIS_RULES,
     batch_sharding,
@@ -186,7 +186,17 @@ class Trainer:
 
         self._model = self._adapter.build_model(cfg)
 
-        devices = jax.devices() if cfg.run.device == "tpu" else jax.devices("cpu")
+        devices = resolve_devices(cfg.run.device)
+        # A fully explicit mesh smaller than a single-process host takes
+        # the leading devices (one chip of a four-chip host); a wildcard
+        # axis, or several processes, still tile every device.
+        mesh_product = math.prod(cfg.distributed.mesh.axis_sizes().values())
+        if 0 < mesh_product < len(devices) and jax.process_count() == 1:
+            logger.warning(
+                "mesh uses %d of this host's %d %s device(s)",
+                mesh_product, len(devices), cfg.run.device,
+            )
+            devices = devices[:mesh_product]
         # Fail-fast plan validation (autotune/plan.py): axis tiling,
         # capability flags and divisibility rules all raise a named
         # MeshPlanError (config exit code 2) here, BEFORE any mesh or
@@ -1107,9 +1117,7 @@ class Trainer:
                         # interval's last loss BEFORE stamping the end time
                         # so queued execution is charged to this interval.
                         # Without this, step_time measures dispatch only and
-                        # tokens_per_sec/mfu are nonsense. (device_get, not
-                        # block_until_ready: on remote-tunnel platforms the
-                        # latter can return before execution finishes.)
+                        # tokens_per_sec/mfu are nonsense.
                         with tl.span("interval_sync", step=step):
                             losses_host = np.asarray(
                                 jax.device_get(jnp.stack(interval_losses))
@@ -1296,14 +1304,25 @@ class Trainer:
         return result
 
     def _precision_block(self) -> dict[str, Any]:
-        """Numerics provenance for report.json: the EFFECTIVE values the
-        model compiled with (post auto-selection / capability fallback),
-        read off the built module — not the raw config keys."""
+        """Provenance for report.json: the implementations and the device
+        that actually EXECUTED (post auto-selection / capability
+        resolution), read off the built module and the mesh — not the raw
+        config keys. chip_smoke.py asserts on these."""
+        from ..ops.flash_attention import resolved_attention_impl
+
+        device = self._mesh.devices.flat[0]
         return {
             "dtype": str(self._cfg.model.dtype),
             "param_dtype": str(self._cfg.model.param_dtype),
             "loss_impl": getattr(self._model, "loss_impl", "dense"),
+            "attention_impl": resolved_attention_impl(
+                str(getattr(self._model, "attention", self._cfg.model.attention))
+            ),
+            "fused_norm": bool(getattr(self._model, "fused_norm", False)),
             "matmul_precision": getattr(self._model, "matmul_precision", "f32"),
+            "platform": device.platform,
+            "device_kind": device.device_kind,
+            "device_count": int(self._mesh.devices.size),
         }
 
     def _probe_seqlen(self, dataset) -> int:

@@ -434,11 +434,13 @@ class TestFailFast:
     def test_trainer_fails_fast_on_untileable_mesh(self):
         # Regression: a mesh that cannot tile the device count must die as
         # a named MeshPlanError during trainer setup, before any mesh or
-        # params materialize — not as an opaque pjit/XLA error later.
+        # params materialize — not as an opaque pjit/XLA error later. (A
+        # fully explicit mesh SMALLER than the host is legal: it takes the
+        # leading devices — tests/test_platform_rules.py.)
         from llmtrain_tpu.tracking import NullTracker
         from llmtrain_tpu.training import Trainer
 
-        cfg = _cfg(distributed={"mesh": {"data": 3}})
+        cfg = _cfg(distributed={"mesh": {"data": 16}})
         with pytest.raises(MeshPlanError, match="devices"):
             Trainer(cfg, None, NullTracker(), None)
 

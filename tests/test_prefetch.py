@@ -460,22 +460,24 @@ class TestWatchdogCatchesPrefetcherHang:
 
 
 class TestCompilationCacheResolution:
-    def test_env_beats_config_beats_default(self, monkeypatch):
-        monkeypatch.setenv("LLMTRAIN_COMPILATION_CACHE", "/from/env")
+    """Precedence only; the set-nothing-in-code rule and the fixed
+    in-checkout default are pinned in tests/test_platform_rules.py."""
+
+    def test_jax_env_beats_config_beats_default(self, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/from/env")
         assert resolve_compilation_cache_dir("/from/config") == "/from/env"
-        monkeypatch.delenv("LLMTRAIN_COMPILATION_CACHE")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert resolve_compilation_cache_dir("/from/config") == "/from/config"
         default = resolve_compilation_cache_dir(None)
-        assert default is not None and default.endswith(os.path.join("llmtrain_tpu", "jax"))
+        assert default.endswith(os.path.join(".cache", "jax"))
 
-    def test_env_off_disables_even_with_config_dir(self, monkeypatch):
-        monkeypatch.setenv("LLMTRAIN_COMPILATION_CACHE", "off")
-        assert resolve_compilation_cache_dir("/from/config") is None
+    def test_retired_private_env_var_is_ignored(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setenv("LLMTRAIN_COMPILATION_CACHE", "/from/private")
+        assert resolve_compilation_cache_dir("/from/config") == "/from/config"
 
-    def test_boolish_enable_uses_config_dir(self, monkeypatch):
-        """on/1/true mean "enable", not "a directory named true" — with a
-        config dir present they resolve to it."""
-        monkeypatch.setenv("LLMTRAIN_COMPILATION_CACHE", "on")
+    def test_empty_env_counts_as_unset(self, monkeypatch):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "")
         assert resolve_compilation_cache_dir("/from/config") == "/from/config"
 
     def test_run_section_accepts_cache_dir(self):

@@ -1,0 +1,247 @@
+"""The chip's compiler, without the chip: the main path's Pallas kernels
+at GPT-2-small widths compiled for a *described* ``v5e:2x2`` topology.
+
+Interpret-mode tests validate kernel math; only the TPU compiler refuses
+a slice not aligned to the tiling, a kernel over its VMEM budget, or a
+Mosaic call GSPMD is asked to partition ("Mosaic kernels cannot be
+automatically partitioned" — what ``attention: flash`` on a mesh raised
+before the kernels wrapped themselves in ``shard_map``). Nothing runs:
+shapes in, an executable out, ``as_text()`` checked for the custom call.
+
+The topology is described INSIDE a module-scoped fixture, never at import
+(only one process may load libtpu; xdist workers all import this file),
+and every compile happens in the test's own process. This is the only
+test file that describes a TPU topology.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+B, T, H, D = 8, 512, 12, 64
+D_MODEL, VOCAB = 768, 50257
+BLOCK_T, BLOCK_V = 256, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no libtpu / lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {exc}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """``{data: 2, fsdp: 2}`` over the four described chips, with the
+    trainer's full axis-name set (size-1 axes included)."""
+    from llmtrain_tpu.distributed import MESH_AXES
+
+    devices = np.array(topo.devices).reshape(2, 2, 1, 1, 1, 1)
+    return Mesh(devices, MESH_AXES)
+
+
+@pytest.fixture(autouse=True)
+def _as_on_chip(monkeypatch):
+    """Steer the kernel dispatch onto its platform-``tpu`` branch (the
+    process's real backend is the CPU) and keep these compiles out of the
+    persistent cache: an entry written for a described chip cannot be read
+    back without one and would warn on every later run."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _qkv(sharding, *, kv_heads=H):
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, T, kv_heads, D), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+def _flash_loss(window=0):
+    from llmtrain_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v, mask=None):
+        out = flash_attention(q, k, v, attention_mask=mask, window=window)
+        return jnp.sum(out.astype(jnp.float32))
+
+    return loss
+
+
+class TestFlashAttentionOneChip:
+    def test_forward(self, one_chip):
+        from llmtrain_tpu.ops.flash_attention import flash_attention
+
+        assert "tpu_custom_call" in _compile(flash_attention, *_qkv(one_chip))
+
+    def test_forward_backward(self, one_chip):
+        text = _compile(jax.grad(_flash_loss(), argnums=(0, 1, 2)), *_qkv(one_chip))
+        # fwd + the two fused backward kernels (dq, dk/dv).
+        assert text.count("tpu_custom_call") >= 3
+
+    def test_masked_forward_backward(self, one_chip):
+        mask = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip)
+        text = _compile(
+            jax.grad(_flash_loss(), argnums=(0, 1, 2)), *_qkv(one_chip), mask
+        )
+        assert text.count("tpu_custom_call") >= 3
+
+    def test_gqa_sliding_window_forward_backward(self, one_chip):
+        text = _compile(
+            jax.grad(_flash_loss(window=256), argnums=(0, 1, 2)),
+            *_qkv(one_chip, kv_heads=4),
+        )
+        assert text.count("tpu_custom_call") >= 3
+
+    def test_untileable_length_is_an_error_not_blockwise(self, one_chip):
+        from llmtrain_tpu.ops.flash_attention import flash_attention
+
+        q = jax.ShapeDtypeStruct((B, 200, H, D), jnp.bfloat16, sharding=one_chip)
+        with pytest.raises(ValueError, match="multiple of 128"):
+            jax.jit(flash_attention).lower(q, q, q)
+
+
+class TestFusedCEOneChip:
+    @staticmethod
+    def _operands(sharding, dtype):
+        h = jax.ShapeDtypeStruct((B, T, D_MODEL), dtype, sharding=sharding)
+        w = jax.ShapeDtypeStruct((VOCAB, D_MODEL), dtype, sharding=sharding)
+        lab = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=sharding)
+        return h, w, lab
+
+    @staticmethod
+    def _loss(h, w, lab):
+        from llmtrain_tpu.ops.fused_ce import fused_ce_per_token
+
+        return jnp.sum(fused_ce_per_token(h, w, lab, BLOCK_T, BLOCK_V))
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+    def test_forward(self, one_chip, dtype):
+        assert "tpu_custom_call" in _compile(self._loss, *self._operands(one_chip, dtype))
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+    def test_grad(self, one_chip, dtype):
+        text = _compile(
+            jax.grad(self._loss, argnums=(0, 1)), *self._operands(one_chip, dtype)
+        )
+        # fwd + dhidden + dW kernels.
+        assert text.count("tpu_custom_call") >= 3
+
+
+class TestFusedNormOneChip:
+    @staticmethod
+    def _operands(sharding):
+        x = jax.ShapeDtypeStruct((B, T, D_MODEL), jnp.bfloat16, sharding=sharding)
+        p = jax.ShapeDtypeStruct((D_MODEL,), jnp.float32, sharding=sharding)
+        return x, p
+
+    def test_layer_norm_grad(self, one_chip):
+        from llmtrain_tpu.ops.fused_norm import fused_layer_norm
+
+        def loss(x, scale, bias):
+            return jnp.sum(fused_layer_norm(x, scale, bias).astype(jnp.float32))
+
+        x, p = self._operands(one_chip)
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, p, p)
+        assert text.count("tpu_custom_call") >= 2
+
+    def test_add_layer_norm_grad(self, one_chip):
+        from llmtrain_tpu.ops.fused_norm import fused_add_layer_norm
+
+        def loss(x, res, scale, bias):
+            y, s = fused_add_layer_norm(x, res, scale, bias)
+            return jnp.sum(y.astype(jnp.float32)) + jnp.sum(s.astype(jnp.float32))
+
+        x, p = self._operands(one_chip)
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, p, p)
+        assert text.count("tpu_custom_call") >= 2
+
+
+class TestKernelsOnFourChipMesh:
+    """The tests that would have caught ``attention: flash`` never having
+    compiled on a TPU mesh: batch-sharded operands inside a GSPMD-jitted
+    function, the kernels partitioned by their own ``shard_map``."""
+
+    def test_flash_forward_backward_partitions(self, mesh4):
+        batch = NamedSharding(mesh4, P(("data", "fsdp")))
+        with mesh4:
+            text = _compile(jax.grad(_flash_loss(), argnums=(0, 1, 2)), *_qkv(batch))
+        assert text.count("tpu_custom_call") >= 3
+        # Each chip's kernel sees its own batch shard only: B/4 rows.
+        assert f"bf16[{B // 4},{T},{H},{D}]" in text
+
+    def test_masked_flash_partitions(self, mesh4):
+        batch = NamedSharding(mesh4, P(("data", "fsdp")))
+        mask = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=batch)
+        with mesh4:
+            text = _compile(
+                jax.grad(_flash_loss(), argnums=(0, 1, 2)), *_qkv(batch), mask
+            )
+        assert text.count("tpu_custom_call") >= 3
+
+    def test_fused_ce_grad_partitions_and_sums_dw(self, mesh4):
+        tokens = NamedSharding(mesh4, P(("data", "fsdp")))
+        h = jax.ShapeDtypeStruct((B, T, D_MODEL), jnp.bfloat16, sharding=tokens)
+        # The tied embedding's training layout: vocab→tensor, embed→fsdp.
+        w = jax.ShapeDtypeStruct(
+            (VOCAB, D_MODEL), jnp.bfloat16,
+            sharding=NamedSharding(mesh4, P("tensor", "fsdp")),
+        )
+        lab = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=tokens)
+        with mesh4:
+            compiled = (
+                jax.jit(jax.grad(TestFusedCEOneChip._loss, argnums=(0, 1)))
+                .lower(h, w, lab)
+                .compile()
+            )
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 3
+        # Per-chip token shard: (B/4)*T rows of d_model into the kernel.
+        assert f"bf16[{B // 4 * T},{D_MODEL}]" in text
+        # dW is summed over the token shards, the fsdp-sharded operand
+        # gathered: both collectives must be in the program.
+        assert "all-reduce" in text or "reduce-scatter" in text
+        assert "all-gather" in text
+        assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+    def test_fused_add_layer_norm_grad_partitions(self, mesh4):
+        from llmtrain_tpu.ops.fused_norm import fused_add_layer_norm
+
+        def loss(x, res, scale, bias):
+            y, s = fused_add_layer_norm(x, res, scale, bias)
+            return jnp.sum(y.astype(jnp.float32)) + jnp.sum(s.astype(jnp.float32))
+
+        tokens = NamedSharding(mesh4, P(("data", "fsdp")))
+        x = jax.ShapeDtypeStruct((B, T, D_MODEL), jnp.bfloat16, sharding=tokens)
+        p = jax.ShapeDtypeStruct(
+            (D_MODEL,), jnp.float32, sharding=NamedSharding(mesh4, P("fsdp"))
+        )
+        with mesh4:
+            text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, p, p)
+        assert text.count("tpu_custom_call") >= 2
+        assert f"bf16[{B // 4 * T},{D_MODEL}]" in text

@@ -137,7 +137,7 @@ class TestHW:
         assert abs(got - 0.1) < 1e-12
 
     def test_headline_run_mfu_reproduces(self):
-        """RESULTS.md's headline numbers cross-check: the 85.6M byte-level
+        """An older hand-taken figure as arithmetic cross-check: the 85.6M byte-level
         GPT at the measured 165.8k tokens/s gives the recorded 0.48 MFU on
         v5e peak."""
         from llmtrain_tpu.utils.hw import TPU_PEAK_FLOPS, mfu

@@ -1,7 +1,7 @@
 """Decode per-token cost attribution by ablation (VERDICT r3 #4).
 
 The chip decode curve is nearly batch-flat (4.4-5.0 ms/token for MHA at
-batch 1/8/32, chip_evidence_r4/decode.json), i.e. dominated by a
+batch 1/8/32, older hand-taken chip figures), i.e. dominated by a
 batch-independent term. Rather than eyeballing a profiler trace, this
 tool attributes the per-token cost by differencing ablations of the REAL
 decode path (generation.generate, one-scan KV decode):

@@ -17,7 +17,10 @@ from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
 PACKAGE = "llmtrain_tpu"
-LINT_ROOTS = [REPO / PACKAGE, REPO / "tests", REPO / "bench.py", REPO / "__graft_entry__.py"]
+LINT_ROOTS = [
+    REPO / PACKAGE, REPO / "tests", REPO / "bench.py", REPO / "chip_smoke.py",
+    REPO / "__graft_entry__.py",
+]
 
 # Names imported for re-export or side effects (registry self-registration).
 ALLOW_UNUSED_IN = {"__init__.py"}
