@@ -1,0 +1,4 @@
+"""engine (paged KV): positions the decode rows attend over positions the
+decode program gathers, from the decode calls' ``serve/engine.stage`` counters (lib/span_tree.py)."""
+
+from benchmarks.lib.span_tree import kv_read_useful_share as read  # noqa: F401

@@ -1,0 +1,4 @@
+"""serving scheduler: median over working ticks of the ``serve/tick`` span minus what its
+``serve/prefill`` and ``serve/decode`` spans cover (lib/span_tree.py)."""
+
+from benchmarks.lib.span_tree import scheduler_self_ms as read  # noqa: F401

@@ -281,6 +281,10 @@ def _row_spec(block_t):
     return pl.BlockSpec((1, block_t), lambda i, j: (0, i))
 
 
+# The enclosing scope takes the ``jvp(...)`` / ``transpose(jvp(...))`` wrapping
+# that a ``custom_vjp`` puts on the first scope under it, so the kernels'
+# own ``name=`` reaches the HLO instruction (and the profiler trace) clean.
+@jax.named_scope("fused_ce")
 def _forward(hidden, w_vocab, labels, block_t, block_v, compute_dtype, z_loss, interpret):
     h, w, lab, n, v, d, n_tb, n_vb = _prep(
         hidden, w_vocab, labels, block_t, block_v, compute_dtype
@@ -297,6 +301,7 @@ def _forward(hidden, w_vocab, labels, block_t, block_v, compute_dtype, z_loss, i
         out_specs=[_row_spec(block_t)] * 3,
         out_shape=[row, row, row],
         interpret=interpret,
+        name="fused_ce_fwd",
     )(h, w, lab)
     b, t = labels.shape
     lse = lse2[0, :n]
@@ -324,6 +329,7 @@ def _fwd(hidden, w_vocab, labels, block_t, block_v, compute_dtype, z_loss, inter
     return loss, (hidden, w_vocab, labels, lse)
 
 
+@jax.named_scope("fused_ce")
 def _bwd(block_t, block_v, compute_dtype, z_loss, interpret, res, g):
     hidden, w_vocab, labels, lse = res
     h, w, lab, n, v, d, n_tb, n_vb = _prep(
@@ -354,6 +360,7 @@ def _bwd(block_t, block_v, compute_dtype, z_loss, interpret, res, g):
         out_specs=pl.BlockSpec((block_t, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
         interpret=interpret,
+        name="fused_ce_bwd_dh",
     )(h, w, lab, lse_p, gl_p, g_p)
 
     col_in = pl.BlockSpec((1, block_t), lambda j, i: (0, i))
@@ -371,6 +378,7 @@ def _bwd(block_t, block_v, compute_dtype, z_loss, interpret, res, g):
         out_specs=pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((n_vb * block_v, d), jnp.float32),
         interpret=interpret,
+        name="fused_ce_bwd_dw",
     )(h, w, lab, lse_p, gl_p, g_p)
 
     b, t = labels.shape

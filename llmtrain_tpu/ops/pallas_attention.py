@@ -250,6 +250,7 @@ def pallas_flash_attention_fwd(
             jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*operands)
 
     return _unfold(out, b, h), lse.reshape(b * h, t)
@@ -538,6 +539,7 @@ def pallas_flash_attention_bwd(
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_operands)
 
     # dk/dv grid over (batch*kv_head, k-block, group-member). The group is
@@ -586,6 +588,7 @@ def pallas_flash_attention_bwd(
             jax.ShapeDtypeStruct((b * hkv, t, d), grad_dtypes[1]),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkdv",
     )(*dkdv_operands)
 
     return (

@@ -23,10 +23,20 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   (models/gpt.py ``_paged_decode_attention``), so every bucket's program
   reads/writes the SAME donated cache buffers — join/evict never copies
   K/V.
+* **Every call says where its host time went.** With a ``span_factory``
+  (the scheduler's, wired like ``on_compile``) each call records
+  ``serve/engine.stage`` (numpy columns, tables, ``jnp.asarray``),
+  ``serve/engine.dispatch`` (the jitted call until it returns) and
+  ``serve/engine.fetch`` (the blocking read of the result), with ``call``
+  naming the program. The ``stage`` span also carries what the call is
+  about to waste, counted where the padding happens: ``prompt_tokens`` and
+  ``bucket`` of a prefill, ``kv_live_tokens`` and ``kv_gathered_tokens``
+  of a decode. Without a factory nothing is recorded.
 """
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import Any
 
 import jax
@@ -334,6 +344,15 @@ class PagedDecodeEngine:
         # scheduler wires it to a timeline instant: a request whose
         # prefill span brackets a compile instant explains its own tail.
         self.on_compile: Any = None
+        # Optional ``(name, **args) -> context manager`` (the scheduler's
+        # timeline span): stage / dispatch / fetch of every call land as
+        # children of the scheduler span that made the call.
+        self.span_factory: Any = None
+
+    def _span(self, phase: str, call: str, **counted: Any):
+        if self.span_factory is None:
+            return nullcontext()
+        return self.span_factory(f"serve/engine.{phase}", call=call, **counted)
 
     def _note_shape(self, shapes: set, key: Any, kind: str, bucket: int) -> None:
         if key in shapes:
@@ -403,12 +422,10 @@ class PagedDecodeEngine:
         tp = int(prompt_ids.shape[0])
         tb = bucket_for(tp, self.prompt_buckets)
         self._note_shape(self._prefill_shapes, tb, "prefill", tb)
-        prompt = np.zeros((1, tb), np.int32)
-        prompt[0, :tp] = prompt_ids
-        try:
-            cache, tok = self._prefill_jit(
-                self.params if params is None else params,
-                self._cache,
+        with self._span("stage", "prefill", prompt_tokens=tp, bucket=tb):
+            prompt = np.zeros((1, tb), np.int32)
+            prompt[0, :tp] = prompt_ids
+            staged = (
                 jnp.asarray(prompt),
                 jnp.asarray([tp], jnp.int32),
                 jnp.asarray([int(offset)], jnp.int32),
@@ -418,11 +435,17 @@ class PagedDecodeEngine:
                 jnp.asarray([0 if top_k is None else top_k], jnp.int32),
                 jnp.asarray([0.0 if top_p is None else top_p], jnp.float32),
             )
+        try:
+            with self._span("dispatch", "prefill"):
+                cache, tok = self._prefill_jit(
+                    self.params if params is None else params, self._cache, *staged
+                )
         except Exception:
             self._recover_cache_after_error()
             raise
         self._cache = cache
-        return int(tok[0])
+        with self._span("fetch", "prefill"):
+            return int(tok[0])
 
     def decode(
         self, rows: list[dict[str, Any]], *, params: Any | None = None
@@ -448,13 +471,18 @@ class PagedDecodeEngine:
                 out[i] = r[key]
             return out
 
-        tables = np.zeros((bb, mb), np.int32)
-        for i, r in enumerate(rows):
-            tables[i] = r["table"]
-        try:
-            cache, tok = self._decode_jit(
-                self.params if params is None else params,
-                self._cache,
+        # Every padded row gathers its whole block table
+        # (``_paged_decode_attention``), whatever the real rows attend.
+        with self._span(
+            "stage",
+            "decode",
+            kv_live_tokens=sum(int(r["position"]) + 1 for r in rows),
+            kv_gathered_tokens=bb * mb * self.block_tokens,
+        ):
+            tables = np.zeros((bb, mb), np.int32)
+            for i, r in enumerate(rows):
+                tables[i] = r["table"]
+            staged = (
                 jnp.asarray(col("token", 0, np.int32)),
                 jnp.asarray(col("position", 0, np.int32)),
                 jnp.asarray(tables),
@@ -469,11 +497,17 @@ class PagedDecodeEngine:
                 jnp.asarray(col("top_k", 0, np.int32)),
                 jnp.asarray(col("top_p", 0.0, np.float32)),
             )
+        try:
+            with self._span("dispatch", "decode"):
+                cache, tok = self._decode_jit(
+                    self.params if params is None else params, self._cache, *staged
+                )
         except Exception:
             self._recover_cache_after_error()
             raise
         self._cache = cache
-        return [int(t) for t in np.asarray(jax.device_get(tok))[:n]]
+        with self._span("fetch", "decode"):
+            return [int(t) for t in np.asarray(jax.device_get(tok))[:n]]
 
     def verify(
         self,
@@ -497,32 +531,32 @@ class PagedDecodeEngine:
         bb = bucket_for(n, self.batch_buckets)
         self._note_shape(self._verify_shapes, (bb, width), "verify", bb)
         mb = self.max_blocks_per_seq
-        tokens = np.zeros((bb, width), np.int32)
-        positions = np.zeros((bb,), np.int32)
-        tables = np.zeros((bb, mb), np.int32)
-        for i, r in enumerate(rows):
-            if len(r["tokens"]) != width:
-                raise ValueError(
-                    f"verify row {i} holds {len(r['tokens'])} tokens, "
-                    f"expected width {width}"
-                )
-            tokens[i] = r["tokens"]
-            positions[i] = r["position"]
-            tables[i] = r["table"]
+        with self._span("stage", "verify"):
+            tokens = np.zeros((bb, width), np.int32)
+            positions = np.zeros((bb,), np.int32)
+            tables = np.zeros((bb, mb), np.int32)
+            for i, r in enumerate(rows):
+                if len(r["tokens"]) != width:
+                    raise ValueError(
+                        f"verify row {i} holds {len(r['tokens'])} tokens, "
+                        f"expected width {width}"
+                    )
+                tokens[i] = r["tokens"]
+                positions[i] = r["position"]
+                tables[i] = r["table"]
+            staged = (jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(tables))
         try:
-            cache, out = self._verify_jit(
-                self.params if params is None else params,
-                self._cache,
-                jnp.asarray(tokens),
-                jnp.asarray(positions),
-                jnp.asarray(tables),
-            )
+            with self._span("dispatch", "verify"):
+                cache, out = self._verify_jit(
+                    self.params if params is None else params, self._cache, *staged
+                )
         except Exception:
             self._recover_cache_after_error()
             raise
         self._cache = cache
-        host = np.asarray(jax.device_get(out))
-        return [[int(t) for t in host[i]] for i in range(n)]
+        with self._span("fetch", "verify"):
+            host = np.asarray(jax.device_get(out))
+            return [[int(t) for t in host[i]] for i in range(n)]
 
     def cow_copy(self, src: int, dst: int) -> None:
         """Device-side copy-on-write: pool block ``src`` → ``dst`` in every
@@ -530,12 +564,13 @@ class PagedDecodeEngine:
         the write half of its contract (must run before the next pool
         mutation can recycle ``src``)."""
         self._cow_used = True
+        with self._span("stage", "cow_copy"):
+            staged = (jnp.asarray([src], jnp.int32), jnp.asarray([dst], jnp.int32))
         try:
-            self._cache = self._cow_jit(
-                self._cache,
-                jnp.asarray([src], jnp.int32),
-                jnp.asarray([dst], jnp.int32),
-            )
+            # Nothing is read back, so there is no ``fetch``: the copy is
+            # waited for by whichever later call reads the cache.
+            with self._span("dispatch", "cow_copy"):
+                self._cache = self._cow_jit(self._cache, *staged)
         except Exception:
             self._recover_cache_after_error()
             raise

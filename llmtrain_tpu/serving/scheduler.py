@@ -241,6 +241,9 @@ class ContinuousBatchingScheduler:
             # instants: they explain latency the per-request spans can't.
             engine.pool.observer = self._kv_event
             engine.on_compile = self._compile_event
+            # The engine's stage / dispatch / fetch spans nest under the
+            # scheduler span that made the call (docs/observability.md).
+            engine.span_factory = self._span
         self.max_batch_slots = int(
             max_batch_slots
             or (engine.max_batch_slots if engine is not None else 1)
@@ -259,6 +262,9 @@ class ContinuousBatchingScheduler:
         self._overload = overload
         self._queue: Any = overload.queue if overload is not None else deque()
         self._active: list[_Row] = []
+        # Scheduler-thread only: the number of the current ``step()``
+        # (every serving span carries it as ``tick``).
+        self._tick = 0
         # Rows still streaming their prompt in under chunked prefill —
         # they hold a batch slot (their KV is resident) but don't decode.
         self._prefilling: list[_Row] = []
@@ -390,10 +396,12 @@ class ContinuousBatchingScheduler:
     # ------------------------------------------------------------- backend
 
     def _span(self, name: str, **args: Any):
-        """Timeline span tagged for Perfetto, no-op without a timeline."""
+        """Timeline span of the current tick (``tick`` in its args; the
+        timeline adds ``parent``), yielding its args so the body can add
+        what it counted. No-op without a timeline."""
         if self.timeline is None:
-            return nullcontext()
-        return self.timeline.span(name, cat="serve", **args)
+            return nullcontext(args)
+        return self.timeline.span(name, cat="serve", tick=self._tick, **args)
 
     @contextmanager
     def _traced_span(self, req: ServeRequest, name: str, **args: Any):
@@ -580,11 +588,18 @@ class ContinuousBatchingScheduler:
     def step(self) -> bool:
         """One scheduler iteration: join, advance, evict. Returns whether
         any work happened (False = idle)."""
-        swapped = self._apply_pending_swap()
-        shed = self._overload_tick()
-        if self.policy == "speculative":
-            return self._step_speculative() or swapped or shed
-        return self._step_paged() or swapped or shed
+        self._tick += 1
+        with self._span("serve/tick") as tick:
+            swapped = self._apply_pending_swap()
+            shed = self._overload_tick()
+            if self.policy == "speculative":
+                worked = self._step_speculative() or swapped or shed
+            else:
+                worked = self._step_paged() or swapped or shed
+            # False marks an idle poll; the time between working ticks is
+            # the thread's idle time (benchmarks/lib/span_tree.py).
+            tick["worked"] = worked
+        return worked
 
     def _overload_tick(self) -> bool:
         """Per-step overload bookkeeping: feed the brownout hysteresis
@@ -722,14 +737,15 @@ class ContinuousBatchingScheduler:
                     kept.append(r)
             rows[:] = kept
 
-    def _step_paged(self) -> bool:
+    def _join_paged(self) -> int:
+        """The join loop of a paged tick: admit while a slot AND a
+        worst-case block budget exist. Head-of-line order — admission is
+        FIFO so a huge request cannot be starved by a stream of small ones
+        slipping past it. Returns how many requests joined."""
         engine = self.engine
         assert engine is not None
         epoch = engine.cache_epoch
         chunk = engine.prefill_chunk
-        # ---- join: admit while a slot AND a worst-case block budget exist.
-        # Head-of-line order — admission is FIFO so a huge request cannot
-        # be starved by a stream of small ones slipping past it.
         admitted = 0
         while len(self._active) + len(self._prefilling) < self.max_batch_slots:
             # Pop-first (the weighted-class queue's head is only defined
@@ -815,6 +831,19 @@ class ContinuousBatchingScheduler:
                 continue
             self._finish_or_activate(row)
             admitted += 1
+        return admitted
+
+    def _step_paged(self) -> bool:
+        """One paged tick. Its spans, in order (docs/observability.md):
+        ``serve/admit`` (the join loop; each joining prompt's
+        ``serve/prefill`` and COW copy nest in it), a chunked
+        ``serve/prefill``, then per param epoch ``serve/decode`` and
+        ``serve/emit``, then ``serve/publish``."""
+        engine = self.engine
+        assert engine is not None
+        chunk = engine.prefill_chunk
+        with self._span("serve/admit"):
+            admitted = self._join_paged()
 
         self._shed_abandoned_in_flight()
 
@@ -828,8 +857,6 @@ class ContinuousBatchingScheduler:
                     self._finish_or_activate(row)
                 else:
                     self._prefilling.insert(0, row)
-            else:
-                epoch = engine.cache_epoch
             chunked = True
 
         # ---- advance every in-flight sequence one token, grouped by the
@@ -872,7 +899,6 @@ class ContinuousBatchingScheduler:
                 try:
                     with self._span(
                         "serve/decode",
-                        request_ids=[r.req.request_id for r in group],
                         batch=len(rows),
                         param_epoch=ep,
                         **extra,
@@ -892,19 +918,21 @@ class ContinuousBatchingScheduler:
                     self._fail_all_in_flight(exc)
                     self._publish_metrics()
                     return True
-                now = time.monotonic()
-                for r, tok in zip(group, toks):
-                    r.req.tokens.append(int(tok))
-                    r.req.token_times.append(now)
-                    self.tokens_generated += 1
-                    if self._is_finished(r):
-                        self._retire(r)
-                    else:
-                        survivors.append(r)
+                with self._span("serve/emit"):
+                    now = time.monotonic()
+                    for r, tok in zip(group, toks):
+                        r.req.tokens.append(int(tok))
+                        r.req.token_times.append(now)
+                        self.tokens_generated += 1
+                        if self._is_finished(r):
+                            self._retire(r)
+                        else:
+                            survivors.append(r)
             self._active = survivors
             stepped = True
 
-        self._publish_metrics()
+        with self._span("serve/publish"):
+            self._publish_metrics()
         return stepped or chunked or admitted > 0
 
     # -------------------------------------------------------- speculative

@@ -6,7 +6,10 @@ framework only read the allocator's peak ONCE, at the end of the run
 (utils/hw.peak_memory_bytes). The monitor samples at every log interval:
 
 * ``mem/hbm_used`` / ``mem/hbm_peak`` / ``mem/hbm_limit`` from the PJRT
-  ``Device.memory_stats()`` counters (the TPU allocator's live numbers);
+  ``Device.memory_stats()`` counters. ``mem/hbm_peak`` is the allocator's
+  peak of live arrays PLUS the region the runtime reserves for compiled
+  programs' temporaries (``utils/hw.peak_bytes_from_stats``); the
+  live-array part alone stays readable as ``mem/hbm_peak_in_use``;
 * when the backend reports nothing (CPU PJRT) the
   used/peak figures FALL BACK to live-array introspection — the summed
   ``nbytes`` of every addressable ``jax.Array`` — so smoke runs still
@@ -25,6 +28,7 @@ from __future__ import annotations
 
 from typing import Any
 
+from ..utils.hw import peak_bytes_from_stats
 from ..utils.logging import get_logger
 
 logger = get_logger()
@@ -99,6 +103,7 @@ class MemoryMonitor:
         self._warn_frac = headroom_warn_frac
         self._timeline = timeline
         self._peak_hbm = 0.0
+        self._peak_hbm_in_use = 0.0
         self._peak_rss = 0.0
         self._peak_live_bytes = 0
         self._source = "unsampled"
@@ -133,7 +138,8 @@ class MemoryMonitor:
         if stats is not None:
             self._source = "memory_stats"
             used = float(stats.get("bytes_in_use") or 0.0)
-            peak = float(stats.get("peak_bytes_in_use") or used)
+            in_use_peak = float(stats.get("peak_bytes_in_use") or used)
+            peak = peak_bytes_from_stats(stats)
             limit = float(stats.get("bytes_limit") or 0.0)
         else:
             # CPU fallback: live addressable array bytes stand in
@@ -141,10 +147,12 @@ class MemoryMonitor:
             # difference; `mem/source` in the report names the estimator).
             self._source = "live_arrays"
             used = float(live_bytes)
-            peak = float(self._peak_live_bytes)
+            in_use_peak = peak = float(self._peak_live_bytes)
         self._peak_hbm = max(self._peak_hbm, peak, used)
+        self._peak_hbm_in_use = max(self._peak_hbm_in_use, in_use_peak, used)
         out["mem/hbm_used"] = used
         out["mem/hbm_peak"] = self._peak_hbm
+        out["mem/hbm_peak_in_use"] = self._peak_hbm_in_use
         if limit > 0:
             out["mem/hbm_limit"] = limit
             frac = used / limit
@@ -200,6 +208,7 @@ class MemoryMonitor:
         """End-of-run summary block for the report."""
         out = {
             "hbm_peak_bytes": self._peak_hbm,
+            "hbm_peak_in_use_bytes": self._peak_hbm_in_use,
             "host_rss_peak_bytes": self._peak_rss,
             "live_array_peak_bytes": float(self._peak_live_bytes),
             "headroom_warnings": float(self.headroom_warnings),

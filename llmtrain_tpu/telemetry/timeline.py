@@ -88,6 +88,9 @@ class EventTimeline:
         self._max_events = max(1000, int(max_events))
         self._xprof = xprof_annotations
         self._lock = threading.Lock()
+        # Names of the spans open on each thread, innermost last: a span
+        # opened inside another records it as its ``parent``.
+        self._open = threading.local()
         self._events: list[dict[str, Any]] = []
         self._flushed = 0  # events [0, _flushed) are already on disk
         self._dropped = 0
@@ -196,19 +199,32 @@ class EventTimeline:
     @contextmanager
     def span(
         self, name: str, *, cat: str = "train", step: int | None = None, **args: Any
-    ) -> Iterator[None]:
+    ) -> Iterator[dict[str, Any]]:
         """Record a duration event around the body; never raises from the
-        recording itself (the body's exceptions propagate untouched)."""
+        recording itself (the body's exceptions propagate untouched).
+
+        Yields the event's ``args``: the body adds what it counted there
+        (``with tl.span("x") as args: args["rows"] = n``). A span opened
+        inside another span of the same thread carries that span's name as
+        ``args["parent"]``, so each span says which span caused it."""
         if not self._enabled:
-            yield
+            yield args
             return
+        try:
+            stack = self._open.stack
+        except AttributeError:
+            stack = self._open.stack = []
+        if stack:
+            args.setdefault("parent", stack[-1])
+        stack.append(name)
         start = self._now_us()
         cm = _trace_annotation(name) if self._xprof else nullcontext()
         try:
             with cm:
-                yield
+                yield args
         finally:
             end = self._now_us()
+            stack.pop()
             event: dict[str, Any] = {
                 "name": name,
                 "cat": cat,

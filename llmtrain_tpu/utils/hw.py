@@ -84,14 +84,27 @@ def mfu(
     return tokens_per_sec_per_chip * flops_per_token / peak
 
 
+def peak_bytes_from_stats(stats: dict) -> float:
+    """Peak device bytes out of one device's ``memory_stats()``.
+
+    On the TPU the allocator's ``peak_bytes_in_use`` counts live arrays
+    only; the temporaries of compiled programs sit in a region the runtime
+    reserves beside them, ``peak_bytes_reserved`` (PR 23, GPT-2 small
+    train step: 2.17 GB + 10.42 GB, against 10.96 GB of temporaries in the
+    step's ``memory_analysis``). The peak is their sum, as
+    ``benchmarks/run.py:Context.memory_peak_bytes`` counts it.
+    ``bytes_in_use`` is a floor where a backend has no peak counter."""
+    in_use = float(stats.get("peak_bytes_in_use") or stats.get("bytes_in_use") or 0.0)
+    return in_use + float(stats.get("peak_bytes_reserved") or 0.0)
+
+
 def peak_memory_bytes() -> float:
     """Best-effort peak device-memory bytes of the first local device.
 
     Single owner of the lookup (trainer metrics, bench.py, and
-    tools/bench_longctx.py all report it). PJRT backends differ in which
-    keys they populate — ``peak_bytes_in_use`` is the TPU allocator's
-    high-water mark; ``bytes_in_use`` is a floor when the peak counter is
-    absent. Returns 0.0 when the backend reports nothing (CPU PJRT)."""
+    tools/bench_longctx.py all report it); :func:`peak_bytes_from_stats`
+    says which counters it sums. Returns 0.0 when the backend reports
+    nothing (CPU PJRT)."""
     import jax
 
     try:
@@ -100,7 +113,7 @@ def peak_memory_bytes() -> float:
         return 0.0
     if not stats:
         return 0.0
-    return float(stats.get("peak_bytes_in_use") or stats.get("bytes_in_use") or 0.0)
+    return peak_bytes_from_stats(stats)
 
 
 def memory_stats_keys() -> list[str]:
@@ -121,6 +134,7 @@ __all__ = [
     "peak_flops_per_chip",
     "transformer_flops_per_token",
     "mfu",
+    "peak_bytes_from_stats",
     "peak_memory_bytes",
     "memory_stats_keys",
 ]

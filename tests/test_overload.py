@@ -475,7 +475,7 @@ class FakeTimeline:
     def span(self, name: str, **kw):
         from contextlib import nullcontext
 
-        return nullcontext()
+        return nullcontext(kw)  # EventTimeline.span yields the span's args
 
 
 def _drain(sched: ContinuousBatchingScheduler, steps: int = 50) -> None:

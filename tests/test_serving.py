@@ -291,6 +291,174 @@ class TestContinuousBackend:
         assert code == 404
 
 
+class TestTickSpanTree:
+    """The span tree and work counters of a scheduler tick
+    (docs/observability.md "Serving timeline"): two requests scripted
+    through ``step()`` on the tiny model, every number worked out by hand.
+
+    Engine: 16 positions in blocks of 4 (4 blocks a sequence), 2 slots,
+    prompt buckets 4/8, batch buckets 1/2. Request A: 3 prompt tokens, 3 new;
+    request B: 6 prompt tokens, 2 new.
+    Tick 1 admits both (prefill buckets 4 and 8), then decodes both: the fed
+    tokens sit at positions 3 and 6, so the rows attend 4 + 7 = 11 positions
+    while the program gathers 2 rows x 4 blocks x 4 = 32; B is done.
+    Tick 2 decodes A alone at position 4: 5 live of 1 x 16 gathered; A is done.
+    A third ``step()`` finds nothing: its tick says ``worked: False``."""
+
+    @pytest.fixture()
+    def events(self):
+        import numpy as np
+
+        from llmtrain_tpu.serving import ServeRequest
+        from llmtrain_tpu.telemetry.timeline import EventTimeline
+
+        model, params = _tiny_model()
+        engine = PagedDecodeEngine(
+            model, params, block_tokens=4, max_batch_slots=2,
+            prompt_buckets=[4, 8], batch_buckets=[1, 2],
+        )
+        timeline = EventTimeline(None)
+        sched = ContinuousBatchingScheduler(engine, timeline=timeline)
+        a = sched.submit(ServeRequest(
+            prompt_ids=np.array([1, 2, 3], np.int32), max_new_tokens=3, temperature=0.0))
+        b = sched.submit(ServeRequest(
+            prompt_ids=np.array([4, 5, 6, 7, 8, 9], np.int32), max_new_tokens=2, temperature=0.0))
+        assert [sched.step(), sched.step(), sched.step()] == [True, True, False]
+        assert a.finish_reason == b.finish_reason == "length"
+        assert (len(a.tokens), len(b.tokens)) == (3, 2)
+        return [e for e in timeline.events() if e["ph"] == "X" and e["cat"] == "serve"]
+
+    @staticmethod
+    def _named(events, name):
+        return [e for e in events if e["name"] == name]
+
+    def test_one_working_tick_per_working_step(self, events):
+        ticks = self._named(events, "serve/tick")
+        assert [t["args"] for t in ticks] == [
+            {"tick": 1, "worked": True},
+            {"tick": 2, "worked": True},
+            {"tick": 3, "worked": False},  # an idle poll
+        ]
+
+    def test_children_carry_the_tick_lie_inside_it_and_add_up(self, events):
+        for tick in self._named(events, "serve/tick"):
+            t0, t1 = tick["ts_us"], tick["ts_us"] + tick["dur_us"]
+            number = tick["args"]["tick"]
+            inside = [e for e in events if e is not tick and e["name"] != "serve/queue_wait"
+                      and e["args"].get("tick") == number]
+            assert inside
+            for e in inside:
+                assert t0 <= e["ts_us"] and e["ts_us"] + e["dur_us"] <= t1, e["name"]
+            children = sorted(
+                (e for e in inside if e["args"]["parent"] == "serve/tick"),
+                key=lambda e: e["ts_us"],
+            )
+            assert [e["name"] for e in children] == (
+                ["serve/admit", "serve/decode", "serve/emit", "serve/publish"]
+                if tick["args"]["worked"] else ["serve/admit", "serve/publish"])
+            for first, second in zip(children, children[1:]):
+                assert first["ts_us"] + first["dur_us"] <= second["ts_us"]
+            self_us = tick["dur_us"] - sum(e["dur_us"] for e in children)
+            assert 0 <= self_us <= tick["dur_us"]
+        # No serving span of the scheduler thread is outside a tick.
+        numbers = {e["args"]["tick"] for e in events if e["name"] != "serve/queue_wait"}
+        assert numbers == {1, 2, 3}
+
+    def test_decode_counters_equal_the_hand_worked_values(self, events):
+        decodes = self._named(events, "serve/decode")
+        assert [d["args"] for d in decodes] == [
+            {"tick": 1, "parent": "serve/tick", "batch": 2, "param_epoch": 0},
+            {"tick": 2, "parent": "serve/tick", "batch": 1, "param_epoch": 0},
+        ]
+        # The engine counts the gather where it pads the rows.
+        stages = [e["args"] for e in self._named(events, "serve/engine.stage")
+                  if e["args"]["call"] == "decode"]
+        assert stages == [
+            {"tick": 1, "parent": "serve/decode", "call": "decode",
+             "kv_live_tokens": 11, "kv_gathered_tokens": 32},
+            {"tick": 2, "parent": "serve/decode", "call": "decode",
+             "kv_live_tokens": 5, "kv_gathered_tokens": 16},
+        ]
+
+    def test_prefills_nest_in_admit_and_their_calls_count_the_bucket(self, events):
+        prefills = self._named(events, "serve/prefill")
+        assert [
+            {k: p["args"][k] for k in ("tick", "parent", "prompt_tokens", "offset")}
+            for p in prefills
+        ] == [
+            {"tick": 1, "parent": "serve/admit", "prompt_tokens": 3, "offset": 0},
+            {"tick": 1, "parent": "serve/admit", "prompt_tokens": 6, "offset": 0},
+        ]
+        assert all("request_id" in p["args"] and "trace_id" in p["args"] for p in prefills)
+        stages = [e["args"] for e in self._named(events, "serve/engine.stage")
+                  if e["args"]["call"] == "prefill"]
+        assert stages == [
+            {"tick": 1, "parent": "serve/prefill", "call": "prefill",
+             "prompt_tokens": 3, "bucket": 4},
+            {"tick": 1, "parent": "serve/prefill", "call": "prefill",
+             "prompt_tokens": 6, "bucket": 8},
+        ]
+
+    def test_every_engine_call_splits_into_stage_dispatch_fetch(self, events):
+        calls = sorted(
+            (e for e in events if e["name"].startswith("serve/engine.")),
+            key=lambda e: e["ts_us"],
+        )
+        got = [(e["name"].rsplit(".", 1)[1], e["args"]["call"], e["args"]["parent"],
+                e["args"]["tick"]) for e in calls]
+        phases = ("stage", "dispatch", "fetch")
+        assert got == (
+            [(ph, "prefill", "serve/prefill", 1) for ph in phases] * 2
+            + [(ph, "decode", "serve/decode", 1) for ph in phases]
+            + [(ph, "decode", "serve/decode", 2) for ph in phases]
+        )
+        # The three phases of one call lie inside the span that made it.
+        for decode in self._named(events, "serve/decode"):
+            mine = [e for e in calls if e["args"]["parent"] == "serve/decode"
+                    and e["args"]["tick"] == decode["args"]["tick"]]
+            assert sum(e["dur_us"] for e in mine) <= decode["dur_us"]
+            assert all(decode["ts_us"] <= e["ts_us"]
+                       and e["ts_us"] + e["dur_us"] <= decode["ts_us"] + decode["dur_us"]
+                       for e in mine)
+
+
+def test_run_forever_marks_idle_polls_and_works_inside_ticks():
+    """``run_forever`` opens one ``serve/tick`` per poll: the polls of an
+    idle loop say ``worked: False`` (about ten a second), and a request's
+    prefill and decodes all lie in working ticks at the top of the tree."""
+    import time
+
+    import numpy as np
+
+    from llmtrain_tpu.serving import ServeRequest
+    from llmtrain_tpu.telemetry.timeline import EventTimeline
+
+    model, params = _tiny_model()
+    engine = PagedDecodeEngine(
+        model, params, block_tokens=4, max_batch_slots=2,
+        prompt_buckets=[4, 8], batch_buckets=[1, 2],
+    )
+    timeline = EventTimeline(None)
+    sched = ContinuousBatchingScheduler(engine, timeline=timeline).start()
+    try:
+        time.sleep(0.35)  # three polls of the idle loop
+        req = sched.submit(ServeRequest(
+            prompt_ids=np.array([1, 2, 3], np.int32), max_new_tokens=3, temperature=0.0))
+        assert req.done.wait(timeout=120.0) and req.finish_reason == "length"
+    finally:
+        sched.close()
+    spans = [e for e in timeline.events() if e["ph"] == "X" and e["cat"] == "serve"]
+    ticks = [e for e in spans if e["name"] == "serve/tick"]
+    assert all("parent" not in t["args"] for t in ticks)
+    working = {t["args"]["tick"] for t in ticks if t["args"]["worked"]}
+    idle = [t for t in ticks if not t["args"]["worked"]]
+    assert len(working) == 2 and 1 <= len(idle) <= 20  # ~10 polls a second, not one per 5 ms
+    for first, second in zip(ticks, ticks[1:]):
+        assert first["ts_us"] + first["dur_us"] <= second["ts_us"]
+    calls = [e for e in spans if e["name"] in ("serve/prefill", "serve/decode")]
+    assert len(calls) == 3 and {e["args"]["tick"] for e in calls} == working
+
+
 class TestLiveServer:
     @pytest.fixture()
     def server(self):

@@ -59,6 +59,63 @@ class TestEventTimeline:
         assert event["dur_us"] >= 0
         assert event["args"] == {"detail": "x"}
 
+    def test_nested_spans_record_their_parent_per_thread(self):
+        """A span opened inside another says which span caused it; the
+        stack is per thread, so a span another thread opens meanwhile is
+        nobody's child; the body adds what it counted to the yielded args."""
+        tl = EventTimeline()
+        inside = threading.Event()
+        release = threading.Event()
+
+        def other():
+            inside.wait(timeout=10.0)
+            with tl.span("other_thread"):
+                with tl.span("other_child"):
+                    pass
+            release.set()
+
+        worker = threading.Thread(target=other, name="other")
+        worker.start()
+        with tl.span("outer", rows=2) as args:
+            with tl.span("middle"):
+                inside.set()
+                assert release.wait(timeout=10.0)
+                with tl.span("inner") as inner_args:
+                    inner_args["counted"] = 5
+            with tl.span("sibling"):
+                pass
+            args["rows_padded"] = 8
+        with tl.span("after"):
+            pass
+        worker.join(timeout=10.0)
+        assert not worker.is_alive()
+        args_of = {e["name"]: e.get("args", {}) for e in tl.events()}
+        assert args_of["outer"] == {"rows": 2, "rows_padded": 8}
+        assert args_of["middle"] == {"parent": "outer"}
+        assert args_of["inner"] == {"parent": "middle", "counted": 5}
+        assert args_of["sibling"] == {"parent": "outer"}
+        assert args_of["after"] == {}
+        assert args_of["other_thread"] == {}
+        assert args_of["other_child"] == {"parent": "other_thread"}
+
+    def test_span_stack_unwinds_when_the_body_raises(self):
+        tl = EventTimeline()
+        with pytest.raises(ValueError):
+            with tl.span("outer"):
+                with tl.span("failing"):
+                    raise ValueError("boom")
+        with tl.span("next"):
+            pass
+        args_of = {e["name"]: e.get("args", {}) for e in tl.events()}
+        assert args_of["failing"] == {"parent": "outer"}
+        assert args_of["next"] == {}
+
+    def test_disabled_timeline_still_yields_args(self):
+        tl = EventTimeline(enabled=False)
+        with tl.span("outer", rows=1) as args:
+            args["more"] = 2
+        assert tl.events() == []
+
     def test_span_propagates_body_exception_but_still_records(self):
         tl = EventTimeline()
         with pytest.raises(ValueError):
@@ -217,6 +274,35 @@ class TestMemoryMonitor:
         stats["bytes_in_use"] = 95.0e9
         mon.sample(step=10)
         assert mon.headroom_warnings == 2
+
+
+    def test_peak_counts_the_reserved_region_too(self, monkeypatch):
+        """On the TPU ``peak_bytes_in_use`` is live arrays only; compiled
+        programs' temporaries are in ``peak_bytes_reserved`` (PR 23's train
+        cell, rounded). The peak sums both, like the benchmark's
+        ``memory_peak_bytes``; the live-array part keeps its own key."""
+        from llmtrain_tpu.telemetry import memory as mem_mod
+        from llmtrain_tpu.utils.hw import peak_bytes_from_stats
+
+        stats = {
+            "bytes_in_use": 1.5e9,
+            "peak_bytes_in_use": 2.17e9,
+            "bytes_reserved": 10.0e9,
+            "peak_bytes_reserved": 10.42e9,
+            "bytes_limit": 16.0e9,
+        }
+        assert peak_bytes_from_stats(stats) == 2.17e9 + 10.42e9
+        assert peak_bytes_from_stats({"bytes_in_use": 3.0}) == 3.0
+        assert peak_bytes_from_stats({}) == 0.0
+        monkeypatch.setattr(mem_mod, "_device_memory_stats", lambda: dict(stats))
+        mon = MemoryMonitor()
+        sample = mon.sample(step=1)
+        assert sample["mem/hbm_peak"] == 2.17e9 + 10.42e9
+        assert sample["mem/hbm_peak_in_use"] == 2.17e9
+        assert sample["mem/hbm_used"] == 1.5e9
+        peaks = mon.peaks()
+        assert peaks["hbm_peak_bytes"] == 2.17e9 + 10.42e9
+        assert peaks["hbm_peak_in_use_bytes"] == 2.17e9
 
 
 # ---------------------------------------------------------------- registry

@@ -182,6 +182,64 @@ class TestFusedNormOneChip:
         assert text.count("tpu_custom_call") >= 2
 
 
+def _custom_call_names(text: str) -> set[str]:
+    """Instruction names (numeric suffix dropped) of the program's
+    ``tpu_custom_call`` instructions: what the profiler's ``XLA Ops`` line
+    shows and ``benchmarks/lib/trace.py:op_label`` reduces."""
+    import re
+
+    return {
+        re.sub(r"(\.\d+)+$", "", m.group(1))
+        for m in re.finditer(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    }
+
+
+def _ce_grad(one_chip):
+    return _compile(
+        jax.grad(TestFusedCEOneChip._loss, argnums=(0, 1)),
+        *TestFusedCEOneChip._operands(one_chip, jnp.bfloat16),
+    )
+
+
+def _flash_grad(one_chip):
+    return _compile(jax.grad(_flash_loss(), argnums=(0, 1, 2)), *_qkv(one_chip))
+
+
+def _norm_grad(one_chip):
+    from llmtrain_tpu.ops.fused_norm import fused_add_layer_norm
+
+    def loss(x, res, scale, bias):
+        y, s = fused_add_layer_norm(x, res, scale, bias)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(s.astype(jnp.float32))
+
+    x, p = TestFusedNormOneChip._operands(one_chip)
+    return _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, p, p)
+
+
+class TestKernelNames:
+    """Every Pallas kernel of the train path carries a stable ``name=``, so
+    the trace names it (an unnamed call takes the enclosing function's name:
+    ``jvp__``, ``transpose_jvp___`` under a ``custom_vjp``) and the
+    per-kernel roofline readers under ``benchmarks/metrics/`` find it."""
+
+    @pytest.mark.parametrize(
+        "build, names",
+        [
+            (_ce_grad, {"fused_ce_fwd", "fused_ce_bwd_dh", "fused_ce_bwd_dw"}),
+            (
+                _flash_grad,
+                {"flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv"},
+            ),
+            (_norm_grad, {"fused_norm_fwd", "fused_norm_bwd"}),
+        ],
+        ids=["fused_ce", "flash_attention", "fused_norm"],
+    )
+    def test_gradient_program_names_its_kernels(self, one_chip, build, names):
+        found = _custom_call_names(build(one_chip))
+        assert found == names
+        assert not any("jvp" in name for name in found)
+
+
 class TestKernelsOnFourChipMesh:
     """The tests that would have caught ``attention: flash`` never having
     compiled on a TPU mesh: batch-sharded operands inside a GSPMD-jitted
