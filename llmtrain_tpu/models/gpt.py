@@ -164,6 +164,21 @@ class FusedLayerNorm(nn.Module):
         )
 
 
+def paged_block_fold(block_tokens: int, width: int) -> int:
+    """How many positions of a block share one row of a paged pool leaf.
+
+    A leaf is ``(num_blocks, block_tokens // fold, fold * width)`` with
+    ``width = kv_width * head_dim`` (``_paged_decode_attention``). ``fold``
+    is the smallest divisor of ``block_tokens`` that makes a row at least
+    one 128-lane tile wide, and ``block_tokens`` where none does: 1 for
+    every model with ``width >= 128``, 2 for MQA with a 64-wide head.
+    """
+    for fold in range(1, block_tokens):
+        if block_tokens % fold == 0 and fold * width >= 128:
+            return fold
+    return block_tokens
+
+
 class CausalSelfAttention(nn.Module):
     d_model: int
     n_heads: int
@@ -627,6 +642,26 @@ class CausalSelfAttention(nn.Module):
         p = positions[b]+t, then attends the gathered blocks masked by
         absolute position (col <= p) — the same liveness rule as the
         linear path, so outputs match single-sequence decode.
+
+        **Pool layout.** A leaf is ``(num_blocks, bt // fold, fold *
+        width)``, ``width = kv_width * head_dim`` and ``fold`` from
+        :func:`paged_block_fold`: a block's positions in order, each one's
+        heads flattened, so a block is ``bt * width`` contiguous values
+        and slot ``s`` sits in row ``s // fold`` at lane ``(s % fold) *
+        width``. The minor dimension must be lane-dense (at least one
+        128-lane tile). The TPU compiler tiles the two minor dimensions
+        8 x 128; given a leaf whose minor dimension is half a tile
+        (``(nb, bt, kv_width, 64)``) it keeps the leaf in HBM with
+        ``num_blocks`` minor, and every prefill, decode and verify
+        program then transposes the WHOLE pool to write it, again to
+        return it and again to gather it. With a lane-dense row the
+        layout the compiler picks is the row-major one that the scatter,
+        the block-table gather and the engine's COW copy use, and the
+        donated input aliases the output in it
+        (``tests/test_tpu_aot_compile.py`` holds that at the benchmark's
+        pool shapes). The gathered blocks are still re-tiled per head for
+        the score einsum; that is a relayout of what was gathered, not of
+        the pool.
         """
         if positions is None or block_tables is None:
             raise ValueError(
@@ -652,11 +687,12 @@ class CausalSelfAttention(nn.Module):
             )
         batch, t, n_heads, head_dim = q.shape
         kv_width = k.shape[2]
-        paged_key = self.variable(
-            "cache", "paged_key", jnp.zeros, (nb, bt, kv_width, head_dim), k.dtype
-        )
+        width = kv_width * head_dim
+        fold = paged_block_fold(bt, width)
+        leaf_shape = (nb, bt // fold, fold * width)
+        paged_key = self.variable("cache", "paged_key", jnp.zeros, leaf_shape, k.dtype)
         paged_value = self.variable(
-            "cache", "paged_value", jnp.zeros, (nb, bt, kv_width, head_dim), v.dtype
+            "cache", "paged_value", jnp.zeros, leaf_shape, v.dtype
         )
         # Absolute position of every token in this call, per row.
         pos = positions[:, None] + jnp.arange(t)[None, :]  # (B, t)
@@ -673,12 +709,33 @@ class CausalSelfAttention(nn.Module):
         # Distinct rows hold disjoint physical blocks (allocator invariant),
         # so the only duplicate targets are padded rows' null-block writes —
         # garbage nothing live ever reads.
-        paged_key.value = paged_key.value.at[blocks, slots].set(
-            k.astype(paged_key.value.dtype)
-        )
-        paged_value.value = paged_value.value.at[blocks, slots].set(
-            v.astype(paged_value.value.dtype)
-        )
+        if fold == 1:
+            # One position a row: whole-row scatter, in place on the pool.
+            def write(pool: jax.Array, rows: jax.Array) -> jax.Array:
+                return pool.at[blocks, slots].set(rows)
+
+        else:
+            # `fold` positions a row: slot s is the `width` lanes from
+            # (s % fold) * width of row s // fold, written as a window.
+            # The TPU compiler turns a windowed scatter into a loop of one
+            # in-place update a token (a whole-row scatter it runs as one
+            # op), so only rows narrower than a lane tile come here.
+            where = jnp.stack(
+                [blocks, slots // fold, (slots % fold) * width], axis=-1
+            )
+            window = jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(2,),
+                inserted_window_dims=(0, 1),
+                scatter_dims_to_operand_dims=(0, 1, 2),
+            )
+
+            def write(pool: jax.Array, rows: jax.Array) -> jax.Array:
+                return jax.lax.scatter(pool, where, rows, window)
+
+        for leaf, new in ((paged_key, k), (paged_value, v)):
+            leaf.value = write(
+                leaf.value, new.astype(leaf.value.dtype).reshape(batch, t, width)
+            )
 
         s = block_tables.shape[1] * bt
         keys = paged_key.value[block_tables].reshape(batch, s, kv_width, head_dim)

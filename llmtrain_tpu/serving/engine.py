@@ -218,7 +218,11 @@ def _verify_impl(
 
 def _cow_impl(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
     """Copy-on-write device copy: pool block ``src`` → ``dst`` across
-    every paged cache leaf (leaves are (num_blocks, bt, kv, dh))."""
+    every paged cache leaf. Only the leading (block) axis is indexed, so
+    the copy holds for any leaf shape; leaves are ``(num_blocks, bt //
+    fold, fold * kv_heads * head_dim)`` with a lane-dense minor dimension
+    (``_paged_decode_attention``), in which a block is contiguous and the
+    donated pool is updated in place."""
     return jax.tree.map(lambda leaf: leaf.at[dst].set(leaf[src]), cache)
 
 

@@ -2,7 +2,13 @@
 
 The device-side cache is a pool of ``num_blocks`` fixed-size blocks of
 ``block_tokens`` positions each, owned per layer by the paged decode
-model (models/gpt.py ``_paged_decode_attention``). THIS module owns the
+model (models/gpt.py ``_paged_decode_attention``): K and V leaves of
+``(num_blocks, block_tokens // fold, fold * kv_heads * head_dim)``, a
+block's positions in order with each position's heads flattened. The
+minor dimension must be lane-dense (>= 128, ``paged_block_fold``): a
+64-wide one makes the TPU compiler put ``num_blocks`` minor and
+transpose the whole pool in every call. Nothing here depends on a
+leaf's shape: a block is an index on the leading axis. THIS module owns the
 host-side accounting that makes the pool safe to share between N
 in-flight sequences (the vLLM PagedAttention layout, PAPERS.md MinT —
 multiplexing many requests onto one accelerator is where serving

@@ -47,25 +47,59 @@ VOCAB = 32
 BLOCK = 32
 
 
+def _unboxed_params(model):
+    return nn_meta.unbox(
+        model.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32), deterministic=True
+        )["params"]
+    )
+
+
+def _llama(**kw):
+    from llmtrain_tpu.models.llama import Llama
+
+    return Llama(vocab_size=VOCAB, block_size=BLOCK, n_layers=1, dropout=0.0, **kw)
+
+
+# The shapes the paged pool's layout rule branches on (models/gpt.py
+# ``paged_block_fold``; a pool row is ``fold`` positions of
+# ``kv_heads * head_dim`` values), with the engine's ``block_tokens: 8``:
+LAYOUT_MODELS = {
+    # 2 heads of 16: a row of 32, four positions fold into 128 lanes.
+    "gpt-row32-fold4": lambda: GPT(
+        vocab_size=VOCAB, block_size=BLOCK, d_model=32, n_layers=1, n_heads=2,
+        d_ff=64, dropout=0.0, tie_embeddings=True,
+    ),
+    # 5 heads of 40: a row of 200, wider than a lane tile and no multiple of it.
+    "gpt-row200": lambda: GPT(
+        vocab_size=VOCAB, block_size=BLOCK, d_model=200, n_layers=1, n_heads=5,
+        d_ff=256, dropout=0.0, tie_embeddings=True,
+    ),
+    # Llama family (RoPE rotates before the write), GQA 8 -> 4 heads of 32:
+    # a row of exactly 128.
+    "llama-gqa-row128": lambda: _llama(d_model=256, n_heads=8, n_kv_heads=4, d_ff=128),
+    # GQA 4 -> 2 heads of 32: a row of 64, two positions fold.
+    "llama-gqa-row64-fold2": lambda: _llama(
+        d_model=128, n_heads=4, n_kv_heads=2, d_ff=128
+    ),
+    # MQA, one head of 16: a whole block of 8 positions is one 128-lane row.
+    "llama-mqa-row16-fold8": lambda: _llama(d_model=64, n_heads=4, n_kv_heads=1, d_ff=128),
+}
+
+
+@pytest.fixture(scope="module", params=list(LAYOUT_MODELS))
+def layout_model(request):
+    model = LAYOUT_MODELS[request.param]()
+    return model, _unboxed_params(model)
+
+
 @pytest.fixture(scope="module")
 def tiny_model():
     # 1 layer: the pool/engine/scheduler logic is layer-count-uniform
     # (per-layer cache vars are created by the same code path), and the
     # tier-1 gate runs this file serially against a tight time budget.
-    model = GPT(
-        vocab_size=VOCAB,
-        block_size=BLOCK,
-        d_model=32,
-        n_layers=1,
-        n_heads=2,
-        d_ff=64,
-        dropout=0.0,
-        tie_embeddings=True,
-    )
-    params = nn_meta.unbox(
-        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))["params"]
-    )
-    return model, params
+    model = LAYOUT_MODELS["gpt-row32-fold4"]()
+    return model, _unboxed_params(model)
 
 
 def _engine(model, params, **kw):
@@ -188,11 +222,12 @@ class TestBuckets:
 
 
 class TestBatchedParity:
-    def test_greedy_bitwise_parity_mixed_lengths(self, tiny_model):
+    def test_greedy_bitwise_parity_mixed_lengths(self, layout_model):
         """The acceptance contract: >= 2 sequences concurrently in flight,
         batched output token-ids bitwise identical to sequential
-        generate(), compile count within the bucket budget."""
-        model, params = tiny_model
+        generate(), compile count within the bucket budget — at every
+        shape the pool's layout rule branches on."""
+        model, params = layout_model
         engine = _engine(model, params)
         scheduler = ContinuousBatchingScheduler(engine, registry=MetricsRegistry(None))
         rng = np.random.default_rng(7)
@@ -340,6 +375,105 @@ class TestBatchedParity:
         assert never.finish_reason == "error"
         assert "pool" in never.error
         assert behind.finish_reason == "length"  # not starved
+
+
+class TestPoolLayout:
+    """The pool leaf's shape follows from (block_tokens, kv_heads, head_dim)
+    alone and every paged path — batched speculative ``verify``, a prefix
+    hit that forces a ``cow_copy`` — stays bitwise equal to ``generate()``
+    on it."""
+
+    @pytest.mark.parametrize(
+        "block_tokens, width, fold",
+        [
+            (16, 768, 1),  # gpt2-small
+            (16, 1600, 1),  # gpt2-xl: 12.5 lane tiles, padded by the tiling
+            (16, 128, 1),
+            (16, 64, 2),  # MQA, one head of 64
+            (8, 32, 4),
+            (8, 16, 8),
+            (4, 16, 4),  # no fold reaches a lane tile: the whole block is one row
+            (6, 48, 3),  # only divisors of block_tokens
+        ],
+    )
+    def test_fold_makes_a_row_lane_dense(self, block_tokens, width, fold):
+        from llmtrain_tpu.models.gpt import paged_block_fold
+
+        assert paged_block_fold(block_tokens, width) == fold
+
+    def test_cache_leaves_are_rows_of_folded_positions(self, layout_model):
+        from llmtrain_tpu.models.gpt import paged_block_fold
+
+        model, params = layout_model
+        engine = _engine(model, params)
+        kv_heads = getattr(model, "n_kv_heads", 0) or model.n_heads
+        width = kv_heads * (model.d_model // model.n_heads)
+        fold = paged_block_fold(8, width)
+        leaves = jax.tree.leaves(engine._cache)
+        assert len(leaves) == 2  # K and V of the one layer
+        for leaf in leaves:
+            assert leaf.shape == (engine.pool.num_blocks, 8 // fold, fold * width)
+            assert leaf.shape[-1] >= 128
+
+    def test_batched_speculative_verify_parity(self, layout_model):
+        """Draft-and-verify through ``engine.verify`` (a multi-token write
+        per row, rejected slots overwritten later) on a draft that
+        disagrees with the target."""
+        model, params = layout_model
+        draft_params = jax.tree.map(lambda x: x * 0.5, params)
+        kw = dict(
+            block_tokens=8, max_batch_slots=2, prompt_buckets=[8], batch_buckets=[1, 2]
+        )
+        scheduler = ContinuousBatchingScheduler(
+            PagedDecodeEngine(model, params, **kw),
+            policy="speculative",
+            model=model,
+            params=params,
+            draft_model=model,
+            draft_params=draft_params,
+            draft_engine=PagedDecodeEngine(model, draft_params, **kw),
+            gamma=3,
+        )
+        requests = [
+            ServeRequest(
+                prompt_ids=np.arange(i, i + 5, dtype=np.int32), max_new_tokens=9, seed=0
+            )
+            for i in range(3)
+        ]
+        for req in requests:
+            scheduler.submit(req)
+        _drain(scheduler, requests)
+        for req in requests:
+            assert req.finish_reason == "length", req.error
+            assert req.tokens == _reference(model, params, req)
+        stats = scheduler.stats()["speculative"]
+        assert stats["mode"] == "batched" and stats["rounds"] > 0
+        assert scheduler.engine.compile_stats()["verify_programs"] >= 1
+
+    def test_prefix_hit_with_cow_copy_parity(self, layout_model):
+        """A prompt that shares one full block and PART of the next with a
+        cached one binds both, copies the partial block on write
+        (``cow_copy``), and still decodes what ``generate()`` does."""
+        model, params = layout_model
+        engine = _engine(model, params, prefix_cache=True)
+        scheduler = ContinuousBatchingScheduler(engine)
+        first = ServeRequest(
+            prompt_ids=np.arange(1, 19, dtype=np.int32) % VOCAB, max_new_tokens=4, seed=0
+        )
+        scheduler.submit(first)
+        _drain(scheduler, [first])
+        # Same first block (8 tokens), same 5 tokens of the second, then its own.
+        second_prompt = np.concatenate(
+            [first.prompt_ids[:13], np.asarray([30, 29, 28], np.int32)]
+        )
+        second = ServeRequest(prompt_ids=second_prompt, max_new_tokens=6, seed=0)
+        scheduler.submit(second)
+        _drain(scheduler, [second])
+        assert engine.compile_stats()["cow_programs"] == 1
+        assert engine.pool.stats()["prefix_tokens_reused"] == 13
+        for req in (first, second):
+            assert req.finish_reason == "length", req.error
+            assert req.tokens == _reference(model, params, req)
 
 
 class TestFailureContainment:

@@ -16,7 +16,9 @@ test file that describes a TPU topology.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -186,8 +188,6 @@ def _custom_call_names(text: str) -> set[str]:
     """Instruction names (numeric suffix dropped) of the program's
     ``tpu_custom_call`` instructions: what the profiler's ``XLA Ops`` line
     shows and ``benchmarks/lib/trace.py:op_label`` reduces."""
-    import re
-
     return {
         re.sub(r"(\.\d+)+$", "", m.group(1))
         for m in re.finditer(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
@@ -303,3 +303,187 @@ class TestKernelsOnFourChipMesh:
             text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), x, x, p, p)
         assert text.count("tpu_custom_call") >= 2
         assert f"bf16[{B // 4 * T},{D_MODEL}]" in text
+
+
+# --------------------------------------------------------------------------
+# The paged KV pool: the engine's programs at the benchmark's pool shapes.
+
+POOL_BLOCK_TOKENS, POOL_CONTEXT, POOL_LAYERS, POOL_VOCAB = 16, 1024, 2, 2048
+POOL_SHAPES = {
+    # gpt2-small.serve-batch: 96 slots x 64 blocks + the null block, rows of 768.
+    "gpt2-small": dict(family="gpt", d_model=768, n_heads=12, slots=96, num_blocks=6145),
+    # gpt2-xl.serve-chat: 24 slots, rows of 1,600 (12.5 lane tiles).
+    "gpt2-xl": dict(family="gpt", d_model=1600, n_heads=25, slots=24, num_blocks=1537),
+    # Llama family (RoPE), 4 KV heads of 64: rows of 256. Pools of the batch
+    # cell's bytes: a pool of a few MB the compiler prefetches whole into
+    # fast memory, which says nothing about one that fills a chip.
+    "llama-gqa": dict(
+        family="llama", d_model=768, n_heads=12, n_kv_heads=4, slots=96, num_blocks=18433
+    ),
+    # One KV head of 64, half a lane tile: two positions fold into a row.
+    "llama-mqa": dict(
+        family="llama", d_model=768, n_heads=12, n_kv_heads=1, slots=96, num_blocks=73729
+    ),
+}
+POOL_PROGRAMS = ("prefill", "decode", "verify", "cow_copy")
+# What may hold a whole pool leaf: the donated leaf itself, passed along,
+# and the write into it (a fusion only when it wraps that write).
+POOL_IN_PLACE = {
+    "parameter", "get-tuple-element", "tuple", "bitcast", "while",
+    "scatter", "dynamic-update-slice",
+}
+
+
+def _pool_programs(name, one_chip):
+    """``{program: (fn, shapes)}`` of the engine's four jitted programs for
+    one pool shape, plus the cache leaf's shape. Shapes only: nothing is
+    allocated (``PagedDecodeEngine`` itself would zero a pool on the CPU)."""
+    import functools
+
+    from llmtrain_tpu.serving import engine
+
+    spec = dict(POOL_SHAPES[name])
+    family, slots, num_blocks = (spec.pop(k) for k in ("family", "slots", "num_blocks"))
+    common = dict(
+        vocab_size=POOL_VOCAB, block_size=POOL_CONTEXT, n_layers=POOL_LAYERS,
+        d_ff=2 * spec["d_model"], dropout=0.0, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, **spec,
+    )
+    if family == "llama":
+        from llmtrain_tpu.models.llama import Llama as Model
+    else:
+        from llmtrain_tpu.models.gpt import GPT as Model
+    mb = POOL_CONTEXT // POOL_BLOCK_TOKENS
+    paged = Model(**common).for_paged_decoding(
+        num_blocks=num_blocks, block_tokens=POOL_BLOCK_TOKENS
+    )
+    variables = jax.eval_shape(
+        lambda: paged.init(
+            jax.random.key(0), jnp.zeros((1, 1), jnp.int32), deterministic=True,
+            positions=jnp.zeros((1,), jnp.int32),
+            block_tables=jnp.zeros((1, mb), jnp.int32),
+        )
+    )
+
+    def on_chip(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params, cache = (
+        jax.tree.map(lambda s: on_chip(*s.shape, dtype=s.dtype), variables[c])
+        for c in ("params", "cache")
+    )
+
+    def sampling(rows):
+        return (
+            on_chip(rows, dtype=jnp.uint32), on_chip(rows, dtype=jnp.float32),
+            on_chip(rows), on_chip(rows, dtype=jnp.float32),
+        )
+
+    seeds, *knobs = sampling(slots)
+    programs = {
+        "prefill": (
+            functools.partial(engine._prefill_impl, paged),
+            (params, cache, on_chip(1, 256), on_chip(1), on_chip(1), on_chip(1, mb),
+             *sampling(1)),
+        ),
+        "decode": (
+            functools.partial(engine._decode_impl, paged),
+            (params, cache, on_chip(slots), on_chip(slots), on_chip(slots, mb),
+             seeds, on_chip(slots), *knobs),
+        ),
+        "verify": (
+            functools.partial(engine._verify_impl, paged),
+            (params, cache, on_chip(slots, 4), on_chip(slots), on_chip(slots, mb)),
+        ),
+        "cow_copy": (
+            lambda _params, cache, src, dst: engine._cow_impl(cache, src, dst),
+            (params, cache, on_chip(1), on_chip(1)),
+        ),
+    }
+    leaves = {leaf.shape for leaf in jax.tree.leaves(cache)}
+    assert len(leaves) == 1
+    return programs, leaves.pop()
+
+
+def _elements(type_text: str) -> int:
+    """Element count of the largest array in an HLO result type."""
+    return max(
+        (math.prod(int(d) for d in dims.split(",") if d)
+         for dims in re.findall(r"\w+\[([\d,]*)\]", type_text)),
+        default=0,
+    )
+
+
+def _hlo_instructions(text: str):
+    """``(opcode, result type, called computation, line)`` of every
+    instruction of every computation of an HLO module's text."""
+    for line in text.splitlines():
+        head, eq, rest = line.partition(" = ")
+        if not eq or not head.lstrip().removeprefix("ROOT ").startswith("%"):
+            continue
+        # The opcode is the first `name(` outside a layout (`{...T(8,128)}`).
+        bare = re.sub(r"\{[^{}]*\}", lambda m: " " * len(m.group()), rest)
+        op = re.search(r"(?:^|\s)([a-z][\w\-]*)\(", bare)
+        if op is None:
+            continue
+        called = re.search(r"calls=%([\w.\-]+)", rest)
+        yield op.group(1), rest[: op.start()], called and called.group(1), line
+
+
+def _entry_layout(text: str):
+    """Parameter and result types (layout included) of the entry computation
+    and ``{parameter number: output index}`` of ``input_output_alias``."""
+    header = re.sub(r"/\*.*?\*/", "", text.split("\n", 1)[0])
+    signature = header.split("entry_computation_layout={", 1)[1]
+    signature = re.split(r"\}, [a-z_]+=", signature, maxsplit=1)[0]
+    params, results = signature.split(")->", 1)
+
+    def arrays(types: str) -> list[str]:
+        return re.findall(r"\w+\[[\d,]*\]\{[^{}]*\}", types)
+
+    aliased = {
+        int(param): int(out)
+        for out, param in re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    }
+    return arrays(params), arrays(results), aliased
+
+
+def _computation(text: str, name: str) -> str:
+    """The body of one named computation of an HLO module's text."""
+    start = text.index(f"%{name} (")
+    return text[start : text.index("\n}", start)]
+
+
+class TestPagedPoolKeepsItsLayout:
+    """No prefill, decode, verify or COW program copies, transposes or
+    re-tiles a pool-sized array: the layout the compiler gives a pool leaf
+    in HBM is the one its scatter, its block-table gather and the COW copy
+    use, and the donated input aliases the output in it. With the leaf
+    declared ``(num_blocks, block_tokens, kv_heads, 64)`` the compiler made
+    ``num_blocks`` the minor dimension and every call transposed the whole
+    pool three or four times (PERF.md section 6, PR 25)."""
+
+    @pytest.mark.parametrize("program", POOL_PROGRAMS)
+    @pytest.mark.parametrize("shape", list(POOL_SHAPES))
+    def test_no_pool_sized_relayout(self, one_chip, shape, program):
+        programs, leaf_shape = _pool_programs(shape, one_chip)
+        fn, shapes = programs[program]
+        text = jax.jit(fn, donate_argnums=(1,)).lower(*shapes).compile().as_text()
+        leaf = math.prod(leaf_shape)
+
+        params, results, aliased = _entry_layout(text)
+        dims = "[" + ",".join(map(str, leaf_shape)) + "]"
+        pool_params = [i for i, p in enumerate(params) if dims in p]
+        assert len(pool_params) == 2 * POOL_LAYERS
+        for i in pool_params:
+            assert i in aliased, f"pool leaf (parameter {i}) is not donated in place"
+            assert results[aliased[i]] == params[i], "the output's layout differs"
+
+        for op, result, called, line in _hlo_instructions(text):
+            if _elements(result) < leaf:
+                continue
+            in_place = op in POOL_IN_PLACE or (
+                op == "fusion"
+                and re.search(r" (scatter|dynamic-update-slice)\(", _computation(text, called))
+            )
+            assert in_place, f"pool-sized `{op}` in {shape}.{program}: {line.strip()[:300]}"
