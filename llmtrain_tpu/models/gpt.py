@@ -65,6 +65,15 @@ def _scaled_init(n_layers: int) -> nn.initializers.Initializer:
     return nn.initializers.normal(stddev=0.02 / math.sqrt(2 * n_layers))
 
 
+def scaled(x: jax.Array, scale: float) -> jax.Array:
+    """``x * scale`` computed in float32 and cast back (a bf16 multiplier
+    would carry its own rounding into every element); 1.0 returns ``x``.
+    For muP multipliers (models/falcon_h1.py)."""
+    if scale == 1.0:
+        return x
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
 logger = logging.getLogger(__name__)
 
 _TIER_MIGRATION_LOGGED = False
@@ -251,6 +260,13 @@ class CausalSelfAttention(nn.Module):
     # quant_dot_general — straight-through gradients, f32 master weights,
     # unchanged param tree. "f32" keeps the stock flax path bit-identical.
     matmul_precision: str = "f32"
+    # Width of one head where it is not d_model / n_heads (models whose
+    # attention is narrower than the residual stream: models/falcon_h1.py).
+    # 0 = d_model // n_heads.
+    head_dim: int = 0
+    # Keys are multiplied by this after their projection, before RoPE and
+    # before they are cached (a muP key multiplier). 1.0 = no multiply.
+    key_scale: float = 1.0
 
     @nn.compact
     def __call__(
@@ -262,7 +278,7 @@ class CausalSelfAttention(nn.Module):
         positions: jax.Array | None = None,
         block_tables: jax.Array | None = None,
     ) -> jax.Array:
-        head_dim = self.d_model // self.n_heads
+        head_dim = self.head_dim or self.d_model // self.n_heads
         kv_heads = self.n_kv_heads or self.n_heads
         qkv_use_bias = self.use_bias if self.qkv_bias is None else self.qkv_bias
         if self.sliding_window and self.attention in ("ring", "ulysses"):
@@ -324,6 +340,7 @@ class CausalSelfAttention(nn.Module):
                 name="kv_proj",
             )(x)
             k, v = kv[:, :, 0], kv[:, :, 1]
+        k = scaled(k, self.key_scale)
         q = nn.with_logical_constraint(q, ("batch", "length", "act_heads", "act_kv"))
         k = nn.with_logical_constraint(k, ("batch", "length", "act_heads", "act_kv"))
         v = nn.with_logical_constraint(v, ("batch", "length", "act_heads", "act_kv"))
@@ -1046,9 +1063,11 @@ class GPT(nn.Module):
     matmul_precision: str = "f32"
 
     def for_paged_decoding(
-        self, *, num_blocks: int, block_tokens: int
+        self, *, num_blocks: int, block_tokens: int, state_rows: int = 0
     ) -> "GPT":
         """Clone configured for paged-KV continuous-batching decode.
+        ``state_rows`` is the engine's offer of per-sequence state rows
+        (serving/paged_kv.py); a model without recurrent state takes none.
 
         The cache becomes a pool of ``num_blocks`` blocks of
         ``block_tokens`` positions each, shared by every in-flight

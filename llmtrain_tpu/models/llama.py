@@ -42,6 +42,7 @@ from .gpt import (
     CausalSelfAttention,
     GPTAdapter,
     _scaled_init,
+    scaled,
 )
 from .gpt_moe import GPTMoEAdapter as _GPTMoEAdapter
 
@@ -85,6 +86,59 @@ class RMSNorm(nn.Module):
         if self.offset:
             mult = 1.0 + mult
         return (norm * mult).astype(self.dtype)
+
+
+def gated_mlp(
+    h: jax.Array,
+    *,
+    d_model: int,
+    d_ff: int,
+    n_layers: int,
+    dtype: Any,
+    param_dtype: Any,
+    act: str = "silu",
+    gate_scale: float = 1.0,
+    out_scale: float = 1.0,
+) -> jax.Array:
+    """``down(act(gate(h) * gate_scale) * up(h)) * out_scale``, bias-free:
+    SwiGLU with ``act="silu"``, Gemma's GeGLU with ``"gelu_tanh"``. Call it
+    inside a block's ``@nn.compact`` method: the three ``nn.Dense`` layers
+    (``mlp_gate``, ``mlp_up``, ``mlp_down``) become the caller's own
+    children. The two scales are muP multipliers (models/falcon_h1.py),
+    applied in float32; 1.0 multiplies nothing."""
+    dense_kw = dict(use_bias=False, dtype=dtype, param_dtype=param_dtype)
+    gate = nn.Dense(
+        d_ff,
+        kernel_init=nn.with_logical_partitioning(_DENSE_INIT, ("embed", "mlp")),
+        name="mlp_gate",
+        **dense_kw,
+    )(h)
+    up = nn.Dense(
+        d_ff,
+        kernel_init=nn.with_logical_partitioning(_DENSE_INIT, ("embed", "mlp")),
+        name="mlp_up",
+        **dense_kw,
+    )(h)
+    gate = scaled(gate, gate_scale)
+    if act == "silu":
+        h = nn.silu(gate) * up
+    elif act == "gelu_tanh":
+        # Gemma's GeGLU: HF hidden_activation gelu_pytorch_tanh.
+        h = nn.gelu(gate, approximate=True) * up
+    else:
+        raise ValueError(
+            f"mlp_act {act!r} unknown; expected 'silu' or 'gelu_tanh'"
+        )
+    h = nn.with_logical_constraint(h, ("batch", "length", "act_mlp"))
+    h = nn.Dense(
+        d_model,
+        kernel_init=nn.with_logical_partitioning(
+            _scaled_init(n_layers), ("mlp", "embed")
+        ),
+        name="mlp_down",
+        **dense_kw,
+    )(h)
+    return scaled(h, out_scale)
 
 
 class LlamaBlock(nn.Module):
@@ -198,40 +252,15 @@ class LlamaBlock(nn.Module):
                 name="moe_mlp",
             )(h)
         else:
-            dense_kw = dict(
-                use_bias=False, dtype=self.dtype, param_dtype=self.param_dtype
+            h = gated_mlp(
+                h,
+                d_model=self.d_model,
+                d_ff=self.d_ff,
+                n_layers=self.n_layers,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                act=self.mlp_act,
             )
-            gate = nn.Dense(
-                self.d_ff,
-                kernel_init=nn.with_logical_partitioning(_DENSE_INIT, ("embed", "mlp")),
-                name="mlp_gate",
-                **dense_kw,
-            )(h)
-            up = nn.Dense(
-                self.d_ff,
-                kernel_init=nn.with_logical_partitioning(_DENSE_INIT, ("embed", "mlp")),
-                name="mlp_up",
-                **dense_kw,
-            )(h)
-            if self.mlp_act == "silu":
-                h = nn.silu(gate) * up
-            elif self.mlp_act == "gelu_tanh":
-                # Gemma's GeGLU: HF hidden_activation gelu_pytorch_tanh.
-                h = nn.gelu(gate, approximate=True) * up
-            else:
-                raise ValueError(
-                    f"mlp_act {self.mlp_act!r} unknown; expected 'silu' "
-                    "or 'gelu_tanh'"
-                )
-            h = nn.with_logical_constraint(h, ("batch", "length", "act_mlp"))
-            h = nn.Dense(
-                self.d_model,
-                kernel_init=nn.with_logical_partitioning(
-                    _scaled_init(self.n_layers), ("mlp", "embed")
-                ),
-                name="mlp_down",
-                **dense_kw,
-            )(h)
         h = nn.Dropout(self.dropout)(h, deterministic=deterministic)
         x = x + h
         return nn.with_logical_constraint(x, ("batch", "length", "act_embed"))
@@ -299,12 +328,13 @@ class Llama(nn.Module):
     router_top_k: int = 1
 
     def for_paged_decoding(
-        self, *, num_blocks: int, block_tokens: int
+        self, *, num_blocks: int, block_tokens: int, state_rows: int = 0
     ) -> "Llama":
         """Clone configured for paged-KV continuous-batching decode (the
         GPT.for_paged_decoding contract; serving/engine.py dispatches on
-        this method's presence). RoPE needs no special casing — the paged
-        attention rotates q/k by its per-row absolute positions — but the
+        this method's presence; ``state_rows`` is offered and not taken).
+        RoPE needs no special casing — the paged attention rotates q/k by
+        its per-row absolute positions — but the
         sliding-window ring and the int8 cache keep their named raise, so
         Mistral-with-window configs fall back to ``serving.mode: simple``
         with an actionable error instead of silently wrong K/V."""

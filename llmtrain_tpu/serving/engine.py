@@ -32,10 +32,21 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   about to waste, counted where the padding happens: ``prompt_tokens`` and
   ``bucket`` of a prefill, ``kv_live_tokens`` and ``kv_gathered_tokens``
   of a decode. Without a factory nothing is recorded.
+* **Two kinds of cache leaf, told apart in the tree.** A model with a
+  recurrent mixer (models/falcon_h1.py) keeps, beside the block pool,
+  leaves whose leading axis is a sequence's *state row* (their variable
+  names begin with ``state_``; serving/paged_kv.py owns the rows). When
+  the cache tree holds such leaves, ``prefill`` and ``decode`` also stage
+  each row's state row id (and ``prefill`` hands the model ``true_len``),
+  the ``stage`` span also carries ``state_rows`` / ``state_bytes`` (decode)
+  and ``state_bytes`` / ``scan_chunks`` (prefill), the COW copy leaves
+  those leaves alone, and the prefix cache and ``verify`` are refused by
+  name. When it holds none, nothing of this is staged, compiled or counted.
 """
 
 from __future__ import annotations
 
+import math
 from contextlib import nullcontext
 from typing import Any
 
@@ -138,11 +149,14 @@ def _prefill_impl(
     temps: jax.Array,
     top_ks: jax.Array,
     top_ps: jax.Array,
+    state_rows: jax.Array | None = None,  # (1,) int32, models with state leaves
 ) -> tuple[Any, jax.Array]:
     # `offsets` starts the row mid-sequence: 0 for a whole prompt, the
     # reused-prefix length under shared-prefix reuse, the chunk start
     # under chunked prefill. The suffix attends earlier positions through
     # the block table (cached K/V), exactly like a multi-token decode.
+    # A recurrent state has to be told where the padding starts too.
+    state = {} if state_rows is None else {"state_rows": state_rows, "true_len": true_len}
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
         prompt,
@@ -150,6 +164,7 @@ def _prefill_impl(
         positions=offsets,
         block_tables=block_tables,
         mutable=["cache"],
+        **state,
     )
     # Sample at the LAST REAL position; padded positions' K/V landed in
     # the null block and padded-row logits are garbage nobody reads.
@@ -174,7 +189,9 @@ def _decode_impl(
     temps: jax.Array,
     top_ks: jax.Array,
     top_ps: jax.Array,
+    state_rows: jax.Array | None = None,  # (B,) int32, models with state leaves
 ) -> tuple[Any, jax.Array]:
+    state = {} if state_rows is None else {"state_rows": state_rows}
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
         tokens[:, None],
@@ -182,6 +199,7 @@ def _decode_impl(
         positions=positions,
         block_tables=block_tables,
         mutable=["cache"],
+        **state,
     )
     tok = _sample_rows(
         logits[:, -1].astype(jnp.float32), seeds, emit_idx, temps, top_ks, top_ps
@@ -216,14 +234,24 @@ def _verify_impl(
     ).astype(jnp.int32)
 
 
+def is_state_leaf(path: tuple) -> bool:
+    """Whether a cache leaf is indexed by state row, not by pool block:
+    its variable's name (the last key of its path) begins with ``state_``."""
+    return str(getattr(path[-1], "key", "")).startswith("state_")
+
+
 def _cow_impl(cache: Any, src: jax.Array, dst: jax.Array) -> Any:
     """Copy-on-write device copy: pool block ``src`` → ``dst`` across
     every paged cache leaf. Only the leading (block) axis is indexed, so
     the copy holds for any leaf shape; leaves are ``(num_blocks, bt //
     fold, fold * kv_heads * head_dim)`` with a lane-dense minor dimension
     (``_paged_decode_attention``), in which a block is contiguous and the
-    donated pool is updated in place."""
-    return jax.tree.map(lambda leaf: leaf.at[dst].set(leaf[src]), cache)
+    donated pool is updated in place. A state leaf's leading axis is no
+    block index: it passes through untouched."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf if is_state_leaf(path) else leaf.at[dst].set(leaf[src]),
+        cache,
+    )
 
 
 class PagedDecodeEngine:
@@ -289,11 +317,12 @@ class PagedDecodeEngine:
                 f"prompt bucket ({self.prompt_buckets[-1]}) — chunks must "
                 "pad into an existing bucket (the bounded-compile contract)"
             )
+        # One state row a slot plus the null row 0, offered to every model;
+        # only one with a recurrent state declares leaves that use them.
         self.decode_model = model.for_paged_decoding(
-            num_blocks=num_blocks, block_tokens=self.block_tokens
-        )
-        self.pool = PagedKVPool(
-            num_blocks, self.block_tokens, prefix_cache=prefix_cache
+            num_blocks=num_blocks,
+            block_tokens=self.block_tokens,
+            state_rows=1 + self.max_batch_slots,
         )
 
         # Zero cache pytree from an eval_shape trace — no param init work
@@ -310,6 +339,31 @@ class PagedDecodeEngine:
             )
         )
         self._cache_struct = var_shapes["cache"]
+        state_leaves = [
+            leaf
+            for path, leaf in jax.tree_util.tree_leaves_with_path(self._cache_struct)
+            if is_state_leaf(path)
+        ]
+        for leaf in state_leaves:
+            if leaf.shape[0] != 1 + self.max_batch_slots:
+                raise ValueError(
+                    f"state leaf of shape {leaf.shape} does not hold one row a "
+                    f"slot plus the null row ({1 + self.max_batch_slots})"
+                )
+        # Bytes of recurrent state one sequence owns over all layers;
+        # 0 = the model has no state leaves and nothing below stages any.
+        self.state_bytes_per_row = sum(
+            math.prod(leaf.shape[1:]) * leaf.dtype.itemsize for leaf in state_leaves
+        )
+        self._state_leaves = len(state_leaves)
+        self._scan_chunk = int(getattr(self.decode_model, "state_scan_chunk", 0))
+        # Raises by name on prefix_cache with state rows (paged_kv.py).
+        self.pool = PagedKVPool(
+            num_blocks,
+            self.block_tokens,
+            prefix_cache=prefix_cache,
+            state_rows=self.max_batch_slots if state_leaves else 0,
+        )
         self._cache = jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), self._cache_struct
         )
@@ -418,6 +472,7 @@ class PagedDecodeEngine:
         top_p: float | None,
         offset: int = 0,  # absolute position of prompt_ids[0]
         params: Any | None = None,  # hot-swap: admitted-epoch params
+        state_row: int = 0,  # the sequence's state row (models with state)
     ) -> int:
         """Run one joining sequence's prompt slab; returns the token
         sampled at its last real position (the first output token when
@@ -426,7 +481,13 @@ class PagedDecodeEngine:
         tp = int(prompt_ids.shape[0])
         tb = bucket_for(tp, self.prompt_buckets)
         self._note_shape(self._prefill_shapes, tb, "prefill", tb)
-        with self._span("stage", "prefill", prompt_tokens=tp, bucket=tb):
+        counted = {"prompt_tokens": tp, "bucket": tb}
+        if self.state_bytes_per_row:
+            # The row is read (unless the slab starts the sequence) and written.
+            counted["state_bytes"] = (2 if offset else 1) * self.state_bytes_per_row
+            if self._scan_chunk:
+                counted["scan_chunks"] = -(-tb // self._scan_chunk)
+        with self._span("stage", "prefill", **counted):
             prompt = np.zeros((1, tb), np.int32)
             prompt[0, :tp] = prompt_ids
             staged = (
@@ -439,6 +500,8 @@ class PagedDecodeEngine:
                 jnp.asarray([0 if top_k is None else top_k], jnp.int32),
                 jnp.asarray([0.0 if top_p is None else top_p], jnp.float32),
             )
+            if self.state_bytes_per_row:
+                staged += (jnp.asarray([int(state_row)], jnp.int32),)
         try:
             with self._span("dispatch", "prefill"):
                 cache, tok = self._prefill_jit(
@@ -458,9 +521,10 @@ class PagedDecodeEngine:
 
         Each row dict: ``token`` (last emitted), ``position`` (its
         absolute position), ``table`` (padded physical ids), ``seed``,
-        ``emit_idx``, ``temperature``, ``top_k``, ``top_p``. The batch is
-        padded to a batch bucket with null-table greedy rows whose output
-        is discarded.
+        ``emit_idx``, ``temperature``, ``top_k``, ``top_p``, and for a
+        model with state leaves ``state_row``. The batch is padded to a
+        batch bucket with null-table, null-state-row greedy rows whose
+        output is discarded.
         """
         n = len(rows)
         if n == 0:
@@ -477,12 +541,15 @@ class PagedDecodeEngine:
 
         # Every padded row gathers its whole block table
         # (``_paged_decode_attention``), whatever the real rows attend.
-        with self._span(
-            "stage",
-            "decode",
-            kv_live_tokens=sum(int(r["position"]) + 1 for r in rows),
-            kv_gathered_tokens=bb * mb * self.block_tokens,
-        ):
+        counted = {
+            "kv_live_tokens": sum(int(r["position"]) + 1 for r in rows),
+            "kv_gathered_tokens": bb * mb * self.block_tokens,
+        }
+        if self.state_bytes_per_row:
+            # What the call must move: each real row's state, read and written.
+            counted["state_rows"] = n
+            counted["state_bytes"] = 2 * n * self.state_bytes_per_row
+        with self._span("stage", "decode", **counted):
             tables = np.zeros((bb, mb), np.int32)
             for i, r in enumerate(rows):
                 tables[i] = r["table"]
@@ -501,6 +568,8 @@ class PagedDecodeEngine:
                 jnp.asarray(col("top_k", 0, np.int32)),
                 jnp.asarray(col("top_p", 0.0, np.float32)),
             )
+            if self.state_bytes_per_row:
+                staged += (jnp.asarray(col("state_row", 0, np.int32)),)
         try:
             with self._span("dispatch", "decode"):
                 cache, tok = self._decode_jit(
@@ -529,6 +598,12 @@ class PagedDecodeEngine:
         positions are simply overwritten when the corrected tokens are
         fed (position p maps to a fixed (block, slot), and queries never
         see past their own position — cursorless rollback)."""
+        if self.state_bytes_per_row:
+            raise ValueError(
+                "verify (speculative decoding) cannot serve a model with "
+                "recurrent state: rejected draft tokens would have moved the "
+                "state and there is no rollback of a state row yet"
+            )
         n = len(rows)
         if n == 0:
             return []
@@ -585,18 +660,20 @@ class PagedDecodeEngine:
         every later prefill/decode would die on "Array has been deleted" —
         one transient device error would wedge the server for good.
         Trace-time failures never donate: a still-live cache (and the
-        in-flight KV it holds) is kept untouched; a deleted one is rebuilt
-        zeroed and ``cache_epoch`` bumped so the scheduler fails the
-        in-flight sequences whose KV went with it.
+        in-flight KV it holds) is kept untouched; every deleted leaf, pool
+        or state, is rebuilt zeroed (a live one is kept) and ``cache_epoch``
+        bumped so the scheduler fails the in-flight sequences whose KV or
+        recurrent state went with it.
         """
-        leaves = jax.tree.leaves(self._cache)
-        if any(
-            leaf.is_deleted()
-            for leaf in leaves
-            if isinstance(leaf, jax.Array)
-        ):
+
+        def deleted(leaf: Any) -> bool:
+            return isinstance(leaf, jax.Array) and leaf.is_deleted()
+
+        if any(deleted(leaf) for leaf in jax.tree.leaves(self._cache)):
             self._cache = jax.tree.map(
-                lambda s: jnp.zeros(s.shape, s.dtype), self._cache_struct
+                lambda leaf, s: jnp.zeros(s.shape, s.dtype) if deleted(leaf) else leaf,
+                self._cache,
+                self._cache_struct,
             )
             self.cache_epoch += 1
 
@@ -641,7 +718,7 @@ class PagedDecodeEngine:
             sds((1,), jnp.float32),    # temps
             sds((1,), jnp.int32),      # top_ks
             sds((1,), jnp.float32),    # top_ps
-        )
+        ) + ((sds((1,), jnp.int32),) if self.state_bytes_per_row else ())
         decode_args = (
             param_structs,
             cache_structs,
@@ -653,7 +730,7 @@ class PagedDecodeEngine:
             sds((bb,), jnp.float32),   # temps
             sds((bb,), jnp.int32),     # top_ks
             sds((bb,), jnp.float32),   # top_ps
-        )
+        ) + ((sds((bb,), jnp.int32),) if self.state_bytes_per_row else ())
         profiles: list[dict[str, Any]] = []
         for name, jitted, args in (
             (f"prefill_T{tb}", self._prefill_jit, prefill_args),
@@ -702,6 +779,10 @@ class PagedDecodeEngine:
             stats["decode_programs"] = len(self._decode_shapes)
             stats["verify_programs"] = len(self._verify_shapes)
             stats["cow_programs"] = 1 if self._cow_used else 0
+        if self.state_bytes_per_row:
+            stats["state_leaves"] = self._state_leaves
+            stats["state_rows"] = self.pool.state_rows
+            stats["state_bytes_per_row"] = self.state_bytes_per_row
         stats["within_budget"] = (
             stats["prefill_programs"]
             + stats["decode_programs"]
@@ -719,7 +800,9 @@ class PagedDecodeEngine:
         them explicitly to prefill/decode/verify instead — the jitted
         programs take params as a traced argument, so neither path
         recompiles. The prefix cache must be invalidated by the caller
-        (scheduler) — cached K/V is a function of the OLD params."""
+        (scheduler) — cached K/V is a function of the OLD params. State
+        rows need nothing: a row belongs to one request, which decodes on
+        the params it was admitted under until it retires."""
         self.params = params
 
 

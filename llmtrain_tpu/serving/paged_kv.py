@@ -38,6 +38,18 @@ throughput/$ is decided):
   shared binds consume reservation slots without consuming free blocks,
   hence ``free + evictable >= outstanding unbound reservations`` always.
 
+* **state rows** (only where the engine's cache tree holds recurrent
+  state leaves, ``state_rows > 0``): a model with a recurrent mixer
+  (models/falcon_h1.py) keeps one fixed-size state per sequence, which no
+  block can hold. Row ids ``1..state_rows`` index the leading axis of
+  those leaves (row 0 is the null row of padded batch rows, as block 0 is
+  the null block). :meth:`try_reserve` hands a sequence one row beside its
+  block budget — both or neither, so a later step can never fail for want
+  of a row — and :meth:`release` takes it back. A row's identity is the
+  request's for its whole life, whatever position it holds in a decode
+  batch. Its contents are never cleared here: a prefill at offset 0 starts
+  from zeros whatever the row held (the model's contract).
+
 Pure host-side Python (no jax): allocation is scheduler-thread-only and
 lock-free here — the scheduler serializes all calls.
 """
@@ -114,6 +126,8 @@ class BlockTable:
     # Everything past it is exclusively owned. COW and registration
     # preserve the leading-run shape.
     shared: int = 0
+    # The sequence's recurrent-state row (0 = none: the null row).
+    state_row: int = 0
 
     @property
     def allocated(self) -> int:
@@ -145,6 +159,7 @@ class PagedKVPool:
         block_tokens: int,
         *,
         prefix_cache: bool = False,
+        state_rows: int = 0,
     ) -> None:
         if num_blocks < 2:
             raise ValueError(
@@ -161,6 +176,18 @@ class PagedKVPool:
         self._tables: set[int] = set()  # live table object ids (double-free guard)
         self.peak_allocated = 0
         self.peak_reserved = 0
+        # ---- recurrent-state rows (docstring: state rows); LIFO, row 0
+        # excluded (null row). Empty for a model without state leaves.
+        if state_rows < 0:
+            raise ValueError(f"state_rows must be >= 0, got {state_rows}")
+        if state_rows and prefix_cache:
+            raise ValueError(
+                "prefix_cache cannot serve a model with recurrent state: a "
+                "token-block hash stands for K/V blocks, not for the state "
+                "a sequence had reached at the block's end"
+            )
+        self.state_rows = int(state_rows)
+        self._free_rows = list(range(self.state_rows, 0, -1))
         # ---- content-addressed prefix cache (docstring: shared prefixes)
         self.prefix_cache_enabled = bool(prefix_cache)
         self._index: dict[str, int] = {}  # chain hash -> physical block
@@ -220,10 +247,12 @@ class PagedKVPool:
         have to evict mid-flight.
         """
         need = self.blocks_needed(total_tokens)
-        if need > self._available:
+        if need > self._available or (self.state_rows and not self._free_rows):
             return None
         self._available -= need
         table = BlockTable(reserved=need, block_tokens=self.block_tokens)
+        if self.state_rows:
+            table.state_row = self._free_rows.pop()
         self._tables.add(id(table))
         self.peak_reserved = max(
             self.peak_reserved, (self.num_blocks - 1) - self._available
@@ -298,6 +327,9 @@ class PagedKVPool:
             else:
                 self._free.append(blk)
         self._available += table.reserved
+        if table.state_row:
+            self._free_rows.append(table.state_row)
+            table.state_row = 0
         table.blocks = []
         table.reserved = 0
         table.shared = 0
@@ -491,6 +523,9 @@ class PagedKVPool:
             "peak_reserved_blocks": self.peak_reserved,
             "active_sequences": len(self._tables),
         }
+        if self.state_rows:
+            out["state_rows_free"] = len(self._free_rows)
+            out["state_rows_in_use"] = self.state_rows - len(self._free_rows)
         if self.prefix_cache_enabled:
             out["prefix_cached_blocks"] = self.cached_blocks
             out["prefix_hits"] = self.prefix_hits

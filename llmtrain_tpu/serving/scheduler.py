@@ -218,6 +218,13 @@ class ContinuousBatchingScheduler:
                 "batched speculative serving needs the TARGET PagedDecodeEngine "
                 "too (draft_engine alone cannot verify)"
             )
+        if policy == "speculative" and any(
+            getattr(eng, "state_bytes_per_row", 0) for eng in (engine, draft_engine)
+        ):
+            raise ValueError(
+                "the speculative policy cannot serve a model with recurrent "
+                "state (no rollback of a state row yet); use policy 'paged'"
+            )
         if policy == "speculative" and engine is not None and engine.prefill_chunk:
             raise ValueError(
                 "chunked prefill is a paged-policy feature; the speculative "
@@ -675,6 +682,8 @@ class ContinuousBatchingScheduler:
         final = end == row.prompt_len
         engine.pool.grow(row.table, end)
         extra = {"rid": row.req.rid} if row.req.rid else {}
+        # Only a table that owns a state row names one (paged_kv.py).
+        state = {"state_row": row.table.state_row} if row.table.state_row else {}
         try:
             with self._traced_span(
                 row.req,
@@ -693,6 +702,7 @@ class ContinuousBatchingScheduler:
                     top_p=row.req.top_p,
                     offset=start,
                     params=self._params_by_epoch[row.epoch],
+                    **state,
                 )
         except Exception as exc:  # noqa: BLE001 — fail THIS request only
             self._drop_row(row)
@@ -892,6 +902,7 @@ class ContinuousBatchingScheduler:
                             "temperature": r.req.temperature,
                             "top_k": 0 if r.req.top_k is None else r.req.top_k,
                             "top_p": 0.0 if r.req.top_p is None else r.req.top_p,
+                            "state_row": r.table.state_row,
                         }
                     )
                 rids = [r.req.rid for r in group if r.req.rid]

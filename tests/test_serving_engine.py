@@ -734,3 +734,257 @@ class TestLoadgen:
         assert block["occupancy"]["peak"] >= 2
         for req in requests:
             assert req.tokens == _reference(model, params, req)
+
+
+# ---------------------------------------------------------------------------
+# recurrent-state rows beside the block pool (models/falcon_h1.py)
+# ---------------------------------------------------------------------------
+
+
+def _falcon(dtype=jnp.float32):
+    """2 layers, GQA (4 query heads on 2 KV heads of 8), 2 groups, chunk 8:
+    the Falcon-H1 block at a size the tier-1 gate compiles in seconds."""
+    from llmtrain_tpu.models.falcon_h1 import FalconH1
+
+    return FalconH1(
+        vocab_size=VOCAB, block_size=64, d_model=32, n_layers=2, n_heads=4, d_ff=48, dropout=0.0,
+        n_kv_heads=2, head_dim=8, mamba_d_ssm=32, mamba_d_state=8, mamba_n_heads=4, mamba_n_groups=2,
+        mamba_chunk_size=8, embedding_multiplier=5.0, key_multiplier=0.4, attention_out_multiplier=0.6,
+        ssm_in_multiplier=0.5, ssm_out_multiplier=0.8, ssm_multipliers=(0.4, 0.3, 0.2, 0.5, 0.35),
+        mlp_multipliers=(0.6, 0.5), lm_head_multiplier=0.3, dtype=dtype,
+    )
+
+
+@pytest.fixture(scope="module")
+def falcon_model():
+    model = _falcon()
+    params = _unboxed_params(model)
+    # Norm scales and the conv away from their neutral start, so a row that
+    # read another's state, or a stale one, would show.
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(7), len(leaves))
+    leaves = [x + 0.05 * jax.random.normal(k, x.shape, x.dtype) for x, k in zip(leaves, keys)]
+    return model, jax.tree.unflatten(tree, leaves)
+
+
+def _full_forward_tokens(model, params, req: ServeRequest) -> tuple[list[int], float]:
+    """Greedy tokens of the model's FULL forward (no cache) at the served
+    positions, teacher forced on what was served, and the widest gap by
+    which a served token's logit lies under that forward's best."""
+    seq = np.concatenate([req.prompt_ids, np.asarray(req.tokens, np.int32)])
+    ids = np.zeros((1, model.block_size), np.int32)
+    ids[0, : len(seq)] = seq
+    logits = np.asarray(model.apply({"params": params}, jnp.asarray(ids)))[0]
+    at = logits[len(req.prompt_ids) - 1 : len(seq) - 1]
+    gap = at.max(-1) - at[np.arange(len(req.tokens)), req.tokens]
+    return [int(t) for t in at.argmax(-1)], float(gap.max())
+
+
+def _state_engine(model, params, **kw):
+    defaults = dict(block_tokens=8, max_batch_slots=3, prompt_buckets=[8, 16, 32], batch_buckets=[3])
+    return PagedDecodeEngine(model, params, **{**defaults, **kw})
+
+
+def _falcon_requests(rng, shapes):
+    return [
+        ServeRequest(prompt_ids=rng.integers(0, VOCAB, n).astype(np.int32), max_new_tokens=m,
+                     temperature=0.0, eos_token_id=None, seed=i)
+        for i, (n, m) in enumerate(shapes)
+    ]
+
+
+class TestRecurrentStateRows:
+    def test_pool_hands_out_a_row_with_the_reservation_and_takes_it_back(self):
+        pool = PagedKVPool(64, 8, state_rows=2)
+        a, b = pool.try_reserve(16), pool.try_reserve(16)
+        assert {a.state_row, b.state_row} == {1, 2}  # row 0 is the null row
+        before = pool.available_blocks
+        assert pool.try_reserve(8) is None  # blocks there are, a row there is not
+        assert pool.available_blocks == before  # and nothing was reserved for it
+        assert pool.stats()["state_rows_in_use"] == 2 and pool.stats()["state_rows_free"] == 0
+        row = a.state_row
+        pool.release(a)
+        assert a.state_row == 0 and pool.try_reserve(8).state_row == row
+        assert "state_rows_free" not in PagedKVPool(8, 8).stats()  # a model without state: no rows, no keys
+        assert PagedKVPool(8, 8).try_reserve(8).state_row == 0
+        with pytest.raises(ValueError, match="prefix_cache cannot serve a model with recurrent state"):
+            PagedKVPool(8, 8, prefix_cache=True, state_rows=2)
+
+    def test_served_tokens_agree_with_the_full_forward_as_rows_join_and_retire(self, falcon_model):
+        """Seven requests on three slots: rows join and retire at different
+        ticks, decode batches are compacted (a row's index is not its
+        identity), and every retired row's state row goes to a later prompt.
+        float32 throughout, so the served tokens ARE the full forward's
+        greedy tokens (gap 0), not merely close."""
+        model, params = falcon_model
+        engine = _state_engine(model, params)
+        assert engine.state_bytes_per_row == 2 * (3 * 64 * 4 + 4 * 8 * 8 * 4)
+        spans = []
+
+        class Spans:
+            def __call__(self, name, **args):
+                spans.append((name, args))
+                from contextlib import nullcontext
+
+                return nullcontext()
+
+        engine.span_factory = Spans()
+        scheduler = ContinuousBatchingScheduler(engine)
+        reqs = _falcon_requests(
+            np.random.default_rng(0), [(5, 6), (17, 9), (9, 3), (30, 12), (3, 20), (12, 5), (8, 8)]
+        )
+        for r in reqs:
+            scheduler.submit(r)
+        _drain(scheduler, reqs)
+        for r in reqs:
+            assert r.finish_reason == "length", r.error
+            want, gap = _full_forward_tokens(model, params, r)
+            assert r.tokens == want and gap == 0.0
+        stats = engine.pool.stats()
+        assert stats["state_rows_free"] == 3 and stats["allocated_blocks"] == 0
+        assert scheduler.stats()["kv_pool"]["state_rows_in_use"] == 0
+        assert engine.compile_stats()["within_budget"] and engine.compile_stats()["state_leaves"] == 4
+        # The counters the benchmark reads, on the stage span of each call.
+        decode = [a for n, a in spans if n == "serve/engine.stage" and a["call"] == "decode"]
+        prefill = [a for n, a in spans if n == "serve/engine.stage" and a["call"] == "prefill"]
+        assert decode and all(a["state_bytes"] == 2 * a["state_rows"] * engine.state_bytes_per_row for a in decode)
+        assert max(a["state_rows"] for a in decode) == 3 and "kv_live_tokens" in decode[0]
+        assert all(a["scan_chunks"] == a["bucket"] // 8 and a["state_bytes"] == engine.state_bytes_per_row
+                   for a in prefill)
+
+    def test_a_reused_state_row_gives_what_a_fresh_engine_gives(self, falcon_model):
+        model, params = falcon_model
+        rng = np.random.default_rng(1)
+        first, second = _falcon_requests(rng, [(20, 10), (11, 7)])
+        engine = _state_engine(model, params, max_batch_slots=1, batch_buckets=[1])
+        scheduler = ContinuousBatchingScheduler(engine)
+        scheduler.submit(first)
+        _drain(scheduler, [first])
+        dirty = [np.asarray(leaf[1]) for leaf in jax.tree.leaves(engine._cache) if leaf.shape[0] == 2]
+        assert any(np.abs(d).max() > 0 for d in dirty)  # the one row holds the first request's state
+        scheduler.submit(second)
+        _drain(scheduler, [second])
+        fresh = ServeRequest(prompt_ids=second.prompt_ids, max_new_tokens=7, temperature=0.0, seed=1)
+        other = ContinuousBatchingScheduler(_state_engine(model, params, max_batch_slots=1, batch_buckets=[1]))
+        other.submit(fresh)
+        _drain(other, [fresh])
+        assert second.tokens == fresh.tokens == _full_forward_tokens(model, params, second)[0]
+
+    def test_chunked_prefill_carries_the_state_from_chunk_to_chunk(self, falcon_model):
+        model, params = falcon_model
+        shapes = [(30, 6), (13, 4), (7, 5)]
+        whole = _falcon_requests(np.random.default_rng(2), shapes)
+        chunked = _falcon_requests(np.random.default_rng(2), shapes)
+        for reqs, chunk in ((whole, 0), (chunked, 8)):
+            scheduler = ContinuousBatchingScheduler(_state_engine(model, params, prefill_chunk=chunk))
+            for r in reqs:
+                scheduler.submit(r)
+            _drain(scheduler, reqs)
+        for a, b in zip(whole, chunked):
+            assert a.tokens == b.tokens == _full_forward_tokens(model, params, a)[0]
+
+    def test_cow_copy_and_recovery_follow_the_two_kinds_of_leaf(self, falcon_model):
+        model, params = falcon_model
+        engine = _state_engine(model, params)
+        scheduler = ContinuousBatchingScheduler(engine)
+        req = _falcon_requests(np.random.default_rng(3), [(12, 3)])[0]
+        scheduler.submit(req)
+        scheduler.step()
+
+        def split(cache):
+            flat = jax.tree_util.tree_leaves_with_path(cache)
+            state = {jax.tree_util.keystr(p): np.asarray(x) for p, x in flat if "state_" in jax.tree_util.keystr(p)}
+            pool = {jax.tree_util.keystr(p): np.asarray(x) for p, x in flat if "state_" not in jax.tree_util.keystr(p)}
+            return state, pool
+
+        state, pool = split(engine._cache)
+        assert len(state) == 4 and len(pool) == 4
+        engine.cow_copy(1, 2)  # block 1 -> 2 in every POOL leaf; a state leaf's rows 1, 2 are sequences
+        state_after, pool_after = split(engine._cache)
+        for name, leaf in state.items():
+            np.testing.assert_array_equal(state_after[name], leaf)
+        for name, leaf in pool.items():
+            np.testing.assert_array_equal(pool_after[name][2], leaf[1])
+        # A failed call that consumed only SOME leaves: those are rebuilt
+        # zeroed, the others kept, and the epoch tells the scheduler.
+        leaves = jax.tree_util.tree_leaves_with_path(engine._cache)
+        for path, leaf in leaves:
+            if "state_ssm" in jax.tree_util.keystr(path):
+                leaf.delete()
+        engine._recover_cache_after_error()
+        assert engine.cache_epoch == 1
+        rebuilt_state, rebuilt_pool = split(engine._cache)
+        for name, leaf in rebuilt_state.items():
+            if "state_ssm" in name:
+                assert leaf.shape == state[name].shape and not leaf.any()
+            else:
+                np.testing.assert_array_equal(leaf, state_after[name])
+        for name, leaf in rebuilt_pool.items():
+            np.testing.assert_array_equal(leaf, pool_after[name])
+        engine._recover_cache_after_error()  # nothing deleted: nothing to do
+        assert engine.cache_epoch == 1
+
+    def test_hot_swap_holds_a_row_on_the_params_it_was_admitted_under(self, falcon_model):
+        model, params = falcon_model
+        new_params = jax.tree.map(lambda x: x * 1.1, params)
+        engine = _state_engine(model, params)
+        scheduler = ContinuousBatchingScheduler(engine)
+        old, new = _falcon_requests(np.random.default_rng(4), [(10, 12), (10, 6)])
+        scheduler.submit(old)
+        scheduler.step()
+        scheduler.hot_swap(new_params)
+        scheduler.submit(new)
+        _drain(scheduler, [old, new])
+        assert scheduler.hot_swaps == 1
+        assert old.tokens == _full_forward_tokens(model, params, old)[0]
+        assert new.tokens == _full_forward_tokens(model, new_params, new)[0]
+        assert engine.pool.stats()["state_rows_free"] == 3
+
+    def test_what_the_state_cannot_follow_is_refused_by_name(self, falcon_model):
+        model, params = falcon_model
+        with pytest.raises(ValueError, match="prefix_cache cannot serve a model with recurrent state"):
+            _state_engine(model, params, prefix_cache=True)
+        engine = _state_engine(model, params)
+        with pytest.raises(ValueError, match="verify .* recurrent state"):
+            engine.verify([{"tokens": [1, 2], "position": 0, "table": [0] * 8}], width=2)
+        with pytest.raises(ValueError, match="speculative policy cannot serve a model with recurrent state"):
+            ContinuousBatchingScheduler(
+                engine, policy="speculative", model=model, params=params,
+                draft_model=model, draft_params=params, draft_engine=_state_engine(model, params),
+            )
+        with pytest.raises(ValueError, match="no linear decode cache"):
+            generate(model, params, jnp.zeros((1, 4), jnp.int32), max_new_tokens=2, temperature=0.0)
+        with pytest.raises(ValueError, match="state_rows >= 2"):
+            model.for_paged_decoding(num_blocks=8, block_tokens=8)
+
+
+class TestModelsWithoutState:
+    @pytest.mark.parametrize("name", ["gpt-row32-fold4", "llama-gqa-row128"])
+    def test_staged_arguments_are_what_they_were(self, name):
+        """A model without state leaves: the engine stages, compiles and
+        counts exactly what it did before state rows existed."""
+        model = LAYOUT_MODELS[name]()
+        engine = _engine(model, _unboxed_params(model))
+        assert engine.state_bytes_per_row == 0 and engine.pool.state_rows == 0
+        calls, spans = {}, []
+        real_prefill, real_decode = engine._prefill_jit, engine._decode_jit
+        engine._prefill_jit = lambda p, c, *rest: calls.setdefault("prefill", rest) and real_prefill(p, c, *rest)
+        engine._decode_jit = lambda p, c, *rest: calls.setdefault("decode", rest) and real_decode(p, c, *rest)
+        engine.span_factory = lambda n, **a: (spans.append((n, a)), __import__("contextlib").nullcontext())[1]
+        scheduler = ContinuousBatchingScheduler(engine)
+        req = ServeRequest(prompt_ids=np.asarray([1, 2, 3], np.int32), max_new_tokens=3, seed=0)
+        scheduler.submit(req)
+        _drain(scheduler, [req])
+        assert [(a.shape, str(a.dtype)) for a in calls["prefill"]] == [
+            ((1, 8), "int32"), ((1,), "int32"), ((1,), "int32"), ((1, 4), "int32"),
+            ((1,), "uint32"), ((1,), "float32"), ((1,), "int32"), ((1,), "float32"),
+        ]
+        assert [(a.shape, str(a.dtype)) for a in calls["decode"]] == [
+            ((2,), "int32"), ((2,), "int32"), ((2, 4), "int32"), ((2,), "uint32"),
+            ((2,), "int32"), ((2,), "float32"), ((2,), "int32"), ((2,), "float32"),
+        ]
+        stage = [a for n, a in spans if n == "serve/engine.stage"]
+        assert {k for a in stage for k in a} == {
+            "call", "prompt_tokens", "bucket", "kv_live_tokens", "kv_gathered_tokens"
+        }
+        assert "state_leaves" not in engine.compile_stats() and "state_rows_free" not in engine.pool.stats()

@@ -454,6 +454,15 @@ def _computation(text: str, name: str) -> str:
     return text[start : text.index("\n}", start)]
 
 
+def _unfused_computations(text: str):
+    """``(name, body)`` of every computation that is not the body of a
+    fusion: what a fused computation holds are values inside one kernel,
+    not arrays in memory."""
+    for match in re.finditer(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", text, flags=re.M):
+        if not match.group(1).startswith("fused_computation"):
+            yield match.group(1), text[match.start() : text.index("\n}", match.start())]
+
+
 class TestPagedPoolKeepsItsLayout:
     """No prefill, decode, verify or COW program copies, transposes or
     re-tiles a pool-sized array: the layout the compiler gives a pool leaf
@@ -487,3 +496,113 @@ class TestPagedPoolKeepsItsLayout:
                 and re.search(r" (scatter|dynamic-update-slice)\(", _computation(text, called))
             )
             assert in_place, f"pool-sized `{op}` in {shape}.{program}: {line.strip()[:300]}"
+
+
+# -- recurrent-state rows beside the pool (models/falcon_h1.py, PR 26) --------
+
+STATE_SLOTS = 96
+STATE_LAYERS = 2
+
+
+@pytest.fixture(scope="module")
+def state_programs(one_chip):
+    """Decode (96 rows) and prefill (one prompt of 640) of the Falcon-H1
+    configuration the benchmark runs, at its published widths and whole
+    vocabulary, 2 layers, as ``PagedDecodeEngine`` jits them; abstract
+    shapes only."""
+    import functools
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmarks.reference import falcon_h1 as ref
+    from llmtrain_tpu.config.schemas import RunConfig
+    from llmtrain_tpu.models.lora import build_adapter
+    from llmtrain_tpu.registry import initialize_registries
+    from llmtrain_tpu.serving import engine
+
+    cfg = json.loads((root / "benchmarks/configs/falcon-h1-34b.json").read_text())
+    cfg["num_hidden_layers"] = STATE_LAYERS
+    initialize_registries()
+    run = RunConfig.model_validate({
+        "schema_version": 1, "run": {"name": "aot", "seed": 1, "device": "cpu"}, "model": ref.program_model(cfg),
+        "data": {"name": "dummy_text"}, "trainer": {"max_steps": 1, "micro_batch_size": 1, "warmup_steps": 0},
+        "mlflow": {"enabled": False},
+    })
+    mb = POOL_CONTEXT // POOL_BLOCK_TOKENS
+    paged = build_adapter(run).build_model(run).for_paged_decoding(
+        num_blocks=1 + STATE_SLOTS * mb, block_tokens=POOL_BLOCK_TOKENS, state_rows=1 + STATE_SLOTS
+    )
+    variables = jax.eval_shape(
+        lambda: paged.init(
+            jax.random.key(0), jnp.zeros((1, 1), jnp.int32), deterministic=True,
+            positions=jnp.zeros((1,), jnp.int32), block_tables=jnp.zeros((1, mb), jnp.int32),
+        )
+    )
+
+    def on_chip(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda s: on_chip(*s.shape, dtype=jnp.bfloat16), variables["params"])
+    cache = jax.tree.map(lambda s: on_chip(*s.shape, dtype=s.dtype), variables["cache"])
+
+    def sampling(rows):
+        return (on_chip(rows, dtype=jnp.uint32), on_chip(rows, dtype=jnp.float32),
+                on_chip(rows), on_chip(rows, dtype=jnp.float32))
+
+    seeds, *knobs = sampling(STATE_SLOTS)
+    shapes = {
+        "decode": (functools.partial(engine._decode_impl, paged),
+                   (params, cache, on_chip(STATE_SLOTS), on_chip(STATE_SLOTS), on_chip(STATE_SLOTS, mb),
+                    seeds, on_chip(STATE_SLOTS), *knobs, on_chip(STATE_SLOTS))),
+        "prefill": (functools.partial(engine._prefill_impl, paged),
+                    (params, cache, on_chip(1, 640), on_chip(1), on_chip(1), on_chip(1, mb),
+                     *sampling(1), on_chip(1))),
+    }
+    state_shapes = {
+        leaf.shape for path, leaf in jax.tree_util.tree_leaves_with_path(cache) if engine.is_state_leaf(path)
+    }
+    return shapes, state_shapes
+
+
+class TestStateRowsUpdateInPlace:
+    """PR 25's lesson applied to the state leaves before the first chip
+    run: the donated ``state_ssm`` and ``state_conv`` leaves alias their
+    outputs in one layout, and outside fused computations nothing as large
+    as a ``state_ssm`` leaf exists but the leaf itself passed along and the
+    ONE fusion a layer that writes it (decode: the elementwise update with
+    ``y`` reduced in the same pass; prefill: an in-place
+    ``dynamic-update-slice`` of the request's row). A gather of the batch's
+    rows, the update and a scatter back would be three such passes."""
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_state_leaves_alias_and_nothing_else_is_their_size(self, state_programs, program):
+        shapes, state_shapes = state_programs
+        assert state_shapes == {(97, 3, 5120), (97, 32, 128, 256)}
+        fn, args = shapes[program]
+        text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+        params, results, aliased = _entry_layout(text)
+        for shape in state_shapes:
+            dims = "[" + ",".join(map(str, shape)) + "]"
+            leaves = [i for i, p in enumerate(params) if dims in p]
+            assert len(leaves) == STATE_LAYERS
+            for i in leaves:
+                assert i in aliased, f"state leaf (parameter {i}) is not donated in place"
+                assert results[aliased[i]] == params[i], "the output's layout differs"
+
+        writers = 0
+        for name, body in _unfused_computations(text):
+            for op, result, called, line in _hlo_instructions(body):
+                if "[97,32,128,256]" not in result:
+                    continue
+                if op == "fusion":
+                    writers += 1
+                    fused = _computation(text, called)
+                    assert re.search(r" (dynamic-update-slice|multiply|add)\(", fused), line[:300]
+                    assert not re.search(r" (gather|scatter|copy|transpose)\(", fused), line[:300]
+                else:
+                    assert op in POOL_IN_PLACE, f"state-sized `{op}` in {program}: {line.strip()[:300]}"
+        assert writers == STATE_LAYERS
