@@ -1,4 +1,4 @@
-.PHONY: test test-all lint verify-resilience verify-watchdog verify-prefetch verify-telemetry verify-elastic verify-serving verify-router verify-promote verify-overload verify-trace verify-zero verify-fleet verify-profile verify-quant verify-fusedce verify-goodput verify-tune verify-offload train-smoke train-multiproc bench \
+.PHONY: test test-all lint verify-resilience verify-watchdog verify-prefetch verify-telemetry verify-elastic verify-serving verify-router verify-promote verify-overload verify-trace verify-zero verify-fleet verify-profile verify-quant verify-fusedce verify-goodput verify-tune verify-offload train-smoke train-multiproc \
 	chip-smoke mlflow \
 	k8s-cluster k8s-cluster-delete k8s-build k8s-train k8s-serve k8s-fleet k8s-logs k8s-clean \
 	k8s-full k8s-e2e
@@ -74,12 +74,11 @@ verify-telemetry:
 
 # Cost-attribution + roofline suite (docs/observability.md "Attribution and
 # rooflines"): XLA cost-table extraction, HLO top-ops parsing, roofline
-# classification, MFU reconciliation, serve-latency percentile gauges, and
-# the perf_gate regression rules (self-test included). The slow e2e pieces
-# (fit-path attribution, `llmtrain profile` CLI) ride `make test-all`.
+# classification, MFU reconciliation and serve-latency percentile gauges.
+# The slow e2e pieces (fit-path attribution, `llmtrain profile` CLI) ride
+# `make test-all`.
 verify-profile:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_profiling.py -q -m "not slow"
-	python tools/perf_gate.py --self-test
 
 # Mesh planner + auto-tuner suite (docs/perf.md "Mesh planning and
 # auto-tuning"): wildcard/divisibility plan resolution, capability rules,
@@ -88,19 +87,16 @@ verify-profile:
 # probe-fit e2e and tune->train round-trip ride `make test-all`.
 verify-tune:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_autotune.py -q -m "not slow"
-	python tools/perf_gate.py --self-test
 
 # Quantized-training suite (docs/perf.md "Quantized training"):
 # per-channel scale/STE-vjp units, QuantDense-vs-Dense drop-in parity,
-# knob validation + fp8 capability fallback, chunked-CE auto-select, the
-# perf_gate matrix rules — PLUS the @pytest.mark.slow fits plain
-# `make test` skips: int8-vs-f32 N-step loss-parity on a tiny GPT,
-# grad-finiteness under the non-finite guard, and the checkpoint/elastic
-# -resume round-trip with matmul_precision int8. Ends with the gate's
-# own self-test (new-key/removed-key/degraded-parity matrix cases).
+# knob validation + fp8 capability fallback, chunked-CE auto-select —
+# PLUS the @pytest.mark.slow fits plain `make test` skips: int8-vs-f32
+# N-step loss-parity on a tiny GPT, grad-finiteness under the non-finite
+# guard, and the checkpoint/elastic-resume round-trip with
+# matmul_precision int8.
 verify-quant:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_quant_train.py -q
-	python tools/perf_gate.py --self-test
 
 # Fused lm-head + CE suite (docs/perf.md "Fused lm-head + CE"):
 # interpret-mode Pallas kernel parity (fwd per-token loss + dhidden/dW)
@@ -111,20 +107,17 @@ verify-quant:
 # plain `make test` skips: 5-step fused-vs-dense loss parity, the
 # checkpoint resume with loss_impl flipped across the boundary, and the
 # attribution pin (no dot materializes the [B,T,V] logits under
-# fused_ce). Ends with the perf gate's self-test (fused matrix cases).
+# fused_ce).
 verify-fusedce:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fused_ce.py -q
-	python tools/perf_gate.py --self-test
 
 # Activation-tier suite (docs/perf.md "Activation tiers and host
 # offload"): spec grammar, per-layer jaxpr remat boundaries, forward
 # bitwise parity, the remat->tiers deprecation shim, the per-tier HBM
 # model + ladder enumeration, and the @slow Trainer fits (offload
-# fallback warning, resume with tiers changed) — plus the perf-gate
-# offload scenario contract.
+# fallback warning, resume with tiers changed).
 verify-offload:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_activation_tiers.py -q
-	python tools/perf_gate.py --self-test
 
 # Goodput-ledger suite (docs/observability.md "Goodput"): synthetic-
 # timeline taxonomy tables (exact second splits), the ledger-balances
@@ -133,10 +126,8 @@ verify-offload:
 # `make test` skips: a mid-interval SIGKILL leaving a torn timeline that
 # still balances, the 3-cycle chaos drill with recomputed_sec > 0 and
 # post-mortem CLI reproducibility, and the fleet-storm goodput floor.
-# Ends with the perf gate's own self-test (goodput regression cases).
 verify-goodput:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_goodput.py -q
-	python tools/perf_gate.py --self-test
 
 # Continuous-batching serving suite (docs/serving.md): paged-KV pool
 # invariants, batched-vs-generate() bitwise parity (greedy, per-request
@@ -229,9 +220,6 @@ train-pipeline:
 train-moe:
 	JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 		python -m llmtrain_tpu train --config configs/presets/gpt_moe_smoke.yaml
-
-bench:
-	python bench.py
 
 # On-chip bring-up proof (needs one TPU chip; exits nonzero without one).
 chip-smoke:

@@ -4,8 +4,9 @@ The search space is mesh shape x microbatch x activation regime (remat /
 tier ladder) x zero stage.  Every
 candidate is scored *analytically* first — the PaLM FLOP model
 (utils/hw.py), the plan-level HBM prediction (autotune/plan.py), and the
-``DEVICE_PEAKS`` roofline (telemetry/profiling.py) — and infeasible or
-dominated candidates are discarded before any device time is spent.
+device table's roofline (utils/hw.py, telemetry/profiling.py) — and
+infeasible or dominated candidates are discarded before any device time
+is spent.
 Pruning is observable by contract: every discarded candidate lands in the
 result with a named reason (``topology-illegal``, ``infeasible-hbm``,
 ``dominated``, ``probe-budget``) — no silent caps.
@@ -30,7 +31,7 @@ from typing import Any, Callable, Mapping
 
 from ..resilience.elastic import TopologyMismatchError, classify_topology_change
 from ..telemetry.profiling import classify_roofline, gradient_collective_bytes
-from ..utils.hw import transformer_flops_per_token
+from ..utils.hw import device_row, transformer_flops_per_token
 from ..config.activation_tiers import canonical_tier_spec, parse_activation_tiers
 from .plan import (
     MESH_AXES,
@@ -62,34 +63,17 @@ TIER_FLOPS_FACTOR: dict[str, float] = {
 # wall-clock.
 HOST_DMA_BYTES_PER_SEC = 100e9
 
-# Per-device HBM capacity by device kind (bytes), substring-matched like
-# DEVICE_PEAKS (longest key wins). These bound the feasibility half of the
-# pruning pass; ``tune.hbm_limit_bytes`` overrides. The cpu row is an
-# emulated-device placeholder generous enough for every smoke shape yet
-# small enough that deliberately-oversized test candidates still prune.
-DEVICE_HBM_BYTES: dict[str, float] = {
-    "v4": 32e9,
-    "v5e": 16e9,
-    "v5 lite": 16e9,
-    "v5p": 95e9,
-    "v6e": 32e9,
-    "v6 lite": 32e9,
-    "cpu": 8e9,
-}
-
 
 def resolve_hbm_limit(
     device_kind: str | None, override: float | None = None
 ) -> float:
-    """Per-device HBM budget for feasibility pruning (bytes)."""
+    """Per-device HBM budget for feasibility pruning (bytes):
+    ``tune.hbm_limit_bytes`` when set, else the capacity of the device
+    kind's row in the one device table (``utils/hw.py:device_row``, which
+    raises for an unknown TPU kind; None reads the first local device)."""
     if override:
         return float(override)
-    kind = (device_kind or "cpu").lower()
-    best, limit = "", DEVICE_HBM_BYTES["cpu"]
-    for key, cap in DEVICE_HBM_BYTES.items():
-        if key in kind and len(key) > len(best):
-            best, limit = key, cap
-    return limit
+    return float(device_row(device_kind)["hbm_bytes"])
 
 
 def _factorizations(n: int, slots: int) -> list[tuple[int, ...]]:
@@ -561,7 +545,6 @@ def prune_candidates(
 
 __all__ = [
     "Candidate",
-    "DEVICE_HBM_BYTES",
     "HOST_DMA_BYTES_PER_SEC",
     "TIER_FLOPS_FACTOR",
     "analytic_candidate_cost",

@@ -1,9 +1,8 @@
 """Probe-fit orchestration for ``llmtrain tune``.
 
 Survivors of the analytic pruning pass (autotune/search.py) run as short
-seeded training fits in budget-aware subprocesses — the bench.py
-scenario-child pattern: each candidate gets its own ``llmtrain train``
-child with a derived config, a wall-clock timeout, and a pinned device
+seeded training fits in budget-aware subprocesses: each candidate gets
+its own ``llmtrain train`` child with a derived config, a wall-clock timeout, and a pinned device
 topology, and is scored from the run's durable ``report.json``
 (``perf_attribution`` measured MFU, PR 10's substrate). The untuned
 config is always probed first and is exempt from the probe cap, so the
@@ -68,7 +67,7 @@ def _probe_overrides(
 def _pin_child_topology(env: dict[str, str], device_count: int) -> dict[str, str]:
     """The plan was resolved against the parent's device count; a probe
     child on the cpu backend must see exactly the same — strip any
-    inherited host-device-count flag and pin our own (bench.py idiom)."""
+    inherited host-device-count flag and pin our own."""
     if env.get("JAX_PLATFORMS", "").lower() not in ("", "cpu"):
         return env
     flags = [
@@ -100,8 +99,8 @@ def _run_probe(
     record: dict[str, Any] = {"key": plan.key(), "run_id": run_id}
     if plan.activation_tiers:
         # The tier ladder, named explicitly (it is also suffixed into the
-        # key) so perf_gate's tuned-plan "winner changed" notes and report
-        # consumers see which activation regime the winner runs.
+        # key) so report consumers see which activation regime the winner
+        # runs.
         record["activation_tiers"] = plan.activation_tiers
     dump = deep_merge(
         base_dump,

@@ -137,7 +137,7 @@ def build_parser() -> argparse.ArgumentParser:
         help="'compute' (default) casts floating checkpoint params to the "
         "model compute dtype before decoding — a bf16-compute model then "
         "streams half the weight bytes per token (decode is weight-bandwidth "
-        "bound; tools/diag_decode.py attribution); 'param' keeps the "
+        "bound); 'param' keeps the "
         "checkpoint's master precision",
     )
     gen.add_argument(
@@ -2865,7 +2865,7 @@ def _prepare_decode_model(model, params, decode_param_dtype: str, logger, label=
       config doesn't revert to dense and materialize (T, T).
     * ``decode_param_dtype == "compute"`` casts floating params to the
       model compute dtype — decode is weight-bandwidth bound and a bf16
-      model reading f32 weights pays 2x the bytes (tools/diag_decode.py).
+      model reading f32 weights pays 2x the bytes.
       Models without a dtype/param_dtype split (e.g. dummy_gpt) have
       nothing to cast.
     """

@@ -495,8 +495,8 @@ class TelemetryConfig(BaseModel):
     # perf/* gauges. Costs one extra trace+lower of the step function at
     # end of fit (no XLA compile, nothing executes).
     perf_attribution: bool = True
-    # Roofline peak overrides merged over the built-in DEVICE_PEAKS row
-    # for the detected device kind. Keys: peak_flops, hbm_bytes_per_sec,
+    # Roofline peak overrides merged over the detected device kind's row
+    # of utils/hw.py DEVICE_TABLE. Keys: peak_flops, hbm_bytes_per_sec,
     # ici_bytes_per_sec (values in FLOP/s and bytes/s).
     device_peaks: dict[str, float] = Field(default_factory=dict)
     # Distributed request tracing with tail-based sampling (serving
@@ -896,7 +896,7 @@ class TuneConfig(BaseModel):
     # re-tuning a run that must resume from its existing checkpoints.
     preserve_topology: bool = False
     # Per-device HBM feasibility limit override (bytes). None = the
-    # DEVICE_HBM_BYTES row for the detected device kind.
+    # hbm_bytes of the detected device kind's row in utils/hw.py.
     hbm_limit_bytes: float | None = Field(None, gt=0.0)
     # Candidate-order shuffle seed; None = run.seed.
     seed: int | None = None

@@ -168,7 +168,7 @@ DEFAULT_COMPILATION_CACHE_DIR = os.path.join(_REPO_ROOT, ".cache", "jax")
 
 def resolve_compilation_cache_dir(config_dir: str | None = None) -> str:
     """The directory JAX's persistent compilation cache lives in. Single
-    owner of the rule (bench.py / chip_smoke.py cache telemetry read it).
+    owner of the rule (chip_smoke.py's cache telemetry reads it).
 
     ``JAX_COMPILATION_CACHE_DIR`` (JAX's own variable) places the cache
     from outside and nothing in this program overrides it; otherwise
@@ -185,7 +185,7 @@ def resolve_compilation_cache_dir(config_dir: str | None = None) -> str:
 
 def compilation_cache_entries(config_dir: str | None = None) -> int:
     """Entry count of the persistent compilation cache (0 = no dir yet):
-    the before/after evidence bench.py and chip_smoke.py print."""
+    the before/after evidence chip_smoke.py prints."""
     try:
         return len(os.listdir(resolve_compilation_cache_dir(config_dir)))
     except OSError:

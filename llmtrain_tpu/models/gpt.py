@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from ..config.activation_tiers import canonical_tier_spec, parse_activation_tiers
+from ..config.activation_tiers import parse_activation_tiers
 from ..config.schemas import RunConfig
 from ..registry.models import register_model
 from .activation_policy import (
@@ -1025,8 +1025,8 @@ class GPT(nn.Module):
     # trained-shape tuned and decode runs T=1 slices.
     fused_norm: bool = False
     # Force interpret-mode Pallas kernels (fused_ce / fused_norm) on any
-    # backend — CPU parity tests and the bench matrix run the real kernel
-    # logic under emulation (model.extra.pallas_interpret).
+    # backend — CPU parity tests run the real kernel logic under
+    # emulation (model.extra.pallas_interpret).
     pallas_interpret: bool = False
     # PaLM z-loss coefficient: adds z_loss * log(Z)^2 per token to the LM
     # objective (both loss paths). 0 = off (reference behavior).

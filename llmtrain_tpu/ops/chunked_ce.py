@@ -2,7 +2,7 @@
 
 The dense LM loss path (models/base.py:masked_ce_components) materializes
 the full logits tensor and, in the backward, its softmax gradient — at
-GPT-2's V=50257 and the bench shape (64x512) that is the single largest
+GPT-2's V=50257 and a batch of 64x512 that is the single largest
 HBM resident of the train step (reference behavior spec: gpt.py:256-269;
 the reference materializes the same tensors via F.cross_entropy).
 

@@ -10,9 +10,7 @@ reference models/gpt.py:56-69). Training differentiates through a
 * elsewhere the backward differentiates the checkpointed XLA blockwise
   implementation.
 
-Both paths are O(T) memory — no (T, T) materialization. Set
-``LLMTRAIN_FLASH_BWD=blockwise`` to force the recompute backward on TPU
-(the A/B knob for benchmarking fused vs recompute).
+Both paths are O(T) memory — no (T, T) materialization.
 
 The platform decides, never the shape: on ``tpu`` a sequence length the
 kernels cannot tile is an error (no silent blockwise), and
@@ -38,7 +36,6 @@ materialized at full width on any path here.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -84,10 +81,6 @@ def resolved_attention_impl(attention: str) -> str:
     return "pallas_flash" if jax.default_backend() == "tpu" else "blockwise"
 
 
-def _pallas_bwd_enabled() -> bool:
-    return os.environ.get("LLMTRAIN_FLASH_BWD", "pallas").lower() != "blockwise"
-
-
 def _blockwise(q, k, v, key_mask=None, window=0):
     # blockwise consumes grouped-query narrow K/V natively. query_mask =
     # key_mask upgrades to segment semantics (q and k cover the same
@@ -112,7 +105,7 @@ def _flash(window, q, k, v):
 
 
 def _flash_fwd(window, q, k, v):
-    if _use_pallas(q.shape[1]) and _pallas_bwd_enabled():
+    if _use_pallas(q.shape[1]):
         from .pallas_attention import pallas_flash_attention_fwd
 
         block = _auto_block(q.shape[1])
@@ -157,7 +150,7 @@ def _flash_masked(window, q, k, v, maskf):
 
 
 def _flash_masked_fwd(window, q, k, v, maskf):
-    if _use_pallas(q.shape[1]) and _pallas_bwd_enabled():
+    if _use_pallas(q.shape[1]):
         from .pallas_attention import pallas_flash_attention_fwd
 
         block = _auto_block(q.shape[1])

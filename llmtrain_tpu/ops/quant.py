@@ -8,8 +8,8 @@ quantization recipe (:func:`quantize_array`):
 tree's big leaves into :class:`QuantizedArray` containers; ``__jax_array__``
 dequantizes in-graph so XLA keeps the int8 buffer in HBM and fuses the
 ``convert+multiply`` into the consuming matmul's operand read. Rationale:
-single-stream decode is weight-bandwidth bound (tools/diag_decode.py
-attribution), so halving weight bytes is worth ~1% logit error — and TPU
+single-stream decode is weight-bandwidth bound (docs/perf.md "Serving
+bandwidth model"), so halving weight bytes is worth ~1% logit error — and TPU
 v5e reads int8 natively.
 
 **Training (quantized matmuls, ``model.extra.matmul_precision``)** —
@@ -37,10 +37,10 @@ identity in the backward pass, so gradients are exact f32 with respect
 to the quantized operands — master weights, grad accumulation, the
 optimizer, ZeRO sharding, and checkpoint contracts are all untouched
 (the param tree never stores codes during training). Loss parity with
-the f32 trajectory is *gated*, not assumed: bench.py's scenario matrix
-trains N probe steps quantized-vs-f32 and fails the scenario line as
-``degraded`` when the trajectories diverge beyond the documented rtol
-(docs/perf.md "Quantized matmul training").
+the f32 trajectory is *tested*, not assumed: tests/test_quant_train.py
+trains N steps quantized-vs-f32 from one init and fails when the
+trajectories diverge beyond the documented rtol (docs/perf.md "Quantized
+training").
 
 Scales are symmetric per-channel (no zero-point): dequant stays a single
 fused multiply and 0.0 is exact, which LayerNorm-heavy stacks care about.

@@ -19,9 +19,9 @@ al., arXiv:2104.04473):
     HLO for the per-op table, compile wall-times, and the compiled
     memory footprint.
 
-* **Roofline attribution** — against a per-device-kind peak table
-  (:data:`DEVICE_PEAKS`, config-overridable), each executable and each
-  top-k HLO op category is classified compute-/memory-/comms-bound by
+* **Roofline attribution** — against the per-device-kind table
+  (``utils/hw.py:DEVICE_TABLE``, config-overridable), each executable and
+  each top-k HLO op category is classified compute-/memory-/comms-bound by
   comparing ``flops/peak_flops`` vs ``bytes/hbm_bw`` vs
   ``collective_bytes/ici_bw`` (Williams et al. roofline model).
 
@@ -41,32 +41,16 @@ from __future__ import annotations
 
 import re
 import time
-from typing import Any, Callable, Iterable, Mapping
+from typing import Any, Iterable, Mapping
 
+from ..utils.hw import device_row
 from ..utils.logging import get_logger
 
 logger = get_logger()
 
 # --------------------------------------------------------------------------
-# Device peak table
+# Device peaks
 # --------------------------------------------------------------------------
-
-# Per-chip peaks by TPU generation: bf16 FLOP/s, HBM bandwidth (bytes/s),
-# and aggregate ICI bandwidth (bytes/s, all links).  Bandwidths are
-# approximate public figures — they set roofline *ratios*, not absolute
-# claims, and every entry is overridable via
-# ``telemetry.device_peaks`` in the run config.  The cpu row is a nominal
-# placeholder (same stance as utils/hw.py CPU_NOMINAL_FLOPS) so local
-# smoke runs still produce trend-comparable classifications.
-DEVICE_PEAKS: dict[str, dict[str, float]] = {
-    "v4": {"peak_flops": 275e12, "hbm_bytes_per_sec": 1228e9, "ici_bytes_per_sec": 270e9},
-    "v5e": {"peak_flops": 197e12, "hbm_bytes_per_sec": 819e9, "ici_bytes_per_sec": 186e9},
-    "v5 lite": {"peak_flops": 197e12, "hbm_bytes_per_sec": 819e9, "ici_bytes_per_sec": 186e9},
-    "v5p": {"peak_flops": 459e12, "hbm_bytes_per_sec": 2765e9, "ici_bytes_per_sec": 540e9},
-    "v6e": {"peak_flops": 918e12, "hbm_bytes_per_sec": 1640e9, "ici_bytes_per_sec": 360e9},
-    "v6 lite": {"peak_flops": 918e12, "hbm_bytes_per_sec": 1640e9, "ici_bytes_per_sec": 360e9},
-    "cpu": {"peak_flops": 2e11, "hbm_bytes_per_sec": 50e9, "ici_bytes_per_sec": 10e9},
-}
 
 _PEAK_KEYS = ("peak_flops", "hbm_bytes_per_sec", "ici_bytes_per_sec")
 
@@ -75,34 +59,17 @@ def resolve_peaks(
     device_kind: str | None = None,
     overrides: Mapping[str, float] | None = None,
 ) -> dict[str, float]:
-    """Peak figures for ``device_kind`` (substring match, like
-    utils/hw.py), with config overrides merged on top.
+    """Roofline peaks of ``device_kind`` (None: the first local jax
+    device) from the one device table (``utils/hw.py:device_row``, which
+    raises for an unknown TPU kind), with the ``telemetry.device_peaks``
+    config overrides merged on top."""
+    row = device_row(device_kind)  # None: the lookup reads platform and kind
+    if device_kind is None:
+        import jax
 
-    ``device_kind`` None reads the first local jax device. Off the chip
-    an unmatched kind takes the nominal cpu row; on platform ``tpu`` (or
-    for any kind that names a TPU) an unknown kind raises — a made-up
-    peak would make every roofline share derived from it fiction.
-    """
-    import jax
-
-    platform = jax.default_backend() if device_kind is None else None
-    kind = (
-        jax.devices()[0].device_kind if device_kind is None else device_kind
-    ).lower()
-    # Longest matching key wins so "v5 lite" beats "v5" styles of kind.
-    best = max(
-        (key for key in DEVICE_PEAKS if key in kind), key=len, default=None
-    )
-    if best is None:
-        if platform == "tpu" or "tpu" in kind:
-            raise ValueError(
-                f"no peaks known for TPU device_kind {kind!r}; add its row "
-                "to telemetry/profiling.py DEVICE_PEAKS "
-                f"(known: {sorted(DEVICE_PEAKS)})"
-            )
-        best = "cpu"
-    peaks = dict(DEVICE_PEAKS[best])
-    peaks["device_kind"] = kind  # type: ignore[assignment]
+        device_kind = jax.devices()[0].device_kind
+    peaks = {key: float(row[key]) for key in _PEAK_KEYS}
+    peaks["device_kind"] = device_kind.lower()  # type: ignore[assignment]
     for key in _PEAK_KEYS:
         if overrides and key in overrides and overrides[key]:
             peaks[key] = float(overrides[key])
@@ -686,7 +653,6 @@ def render_top_ops_markdown(rows: Iterable[Mapping[str, Any]]) -> list[str]:
 
 
 __all__ = [
-    "DEVICE_PEAKS",
     "MFU_RECONCILE_BAND",
     "resolve_peaks",
     "normalize_cost",

@@ -1,44 +1,88 @@
-"""Hardware peak-FLOPs lookup and MFU arithmetic.
+"""What a device can do (the one table of device kinds), and MFU arithmetic.
 
 New capability over the reference (SURVEY §5: profiling/MFU absent there —
-``peak_memory`` is a hardcoded 0.0 at reference trainer.py:542). Peak numbers
-are bf16 per-chip figures by TPU generation; a TPU whose ``device_kind`` is
-not in the table is an error, never a default. The CPU figure is a nominal
-placeholder so local smoke runs still produce a (meaningless in absolute
-terms, but trend-comparable) MFU.
+``peak_memory`` is a hardcoded 0.0 at reference trainer.py:542).
+:data:`DEVICE_TABLE` is the program's only table keyed by device kind and
+:func:`device_row` the only code that matches a ``device_kind`` string
+against it: the trainer's MFU (:func:`peak_flops_per_chip`), the roofline
+peaks (``telemetry/profiling.py:resolve_peaks``) and the auto-tuner's HBM
+budget (``autotune/search.py:resolve_hbm_limit``) all read it. A TPU whose
+``device_kind`` is not in the table is an error, never a default.
 """
 
 from __future__ import annotations
 
-# bf16 peak FLOP/s per chip by TPU generation.
-TPU_PEAK_FLOPS = {
-    "v4": 275e12,
-    "v5 lite": 197e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6 lite": 918e12,
-    "v6e": 918e12,
+_CARRIED = (
+    "approximate public per-chip figures, no chip run behind them; "
+    "telemetry.device_peaks / tune.hbm_limit_bytes override"
+)
+_V5E = (
+    "Google Cloud docs, 'TPU v5e' (v5litepod), per chip: 197 TFLOP/s bf16, "
+    "16 GB HBM2e at 819 GB/s, 1,600 Gbit/s of ICI; the row of "
+    "benchmarks/lib/peaks.py (tests/test_utils.py holds the two equal)"
+)
+
+
+def _row(peak_flops: float, hbm_bw: float, ici_bw: float, hbm: float, source: str) -> dict:
+    return {
+        "peak_flops": peak_flops,
+        "hbm_bytes_per_sec": hbm_bw,
+        "ici_bytes_per_sec": ici_bw,
+        "hbm_bytes": hbm,
+        "source": source,
+    }
+
+
+# One chip under the two names its device_kind has carried.
+_V5E_ROW = _row(197e12, 819e9, 200e9, 16e9, _V5E)
+_V6E_ROW = _row(918e12, 1640e9, 360e9, 32e9, _CARRIED)
+
+# Per chip, by device-kind substring: bf16 FLOP/s, HBM bandwidth (bytes/s),
+# aggregate ICI bandwidth (bytes/s, all links) and HBM capacity (bytes).
+# The bandwidths set roofline *ratios*. The cpu row is a nominal
+# placeholder so local smoke runs still give trend-comparable MFU and
+# roofline classes, and an emulated-device budget generous enough for every
+# smoke shape yet small enough that deliberately oversized tune candidates
+# still prune.
+DEVICE_TABLE: dict[str, dict[str, float | str]] = {
+    "v4": _row(275e12, 1228e9, 270e9, 32e9, _CARRIED),
+    "v5e": _V5E_ROW,
+    "v5 lite": _V5E_ROW,
+    "v5p": _row(459e12, 2765e9, 540e9, 95e9, _CARRIED),
+    "v6e": _V6E_ROW,
+    "v6 lite": _V6E_ROW,
+    "cpu": _row(2e11, 50e9, 10e9, 8e9, "nominal placeholder, not a measurement"),
 }
 
-CPU_NOMINAL_FLOPS = 2e11  # placeholder for local smoke runs
+
+def device_row(device_kind: str | None = None) -> dict[str, float | str]:
+    """The :data:`DEVICE_TABLE` row of ``device_kind`` (None: the first
+    local jax device): the longest key that is a substring of the
+    lower-cased kind. With no match, a kind on platform ``tpu`` or one that
+    names a TPU raises — an assumed peak makes every MFU, roofline share and
+    memory budget derived from it fiction; anything else takes the nominal
+    ``cpu`` row."""
+    on_tpu = False
+    if device_kind is None:
+        import jax
+
+        on_tpu = jax.default_backend() == "tpu"
+        device_kind = jax.devices()[0].device_kind
+    kind = device_kind.lower()
+    key = max((k for k in DEVICE_TABLE if k in kind), key=len, default=None)
+    if key is None:
+        if on_tpu or "tpu" in kind:
+            raise ValueError(
+                f"no row for TPU device_kind {device_kind!r}; add a sourced "
+                f"row to utils/hw.py DEVICE_TABLE (known: {sorted(DEVICE_TABLE)})"
+            )
+        key = "cpu"
+    return DEVICE_TABLE[key]
 
 
 def peak_flops_per_chip() -> float:
-    """bf16 peak FLOP/s of one local device: the table row on platform
-    tpu (an unknown ``device_kind`` raises — an assumed peak makes every
-    MFU derived from it fiction), the nominal placeholder off it."""
-    import jax
-
-    if jax.default_backend() != "tpu":
-        return CPU_NOMINAL_FLOPS
-    kind = jax.devices()[0].device_kind
-    for key, peak in TPU_PEAK_FLOPS.items():
-        if key in kind.lower():
-            return peak
-    raise ValueError(
-        f"no peak FLOP/s known for TPU device_kind {kind!r}; add its row "
-        f"to utils/hw.py TPU_PEAK_FLOPS (known: {sorted(TPU_PEAK_FLOPS)})"
-    )
+    """bf16 peak FLOP/s of one local device, from :func:`device_row`."""
+    return float(device_row()["peak_flops"])
 
 
 def transformer_flops_per_token(
@@ -101,10 +145,9 @@ def peak_bytes_from_stats(stats: dict) -> float:
 def peak_memory_bytes() -> float:
     """Best-effort peak device-memory bytes of the first local device.
 
-    Single owner of the lookup (trainer metrics, bench.py, and
-    tools/bench_longctx.py all report it); :func:`peak_bytes_from_stats`
-    says which counters it sums. Returns 0.0 when the backend reports
-    nothing (CPU PJRT)."""
+    Single owner of the lookup (the trainer's metrics report it);
+    :func:`peak_bytes_from_stats` says which counters it sums. Returns 0.0
+    when the backend reports nothing (CPU PJRT)."""
     import jax
 
     try:
@@ -116,25 +159,12 @@ def peak_memory_bytes() -> float:
     return peak_bytes_from_stats(stats)
 
 
-def memory_stats_keys() -> list[str]:
-    """Diagnostic: the keys the first local device's memory_stats reports
-    (empty list = no stats). Logged by the long-context sweep when the
-    peak reads 0.0 so the record says WHY."""
-    import jax
-
-    try:
-        return sorted((jax.local_devices()[0].memory_stats() or {}).keys())
-    except Exception:
-        return []
-
-
 __all__ = [
-    "TPU_PEAK_FLOPS",
-    "CPU_NOMINAL_FLOPS",
+    "DEVICE_TABLE",
+    "device_row",
     "peak_flops_per_chip",
     "transformer_flops_per_token",
     "mfu",
     "peak_bytes_from_stats",
     "peak_memory_bytes",
-    "memory_stats_keys",
 ]

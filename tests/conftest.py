@@ -67,6 +67,29 @@ configure_compilation_cache()
 import pytest  # noqa: E402
 
 
+@pytest.fixture(autouse=True)
+def restore_llmtrain_logger():
+    """In-process cli.main and configure_logging reconfigure the llmtrain
+    logger (propagate off, handlers re-targeted) — restore it after every
+    test, or every later caplog-based test in the same worker goes blind;
+    which file that is depends on how xdist deals the files out."""
+    import logging
+
+    logger = logging.getLogger("llmtrain")
+    saved = (logger.propagate, logger.level, list(logger.handlers))
+    yield
+    for handler in list(logger.handlers):
+        if handler not in saved[2]:
+            if isinstance(handler, logging.FileHandler):
+                handler.close()
+            logger.removeHandler(handler)
+    for handler in saved[2]:
+        if handler not in logger.handlers:
+            logger.addHandler(handler)
+    logger.propagate = saved[0]
+    logger.setLevel(saved[1])
+
+
 def pytest_collection_modifyitems(config, items):
     """Under LLMTRAIN_TEST_TPU=1 run ONLY the TPU-gated compiled tests.
 

@@ -14,7 +14,7 @@ transformer block gets one of ``none | selective | full | offload``
   conflict, at both the schema and the adapter layer;
 * the planner's per-tier HBM model: monotone none > full > offload
   ladders, host-offload bytes tracked outside the device total, and the
-  fits/doesn't-fit ordering the bench offload scenario pins a cap from;
+  fits/doesn't-fit ordering of a cap between two ladders' predictions;
 * candidate enumeration producing tier-ladder candidates with the
   ``|act=`` key suffix (and pre-tier keys byte-identical to before);
 * ``@pytest.mark.slow``: real Trainer fits under a ladder (CPU
@@ -253,7 +253,7 @@ class TestJaxprBoundaries:
     def test_grads_flow_and_are_close(self):
         """Gradients under any ladder stay finite and match the no-remat
         baseline to fp noise (remat may reassociate reductions, so this is
-        allclose, not bitwise — the bench gates bitwise on the LOSS)."""
+        allclose, not bitwise — only the forward is held bitwise)."""
         base = _tiny_gpt()
         params = self._params(base)
         tokens = jnp.asarray(
@@ -426,9 +426,9 @@ class TestHbmModel:
         )
 
     def test_cap_ordering_matches_bench_scenario(self):
-        """The bench offload scenario derives its HBM cap as the midpoint
-        of the two predictions; pin the fits/doesn't-fit ordering here so
-        `llmtrain plan` and the bench line can never disagree."""
+        """A cap at the midpoint of the two predictions: the tiered ladder
+        fits where all-`none` does not, which is the ordering `llmtrain
+        plan` reports against a device's HBM limit."""
         h_none = self._hbm(_run_cfg(model_extra={"activation_tiers": "none:*"}))
         h_tier = self._hbm(
             _run_cfg(model_extra={"activation_tiers": "offload:0,full:1"})

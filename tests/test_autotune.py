@@ -26,7 +26,6 @@ from llmtrain_tpu.autotune.plan import (
     resolve_plan,
 )
 from llmtrain_tpu.autotune.search import (
-    DEVICE_HBM_BYTES,
     Candidate,
     enumerate_candidates,
     prune_candidates,
@@ -36,6 +35,7 @@ from llmtrain_tpu.config import RunConfig
 from llmtrain_tpu.registry import initialize_registries
 from llmtrain_tpu.resilience.harness import deep_merge
 from llmtrain_tpu.telemetry.profiling import resolve_peaks
+from llmtrain_tpu.utils.hw import DEVICE_TABLE
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 SMOKE_PRESET = REPO / "configs" / "presets" / "gpt_tune_smoke.yaml"
@@ -409,9 +409,9 @@ class TestSearch:
             )
 
     def test_resolve_hbm_limit(self):
-        assert resolve_hbm_limit("TPU v5 lite") == DEVICE_HBM_BYTES["v5 lite"]
-        assert resolve_hbm_limit("tpu v5p") == DEVICE_HBM_BYTES["v5p"]
-        assert resolve_hbm_limit("weird accelerator") == DEVICE_HBM_BYTES["cpu"]
+        assert resolve_hbm_limit("TPU v5 lite") == DEVICE_TABLE["v5 lite"]["hbm_bytes"]
+        assert resolve_hbm_limit("tpu v5p") == DEVICE_TABLE["v5p"]["hbm_bytes"]
+        assert resolve_hbm_limit("weird accelerator") == DEVICE_TABLE["cpu"]["hbm_bytes"]
         assert resolve_hbm_limit("v4", override=123.0) == 123.0
 
 

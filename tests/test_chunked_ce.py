@@ -224,9 +224,10 @@ class TestKnobValidation:
             }
         )
 
-    def test_unknown_loss_impl_rejected(self):
+    @pytest.mark.parametrize("value", ["chunked", "typo"])
+    def test_unknown_loss_impl_rejected(self, value):
         with pytest.raises(ValueError, match="loss_impl"):
-            GPTAdapter().build_model(self._cfg("gpt", {"loss_impl": "chunked"}))
+            GPTAdapter().build_model(self._cfg("gpt", {"loss_impl": value}))
 
     def test_gpt_moe_chunked_matches_dense(self):
         """MoE composes with chunked CE: same CE + router-aux loss and

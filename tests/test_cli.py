@@ -332,7 +332,7 @@ class TestGenerate:
 
     def test_decode_param_dtype_cast_and_optout(self, workdir):
         """bf16-compute models decode from bf16 weights by default (half the
-        weight bandwidth, tools/diag_decode.py attribution); --decode-param-
+        weight bandwidth); --decode-param-
         dtype param keeps the checkpoint's f32 master params."""
         cfg = {
             **CFG,

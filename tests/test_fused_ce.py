@@ -488,8 +488,8 @@ def _fit_losses(extra: dict, steps: int = 5, vocab: int = 256):
 
 @pytest.mark.slow
 class TestFusedFits:
-    # Same band as the bench matrix's CE parity gate (_CE_PARITY_RTOL in
-    # bench.py, docs/perf.md): identical math, fp reduction-order noise
+    # The CE-parity band, 5e-4 (docs/perf.md; chip_smoke.py holds the chip
+    # to the same at step 1): identical math, fp reduction-order noise
     # amplified over the 5-step trajectory.
     CE_RTOL = 5e-4
 

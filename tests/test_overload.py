@@ -12,7 +12,6 @@ compiles a model and runs under ``@pytest.mark.slow`` via
 
 from __future__ import annotations
 
-import threading
 import time
 
 import numpy as np

@@ -10,7 +10,6 @@ real program (the fit-path attribution, the ``llmtrain profile`` CLI) is
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import os
 import subprocess
@@ -20,7 +19,6 @@ from pathlib import Path
 import pytest
 
 from llmtrain_tpu.telemetry.profiling import (
-    DEVICE_PEAKS,
     MFU_RECONCILE_BAND,
     attribution_gauges,
     build_perf_attribution,
@@ -33,6 +31,7 @@ from llmtrain_tpu.telemetry.profiling import (
     resolve_peaks,
     top_ops,
 )
+from llmtrain_tpu.utils.hw import DEVICE_TABLE
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -75,18 +74,18 @@ class TestResolvePeaks:
     def test_substring_match_prefers_longest_key(self):
         # "TPU v5 lite" must hit the v5e-class row, not a bare "v5" guess.
         peaks = resolve_peaks("TPU v5 lite")
-        assert peaks["peak_flops"] == DEVICE_PEAKS["v5 lite"]["peak_flops"]
+        assert peaks["peak_flops"] == DEVICE_TABLE["v5 lite"]["peak_flops"]
         assert peaks["device_kind"] == "tpu v5 lite"
 
     def test_unknown_kind_falls_back_to_cpu_row(self):
         peaks = resolve_peaks("quantum-abacus")
-        assert peaks["peak_flops"] == DEVICE_PEAKS["cpu"]["peak_flops"]
+        assert peaks["peak_flops"] == DEVICE_TABLE["cpu"]["peak_flops"]
 
     def test_config_overrides_win(self):
         peaks = resolve_peaks("TPU v4", {"peak_flops": 123.0})
         assert peaks["peak_flops"] == 123.0
         # non-overridden keys keep the table value
-        assert peaks["hbm_bytes_per_sec"] == DEVICE_PEAKS["v4"]["hbm_bytes_per_sec"]
+        assert peaks["hbm_bytes_per_sec"] == DEVICE_TABLE["v4"]["hbm_bytes_per_sec"]
 
 
 # --------------------------------------------------------------------------
@@ -289,64 +288,6 @@ class TestServerStatsPercentiles:
         snap = stats.snapshot()
         assert snap["p95_latency_ms"] >= snap["p50_latency_ms"]
         assert snap["p50_ttft_ms"] == 1.0
-
-
-# --------------------------------------------------------------------------
-# perf gate comparison core (tools/perf_gate.py)
-# --------------------------------------------------------------------------
-
-
-def _load_perf_gate():
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", REPO / "tools" / "perf_gate.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestPerfGate:
-    def _line(self, **kw):
-        base = {
-            "metric": "tokens_per_sec_per_chip",
-            "value": 1000.0,
-            "detail": {"model": "gpt L2 d128 T128", "attention": "dense", "batch": 4},
-        }
-        detail_keys = {"fallback"}
-        for key, val in kw.items():
-            if key in detail_keys:
-                base["detail"][key] = val
-            else:
-                base[key] = val
-        return base
-
-    def test_synthetic_regression_gates(self):
-        gate = _load_perf_gate()
-        verdict = gate.compare([self._line()], [self._line(value=400.0)])
-        assert verdict["regressions"]
-
-    def test_noise_wobble_passes(self):
-        gate = _load_perf_gate()
-        verdict = gate.compare([self._line()], [self._line(value=950.0)])
-        assert verdict["compared"] and not verdict["regressions"]
-
-    def test_degraded_lines_never_gate(self):
-        gate = _load_perf_gate()
-        verdict = gate.compare(
-            [self._line()], [self._line(value=10.0, degraded=True, fallback="oom")]
-        )
-        assert not verdict["regressions"]
-        assert verdict["skipped"]
-
-    def test_real_r04_r05_pair_passes(self):
-        """The acceptance pin: the repo's own consecutive rounds must not
-        false-positive (different scenarios + degraded lines → skip)."""
-        gate = _load_perf_gate()
-        old = gate.load_results(str(REPO / "BENCH_r04.json"))
-        new = gate.load_results(str(REPO / "BENCH_r05.json"))
-        assert old and new
-        verdict = gate.compare(old, new)
-        assert not verdict["regressions"]
 
 
 # --------------------------------------------------------------------------

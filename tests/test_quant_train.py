@@ -1,21 +1,18 @@
 """Quantized training path (ops/quant.py training section, docs/perf.md
-"Quantized training") + the bench scenario-matrix gate rules.
+"Quantized training").
 
 Tier-1 keeps to pure units — per-channel scale/STE-vjp behavior, the
 quant_dot_general modes against plain ``lax.dot_general``, QuantDense's
-drop-in contract, knob validation + the fp8 capability fallback, the
-chunked-CE auto-select rule, and tools/perf_gate.py's matrix comparison
-core. Everything that runs train steps or compiles a full program (the
-int8-vs-f32 loss-parity fit, the non-finite-guard fit, the checkpoint/
-elastic-resume round-trip, the attribution pin) is ``@pytest.mark.slow``
-under ``make verify-quant``.
+drop-in contract, knob validation + the fp8 capability fallback, and the
+chunked-CE auto-select rule. Everything that runs train steps or compiles
+a full program (the int8-vs-f32 loss-parity fit, the non-finite-guard fit,
+the checkpoint/elastic-resume round-trip, the attribution pin) is
+``@pytest.mark.slow`` under ``make verify-quant``.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import logging
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +33,6 @@ from llmtrain_tpu.ops.quant import (
     resolve_matmul_precision,
 )
 from llmtrain_tpu.registry import initialize_registries
-
-REPO = Path(__file__).resolve().parents[1]
 
 # docs/perf.md "Parity band": the documented N-step loss-trajectory rtols.
 PARITY_RTOL = {"int8": 0.05, "int8_act": 0.05, "fp8": 0.10}
@@ -248,99 +243,6 @@ class TestChunkedCEAutoSelect:
     def test_ce_auto_vocab_override(self):
         model = GPTAdapter().build_model(_gpt_cfg({"ce_auto_vocab": 128}, vocab=256))
         assert model.loss_impl == "chunked_ce"
-
-
-# --------------------------------------------------------------------------
-# perf_gate matrix comparison core (tools/perf_gate.py)
-# --------------------------------------------------------------------------
-
-
-def _load_perf_gate():
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate_quant", REPO / "tools" / "perf_gate.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _round(mat: dict, skipped: list | None = None) -> list[dict]:
-    return [
-        {
-            "metric": "tokens_per_sec_per_chip",
-            "value": 100.0,
-            "detail": {"model": "gpt", "attention": "dense", "batch": 4},
-            "matrix": mat,
-            "skipped": skipped or [],
-        }
-    ]
-
-
-def _mline(tps: float, flops: float = 5.0e8, **kw) -> dict:
-    return {"tokens_per_sec": tps, "attribution": {"flops": flops}, **kw}
-
-
-class TestPerfGateMatrix:
-    KEY = "dense|short|dense_ce|f32"
-
-    def test_genuine_regression_gates(self):
-        gate = _load_perf_gate()
-        verdict = gate.compare_matrix(
-            _round({self.KEY: _mline(1000.0)}), _round({self.KEY: _mline(400.0)})
-        )
-        assert verdict["regressions"]
-
-    def test_new_key_never_gates(self):
-        gate = _load_perf_gate()
-        verdict = gate.compare_matrix(
-            _round({self.KEY: _mline(1000.0)}),
-            _round({self.KEY: _mline(1000.0), "dense|short|dense_ce|int8": _mline(1.0)}),
-        )
-        assert not verdict["regressions"]
-        assert any("new scenario" in n for n in verdict["notes"])
-
-    def test_removed_key_warns_unless_budget_skipped(self):
-        gate = _load_perf_gate()
-        old = _round({self.KEY: _mline(1000.0)})
-        verdict = gate.compare_matrix(old, _round({}))
-        assert not verdict["regressions"]
-        assert any("WARNING scenario removed" in n for n in verdict["notes"])
-        verdict = gate.compare_matrix(
-            old, _round({}, skipped=[{"scenario": self.KEY, "reason": "budget"}])
-        )
-        assert not any("WARNING" in n for n in verdict["notes"])
-        assert any("skipped for budget" in n for n in verdict["notes"])
-
-    def test_degraded_parity_line_skipped_not_gated(self):
-        gate = _load_perf_gate()
-        bad = _mline(
-            400.0,
-            degraded=True,
-            fallback="loss parity vs f32 failed: max rel diff 0.2 > rtol 0.05",
-            parity={"rtol": 0.05, "max_rel_diff": 0.2, "ok": False},
-        )
-        verdict = gate.compare_matrix(
-            _round({self.KEY: _mline(1000.0)}), _round({self.KEY: bad})
-        )
-        assert not verdict["regressions"] and verdict["skipped"]
-
-    def test_flops_drift_skips(self):
-        gate = _load_perf_gate()
-        verdict = gate.compare_matrix(
-            _round({self.KEY: _mline(1000.0, flops=1.0e9)}),
-            _round({self.KEY: _mline(400.0, flops=2.0e9)}),
-        )
-        assert not verdict["regressions"] and verdict["skipped"]
-
-    def test_matrix_lines_last_json_wins(self):
-        gate = _load_perf_gate()
-        early, late = _round({self.KEY: _mline(1.0)}), _round({self.KEY: _mline(2.0)})
-        lines = gate.matrix_lines(early + late)
-        assert lines[self.KEY]["tokens_per_sec"] == 2.0
-
-    def test_self_test_passes(self):
-        gate = _load_perf_gate()
-        assert gate._self_test() == 0
 
 
 # --------------------------------------------------------------------------

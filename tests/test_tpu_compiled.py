@@ -318,9 +318,10 @@ class TestCompiledBackward:
                 atol=0.1, rtol=0.1,
             )
 
-    def test_custom_vjp_dispatch_uses_pallas_bwd(self, monkeypatch):
+    def test_custom_vjp_dispatch_uses_pallas_bwd(self):
         """flash_attention's grad on TPU goes through the fused kernels and
-        agrees with the blockwise-recompute path (the A/B knob)."""
+        agrees with the gradient of the XLA blockwise twin."""
+        from llmtrain_tpu.ops.blockwise_attention import blockwise_attention
         from llmtrain_tpu.ops.flash_attention import flash_attention
 
         q, k, v = _qkv(t=256, dtype=jnp.float32, seed=4)
@@ -328,10 +329,12 @@ class TestCompiledBackward:
         def loss(q):
             return flash_attention(q, k, v).sum()
 
+        def loss_blockwise(q):
+            return blockwise_attention(q, k, v, causal=True).sum()
+
         with jax.default_matmul_precision("highest"):
             g_fused = jax.device_get(jax.grad(loss)(q))
-            monkeypatch.setenv("LLMTRAIN_FLASH_BWD", "blockwise")
-            g_recompute = jax.device_get(jax.grad(loss)(q))
+            g_recompute = jax.device_get(jax.grad(loss_blockwise)(q))
         np.testing.assert_allclose(
             np.asarray(g_fused), np.asarray(g_recompute), atol=1e-3
         )
@@ -340,7 +343,7 @@ class TestCompiledBackward:
 class TestCompiledTrainStep:
     def test_gpt_flash_train_step_runs(self):
         """One real optimizer step of the flagship GPT with attention=flash,
-        compiled on the chip — the end-to-end smoke the bench relies on."""
+        compiled on the chip."""
         from llmtrain_tpu.config.schemas import RunConfig
         from llmtrain_tpu.models.gpt import GPTAdapter
         from llmtrain_tpu.training.optimizer import build_optimizer
@@ -429,7 +432,7 @@ class TestCompiledChunkedCE:
 
     def test_train_step_with_chunked_ce(self):
         """One compiled optimizer step of GPT with loss_impl=chunked_ce at
-        the real GPT-2 vocab — the config the bench CE sweep runs."""
+        the real GPT-2 vocab."""
         from llmtrain_tpu.config.schemas import RunConfig
         from llmtrain_tpu.models.gpt import GPTAdapter
         from llmtrain_tpu.training.optimizer import build_optimizer

@@ -18,8 +18,7 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 PACKAGE = "llmtrain_tpu"
 LINT_ROOTS = [
-    REPO / PACKAGE, REPO / "tests", REPO / "bench.py", REPO / "chip_smoke.py",
-    REPO / "__graft_entry__.py",
+    REPO / PACKAGE, REPO / "tests", REPO / "chip_smoke.py", REPO / "__graft_entry__.py",
 ]
 
 # Names imported for re-export or side effects (registry self-registration).
