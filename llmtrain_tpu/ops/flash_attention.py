@@ -45,15 +45,26 @@ from ..parallel.sharding import BATCH_AXES, kernel_mesh, shard_axes
 from .blockwise_attention import blockwise_attention
 
 
-def _auto_block(t: int) -> int | None:
-    """Largest legal tile for sequence length ``t``.
+def _auto_block(t: int) -> tuple[int, int] | None:
+    """``(resident, streamed)`` tile of the kernels for sequence length
+    ``t``, or None when no legal tile divides it: the forward and dq hold
+    ``resident`` query rows a program and stream keys, dk/dv holds
+    ``resident`` keys and streams queries.
 
-    512 was fastest in older hand-taken v5e figures at GPT-2-small
-    shapes; smaller tiles keep lengths like 384 or 768 on the Pallas path.
+    Measured on the v5e at the train cell's shape, (32, 1024, 12, 64) bf16,
+    at T 2,048 / 4,096 and with a 128-wide head over 4 K/V heads
+    (``PERF.md`` section 6, PR 30): every kernel wants its resident block as
+    large as divides T (a grid step costs what it costs, and the diagonal
+    block's strips already skip what a smaller block would have skipped),
+    and streams the rest 512 at a time; smaller tiles keep lengths like 384
+    or 768 on the Pallas path. 1,024 rows is the cap: 2,048 was faster
+    still at T 2,048 and 4,096 with a 64-wide head, and runs out of VMEM
+    with a 128-wide one at T 8,192. Below the cap neither the kernel nor
+    the head width moved the choice, so it is one pair from T alone.
     """
-    for block in (512, 256, 128):
-        if t >= block and t % block == 0:
-            return block
+    for resident in (1024, 512, 256, 128):
+        if t >= resident and t % resident == 0:
+            return resident, min(resident, 512)
     return None
 
 
@@ -89,29 +100,39 @@ def _blockwise(q, k, v, key_mask=None, window=0):
                                query_mask=key_mask, window=window)
 
 
+def _pallas_fwd(window, q, k, v, maskf=None):
+    from .pallas_attention import pallas_flash_attention_fwd
+
+    resident, streamed = _auto_block(q.shape[1])
+    return pallas_flash_attention_fwd(
+        q, k, v, maskf, causal=True, block_q=resident, block_k=streamed, window=window
+    )
+
+
+def _pallas_bwd(window, q, k, v, out, lse, g, maskf=None):
+    from .pallas_attention import pallas_flash_attention_bwd
+
+    resident, streamed = _auto_block(q.shape[1])
+    return pallas_flash_attention_bwd(
+        q, k, v, out, lse, g, maskf, causal=True,
+        block_q=resident, block_k=streamed,
+        dkdv_block_q=streamed, dkdv_block_k=resident, window=window,
+    )
+
+
 # ``window`` is a static Python int (0 = off) and travels as the leading
 # nondiff arg of both custom_vjps — Mistral-style sliding-window masking
 # with dead K/V blocks skipped in the Pallas kernels.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash(window, q, k, v):
     if _use_pallas(q.shape[1]):
-        from .pallas_attention import pallas_flash_attention
-
-        block = _auto_block(q.shape[1])
-        return pallas_flash_attention(
-            q, k, v, causal=True, block_q=block, block_k=block, window=window
-        )
+        return _pallas_fwd(window, q, k, v)[0]
     return _blockwise(q, k, v, window=window)
 
 
 def _flash_fwd(window, q, k, v):
     if _use_pallas(q.shape[1]):
-        from .pallas_attention import pallas_flash_attention_fwd
-
-        block = _auto_block(q.shape[1])
-        out, lse = pallas_flash_attention_fwd(
-            q, k, v, causal=True, block_q=block, block_k=block, window=window
-        )
+        out, lse = _pallas_fwd(window, q, k, v)
         return out, (q, k, v, out, lse)
     return _flash(window, q, k, v), (q, k, v, None, None)
 
@@ -119,13 +140,7 @@ def _flash_fwd(window, q, k, v):
 def _flash_bwd(window, residuals, g):
     q, k, v, out, lse = residuals
     if out is not None:
-        from .pallas_attention import pallas_flash_attention_bwd
-
-        block = _auto_block(q.shape[1])
-        return pallas_flash_attention_bwd(
-            q, k, v, out, lse, g, causal=True, block_q=block, block_k=block,
-            window=window,
-        )
+        return _pallas_bwd(window, q, k, v, out, lse, g)
     _, vjp = jax.vjp(lambda q_, k_, v_: _blockwise(q_, k_, v_, window=window),
                      q, k, v)
     return vjp(g)
@@ -139,25 +154,13 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _flash_masked(window, q, k, v, maskf):
     if _use_pallas(q.shape[1]):
-        from .pallas_attention import pallas_flash_attention
-
-        block = _auto_block(q.shape[1])
-        return pallas_flash_attention(
-            q, k, v, maskf, causal=True, block_q=block, block_k=block,
-            window=window,
-        )
+        return _pallas_fwd(window, q, k, v, maskf)[0]
     return _blockwise(q, k, v, key_mask=maskf, window=window)
 
 
 def _flash_masked_fwd(window, q, k, v, maskf):
     if _use_pallas(q.shape[1]):
-        from .pallas_attention import pallas_flash_attention_fwd
-
-        block = _auto_block(q.shape[1])
-        out, lse = pallas_flash_attention_fwd(
-            q, k, v, maskf, causal=True, block_q=block, block_k=block,
-            window=window,
-        )
+        out, lse = _pallas_fwd(window, q, k, v, maskf)
         return out, (q, k, v, maskf, out, lse)
     return _flash_masked(window, q, k, v, maskf), (q, k, v, maskf, None, None)
 
@@ -165,13 +168,7 @@ def _flash_masked_fwd(window, q, k, v, maskf):
 def _flash_masked_bwd(window, residuals, g):
     q, k, v, maskf, out, lse = residuals
     if out is not None:
-        from .pallas_attention import pallas_flash_attention_bwd
-
-        block = _auto_block(q.shape[1])
-        dq, dk, dv = pallas_flash_attention_bwd(
-            q, k, v, out, lse, g, maskf, causal=True, block_q=block,
-            block_k=block, window=window,
-        )
+        dq, dk, dv = _pallas_bwd(window, q, k, v, out, lse, g, maskf)
         return dq, dk, dv, jnp.zeros_like(maskf)
     _, vjp = jax.vjp(
         lambda q_, k_, v_: _blockwise(q_, k_, v_, key_mask=maskf, window=window),

@@ -172,6 +172,121 @@ class TestPallasInterpret:
             )
 
 
+class TestTiledKernels:
+    """The rewritten loops: unequal tiles in each of the three kernels, a
+    sequence with interior, diagonal and strip-skipped blocks all present
+    (T = 4 x the tile, the diagonal walked in strips a quarter of it), bf16
+    operands handed to the MXU as they are, and GQA / window / segment mask
+    across block and strip edges, all against ``dense_attention`` on the
+    SAME inputs (output and all three gradients)."""
+
+    T = 128
+
+    @pytest.fixture
+    def strip8(self, monkeypatch):
+        """Walk the diagonal in 8-wide strips (the chip's are 128 wide), so
+        a 32-wide tile has dead sub-tiles to skip. The wrappers are jitted:
+        the module constant is read at trace time."""
+        from llmtrain_tpu.ops import pallas_attention
+
+        monkeypatch.setattr(pallas_attention, "_FWD_STRIP", 8)
+        monkeypatch.setattr(pallas_attention, "_BWD_STRIP", 8)
+        jax.clear_caches()
+        yield
+        jax.clear_caches()
+
+    @staticmethod
+    def _segments(b, t):
+        """Two documents meeting inside a block (and a strip), then padding."""
+        seg = np.zeros((b, t), np.int32)
+        seg[:, :45] = 1
+        seg[:, 45:t - 19] = 2
+        seg[0, :] = 1  # one fully packed row
+        return jnp.asarray(seg)
+
+    CASES = {
+        # name: (dtype, d, h, hkv, window, masked, fwd, dq, dkdv) tiles (block_q, block_k)
+        "f32-square": ("float32", 8, 2, 2, 0, False, (32, 32), (32, 32), (32, 32)),
+        "f32-fwd-q64-k16": ("float32", 8, 2, 2, 0, False, (64, 16), (32, 32), (32, 32)),
+        "f32-fwd-q16-k64": ("float32", 8, 2, 2, 0, False, (16, 64), (32, 32), (32, 32)),
+        "f32-dq-q64-k16": ("float32", 8, 2, 2, 0, False, (32, 32), (64, 16), (32, 32)),
+        "f32-dq-q16-k32": ("float32", 8, 2, 2, 0, False, (32, 32), (16, 32), (32, 32)),
+        "f32-dkdv-q16-k64": ("float32", 8, 2, 2, 0, False, (32, 32), (32, 32), (16, 64)),
+        "f32-dkdv-q32-k16": ("float32", 8, 2, 2, 0, False, (32, 32), (32, 32), (32, 16)),
+        "bf16-scale-on-scores": ("bfloat16", 8, 2, 2, 0, False, (32, 32), (32, 16), (16, 32)),
+        "bf16-scale-folded": ("bfloat16", 16, 2, 2, 0, False, (32, 32), (32, 16), (16, 32)),
+        "f32-gqa": ("float32", 8, 4, 2, 0, False, (32, 16), (32, 16), (16, 32)),
+        "f32-mqa-window": ("float32", 8, 2, 1, 40, False, (32, 16), (32, 16), (16, 32)),
+        "f32-window-in-strip": ("float32", 8, 2, 2, 5, False, (32, 32), (32, 32), (32, 32)),
+        "f32-window-unequal": ("float32", 8, 2, 2, 27, False, (16, 32), (16, 32), (32, 16)),
+        "f32-segments": ("float32", 8, 2, 2, 0, True, (32, 16), (32, 16), (16, 32)),
+        "bf16-gqa-window-segments": ("bfloat16", 16, 4, 2, 40, True, (32, 16), (32, 16), (16, 32)),
+    }
+
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_matches_dense(self, strip8, case):
+        from llmtrain_tpu.ops.pallas_attention import (
+            pallas_flash_attention_bwd,
+            pallas_flash_attention_fwd,
+        )
+
+        dtype, d, h, hkv, window, masked, fwd, dq_t, dkdv = self.CASES[case]
+        dtype = jnp.dtype(dtype)
+        b, t = 2, self.T
+        keys = jax.random.split(jax.random.key(17), 4)
+        q = jax.random.normal(keys[0], (b, t, h, d), dtype)
+        k = jax.random.normal(keys[1], (b, t, hkv, d), dtype)
+        v = jax.random.normal(keys[2], (b, t, hkv, d), dtype)
+        g = jax.random.normal(keys[3], (b, t, h, d), dtype)
+        mask = self._segments(b, t) if masked else None
+        if masked:  # the model zeroes padded rows' output, so their cotangent
+            g = g * (mask != 0)[:, :, None, None].astype(dtype)
+
+        out, lse = pallas_flash_attention_fwd(
+            q, k, v, mask, block_q=fwd[0], block_k=fwd[1], window=window,
+            interpret=True,
+        )
+        dq, dk, dv = pallas_flash_attention_bwd(
+            q, k, v, out, lse, g, mask, block_q=dq_t[0], block_k=dq_t[1],
+            dkdv_block_q=dkdv[0], dkdv_block_k=dkdv[1], window=window,
+            interpret=True,
+        )
+        assert out.dtype == dq.dtype == dtype and dk.dtype == dv.dtype == dtype
+        assert lse.shape == (b * h, t) and lse.dtype == jnp.float32
+
+        def dense(q, k, v):
+            wide = lambda x: jnp.repeat(x, h // hkv, axis=2)  # noqa: E731
+            return dense_attention(
+                q, wide(k), wide(v), attention_mask=mask, window=window
+            )
+
+        ref, vjp = jax.vjp(dense, q, k, v)
+        rq, rk, rv = vjp(g)
+        f32 = lambda x: np.asarray(x, dtype=np.float32)  # noqa: E731
+        if dtype == jnp.float32:  # today's tolerances
+            fwd_tol, grad_tol = dict(atol=1e-5), dict(atol=1e-4)
+        else:  # both sides round p (and here dS) to bf16 once
+            fwd_tol, grad_tol = dict(atol=2e-2), dict(atol=0.1, rtol=0.1)
+        live = np.ones((b, t, 1, 1), np.float32)
+        if masked:
+            live = f32(mask != 0)[:, :, None, None]
+        np.testing.assert_allclose(f32(out) * live, f32(ref) * live, **fwd_tol)
+        np.testing.assert_allclose(f32(dq), f32(rq), **grad_tol)
+        np.testing.assert_allclose(f32(dk), f32(rk), **grad_tol)
+        np.testing.assert_allclose(f32(dv), f32(rv), **grad_tol)
+
+    def test_auto_block_tiles_are_legal_for_the_schedules(self):
+        """Whatever ``_auto_block`` picks divides T, and the statistics keep
+        a whole lane tile: every width a schedule walks is a multiple of 128."""
+        from llmtrain_tpu.ops.flash_attention import _auto_block
+
+        for t in (128, 256, 384, 512, 768, 1024, 1536, 2048, 4096, 8192):
+            resident, streamed = _auto_block(t)
+            assert t % resident == 0 and resident % streamed == 0, (t, resident, streamed)
+            assert streamed % 128 == 0, (t, resident, streamed)
+        assert _auto_block(200) is None
+
+
 class TestFlashDispatch:
     def test_cpu_dispatch_and_grads(self):
         q, k, v = _qkv(t=16)

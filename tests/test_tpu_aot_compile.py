@@ -128,6 +128,46 @@ class TestFlashAttentionOneChip:
             jax.jit(flash_attention).lower(q, q, q)
 
 
+class TestFlashAttentionAtTheCellsShape:
+    """``gpt2-small.train-64k``'s own attention call, (32, 1024, 12, 64)
+    bf16, with the tiles ``_auto_block`` picks for it, and the other shapes
+    the tile choice must not break: a 128-wide head with 4 K/V heads, and
+    the segment mask with a sliding window."""
+
+    @staticmethod
+    def _grad_text(sharding, *, b=32, t=1024, h=12, hkv=12, d=64, masked=False, window=0):
+        q = jax.ShapeDtypeStruct((b, t, h, d), jnp.bfloat16, sharding=sharding)
+        kv = jax.ShapeDtypeStruct((b, t, hkv, d), jnp.bfloat16, sharding=sharding)
+        args = (q, kv, kv)
+        if masked:
+            args += (jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=sharding),)
+        return _compile(jax.grad(_flash_loss(window), argnums=(0, 1, 2)), *args)
+
+    def test_three_kernels_a_narrow_residual_and_no_replicated_statistics(self, one_chip):
+        text = self._grad_text(one_chip)
+        assert _custom_call_names(text) == {
+            "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
+        }
+        # The residual between forward and backward is one float32 a row...
+        assert re.search(r"f32\[384,(1,)?1024\]", text)
+        # ...and the lane-replicated (rows, 128) statistics never leave VMEM.
+        assert not re.search(r"f32\[[\d,]*1024,128\]", text)
+
+    @pytest.mark.parametrize(
+        "shape",
+        [
+            dict(b=8, h=20, hkv=4, d=128),
+            dict(b=8, masked=True, window=256),
+            dict(b=8, h=20, hkv=4, d=128, masked=True, window=300),
+        ],
+        ids=["head128-gqa", "masked-windowed", "head128-gqa-masked-windowed"],
+    )
+    def test_other_shapes_compile_with_the_tiles_picked_for_them(self, one_chip, shape):
+        assert _custom_call_names(self._grad_text(one_chip, **shape)) == {
+            "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
+        }
+
+
 class TestFusedCEOneChip:
     @staticmethod
     def _operands(sharding, dtype):

@@ -49,7 +49,11 @@ class TestCompiledForward:
 
     @pytest.mark.parametrize(
         "block_q,block_k",
-        [(128, 128), (128, 256), (256, 128), (256, 256), (512, 512)],
+        [
+            (128, 128), (128, 256), (256, 128), (256, 256), (512, 512),
+            # resident rows wider than the streamed keys: what _auto_block picks
+            (512, 256), (512, 128), (256, 512),
+        ],
     )
     def test_block_shape_sweep(self, block_q, block_k):
         """VMEM-relevant tilings: every (block_q, block_k) must lower and
@@ -257,14 +261,25 @@ class TestCompiledSlidingWindow:
 
 
 class TestCompiledBackward:
-    @pytest.mark.parametrize("block_q,block_k", [(128, 128), (256, 256)])
-    def test_fused_bwd_matches_dense_grads(self, block_q, block_k):
+    @pytest.mark.parametrize(
+        "block_q,block_k,dkdv_q,dkdv_k",
+        [
+            (128, 128, None, None),
+            (256, 256, None, None),
+            # dq: rows resident, keys streamed; dk/dv: keys resident, queries
+            # streamed — the unequal pairs _auto_block can pick, and their mirror.
+            (512, 256, 256, 512),
+            (512, 128, 128, 512),
+            (128, 256, 256, 128),
+        ],
+    )
+    def test_fused_bwd_matches_dense_grads(self, block_q, block_k, dkdv_q, dkdv_k):
         from llmtrain_tpu.ops.pallas_attention import (
             pallas_flash_attention_bwd,
             pallas_flash_attention_fwd,
         )
 
-        q, k, v = _qkv(t=256, dtype=jnp.float32, seed=3)
+        q, k, v = _qkv(t=512, dtype=jnp.float32, seed=3)
         g = jax.random.normal(jax.random.key(7), q.shape, jnp.float32)
 
         def loss(q, k, v):
@@ -277,7 +292,8 @@ class TestCompiledBackward:
                 q, k, v, block_q=block_q, block_k=block_k
             )
             dq, dk, dv = pallas_flash_attention_bwd(
-                q, k, v, out, lse, g, block_q=block_q, block_k=block_k
+                q, k, v, out, lse, g, block_q=block_q, block_k=block_k,
+                dkdv_block_q=dkdv_q, dkdv_block_k=dkdv_k,
             )
             rq, rk, rv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
         np.testing.assert_allclose(
@@ -315,6 +331,40 @@ class TestCompiledBackward:
             np.testing.assert_allclose(
                 np.asarray(jax.device_get(got), np.float32),
                 np.asarray(jax.device_get(want)),
+                atol=0.1, rtol=0.1,
+            )
+
+    @pytest.mark.parametrize("d,hkv", [(64, 4), (128, 2)], ids=["head64", "head128-gqa"])
+    def test_bf16_grads_with_the_picked_tiles_match_dense(self, d, hkv):
+        """The train cell's dtype through the dispatch, so with the tiles
+        ``_auto_block`` picks at T 1,024: bf16 operands straight to the MXU,
+        p and dS rounded to bf16 once, against the dense gradient on the SAME
+        bf16 inputs."""
+        from llmtrain_tpu.models.gpt import dense_attention
+        from llmtrain_tpu.ops.flash_attention import flash_attention
+
+        b, t, h = 2, 1024, 4
+        ks = jax.random.split(jax.random.key(21), 4)
+        q = jax.random.normal(ks[0], (b, t, h, d), jnp.bfloat16)
+        k = jax.random.normal(ks[1], (b, t, hkv, d), jnp.bfloat16)
+        v = jax.random.normal(ks[2], (b, t, hkv, d), jnp.bfloat16)
+        g = jax.random.normal(ks[3], (b, t, h, d), jnp.bfloat16)
+
+        def dense(q, k, v):
+            wide = lambda x: jnp.repeat(x, h // hkv, axis=2)  # noqa: E731
+            return dense_attention(q, wide(k), wide(v), attention_mask=None)
+
+        out, vjp = jax.vjp(flash_attention, q, k, v)
+        ref, ref_vjp = jax.vjp(dense, q, k, v)
+        np.testing.assert_allclose(
+            np.asarray(jax.device_get(out), np.float32),
+            np.asarray(jax.device_get(ref), np.float32), atol=2e-2,
+        )
+        for got, want in zip(vjp(g), ref_vjp(g)):
+            assert got.dtype == jnp.bfloat16
+            np.testing.assert_allclose(
+                np.asarray(jax.device_get(got), np.float32),
+                np.asarray(jax.device_get(want), np.float32),
                 atol=0.1, rtol=0.1,
             )
 
