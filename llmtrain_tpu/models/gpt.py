@@ -188,6 +188,45 @@ def paged_block_fold(block_tokens: int, width: int) -> int:
     return block_tokens
 
 
+def paged_pool_writer(pos: jax.Array, block_tables: jax.Array, block_tokens: int, width: int):
+    """``write(pool, rows)``: the ``(B, t, width)`` rows of this call's
+    tokens, at absolute positions ``pos`` (B, t), set into a pool leaf
+    ``(num_blocks, block_tokens // fold, fold * width)`` through the rows'
+    block tables. Every paged leaf, whatever its row holds (K or V of
+    ``kv_heads`` heads; one latent row, models/latent_moe.py), is written
+    through this."""
+    fold = paged_block_fold(block_tokens, width)
+    blocks = jnp.take_along_axis(block_tables, pos // block_tokens, axis=1)  # (B, t)
+    slots = pos % block_tokens
+    # Distinct rows hold disjoint physical blocks (allocator invariant),
+    # so the only duplicate targets are padded rows' null-block writes —
+    # garbage nothing live ever reads.
+    if fold == 1:
+        # One position a row: whole-row scatter, in place on the pool.
+        def write(pool: jax.Array, rows: jax.Array) -> jax.Array:
+            return pool.at[blocks, slots].set(rows)
+
+    else:
+        # `fold` positions a row: slot s is the `width` lanes from
+        # (s % fold) * width of row s // fold, written as a window.
+        # The TPU compiler turns a windowed scatter into a loop of one
+        # in-place update a token (a whole-row scatter it runs as one
+        # op), so only rows narrower than a lane tile come here.
+        where = jnp.stack(
+            [blocks, slots // fold, (slots % fold) * width], axis=-1
+        )
+        window = jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(2,),
+            inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2),
+        )
+
+        def write(pool: jax.Array, rows: jax.Array) -> jax.Array:
+            return jax.lax.scatter(pool, where, rows, window)
+
+    return write
+
+
 class CausalSelfAttention(nn.Module):
     d_model: int
     n_heads: int
@@ -721,34 +760,7 @@ class CausalSelfAttention(nn.Module):
             from ..ops.rope import apply_rope
 
             q, k = apply_rope(q, k, pos, theta=self.rope_theta)
-        blocks = jnp.take_along_axis(block_tables, pos // bt, axis=1)  # (B, t)
-        slots = pos % bt
-        # Distinct rows hold disjoint physical blocks (allocator invariant),
-        # so the only duplicate targets are padded rows' null-block writes —
-        # garbage nothing live ever reads.
-        if fold == 1:
-            # One position a row: whole-row scatter, in place on the pool.
-            def write(pool: jax.Array, rows: jax.Array) -> jax.Array:
-                return pool.at[blocks, slots].set(rows)
-
-        else:
-            # `fold` positions a row: slot s is the `width` lanes from
-            # (s % fold) * width of row s // fold, written as a window.
-            # The TPU compiler turns a windowed scatter into a loop of one
-            # in-place update a token (a whole-row scatter it runs as one
-            # op), so only rows narrower than a lane tile come here.
-            where = jnp.stack(
-                [blocks, slots // fold, (slots % fold) * width], axis=-1
-            )
-            window = jax.lax.ScatterDimensionNumbers(
-                update_window_dims=(2,),
-                inserted_window_dims=(0, 1),
-                scatter_dims_to_operand_dims=(0, 1, 2),
-            )
-
-            def write(pool: jax.Array, rows: jax.Array) -> jax.Array:
-                return jax.lax.scatter(pool, where, rows, window)
-
+        write = paged_pool_writer(pos, block_tables, bt, width)
         for leaf, new in ((paged_key, k), (paged_value, v)):
             leaf.value = write(
                 leaf.value, new.astype(leaf.value.dtype).reshape(batch, t, width)

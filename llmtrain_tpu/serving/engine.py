@@ -42,6 +42,22 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   and ``state_bytes`` / ``scan_chunks`` (prefill), the COW copy leaves
   those leaves alone, and the prefix cache and ``verify`` are refused by
   name. When it holds none, nothing of this is staged, compiled or counted.
+* **A pool leaf is whatever the model pages.** Per-head K and V
+  (``paged_key`` / ``paged_value``) or one latent row a position
+  (``paged_latent``, models/latent_moe.py): the engine indexes a pool
+  leaf's leading (block) axis only, and ``kv_live_tokens`` /
+  ``kv_gathered_tokens`` count positions, whatever a position holds.
+* **What the expert layers of a decode call did.** A model that says it
+  has expert layers (``expert_layers``; models/moe.py:DroplessMoE sows
+  ``moe_stats``) gets two int32 appended to the decode program's sampled
+  tokens, so they come off the device in the tokens' own read-back, and
+  the call's ``serve/engine.fetch`` span carries them: ``expert_pairs``
+  (token-expert pairs routed to experts held here, summed over the call's
+  expert layers and over every row of the call's bucket) and
+  ``experts_hit`` (held experts that got at least one pair, summed over
+  layers). They are on ``fetch`` and not on ``stage`` because they exist
+  only once the program has run. A model without expert layers compiles
+  and reads back exactly what it did before.
 """
 
 from __future__ import annotations
@@ -137,6 +153,9 @@ def _sample_rows(
     return jnp.where(temps == 0.0, greedy_tok, sampled).astype(jnp.int32)
 
 
+EXPERT_COUNTERS = ("expert_pairs", "experts_hit")  # what models/moe.py:DroplessMoE sows as `counts`
+
+
 def _prefill_impl(
     model: Any,
     params: Any,
@@ -192,18 +211,23 @@ def _decode_impl(
     state_rows: jax.Array | None = None,  # (B,) int32, models with state leaves
 ) -> tuple[Any, jax.Array]:
     state = {} if state_rows is None else {"state_rows": state_rows}
+    counts_experts = bool(getattr(model, "expert_layers", 0))
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
         tokens[:, None],
         deterministic=True,
         positions=positions,
         block_tables=block_tables,
-        mutable=["cache"],
+        mutable=["cache", "moe_stats"] if counts_experts else ["cache"],
         **state,
     )
     tok = _sample_rows(
         logits[:, -1].astype(jnp.float32), seeds, emit_idx, temps, top_ks, top_ps
     )
+    if counts_experts:
+        # (B + 2,): the tokens, then EXPERT_COUNTERS over the call's expert
+        # layers, in the one array the host reads back.
+        tok = jnp.concatenate([tok, sum(jax.tree.leaves(mutated["moe_stats"]))])
     return mutated["cache"], tok
 
 
@@ -357,6 +381,7 @@ class PagedDecodeEngine:
         )
         self._state_leaves = len(state_leaves)
         self._scan_chunk = int(getattr(self.decode_model, "state_scan_chunk", 0))
+        self._counts_experts = bool(getattr(self.decode_model, "expert_layers", 0))
         # Raises by name on prefix_cache with state rows (paged_kv.py).
         self.pool = PagedKVPool(
             num_blocks,
@@ -579,8 +604,11 @@ class PagedDecodeEngine:
             self._recover_cache_after_error()
             raise
         self._cache = cache
-        with self._span("fetch", "decode"):
-            return [int(t) for t in np.asarray(jax.device_get(tok))[:n]]
+        with self._span("fetch", "decode") as counted:
+            host = np.asarray(jax.device_get(tok))
+            if self._counts_experts and counted is not None:
+                counted.update(zip(EXPERT_COUNTERS, map(int, host[bb:])))
+            return [int(t) for t in host[:n]]
 
     def verify(
         self,

@@ -988,3 +988,128 @@ class TestModelsWithoutState:
             "call", "prompt_tokens", "bucket", "kv_live_tokens", "kv_gathered_tokens"
         }
         assert "state_leaves" not in engine.compile_stats() and "state_rows_free" not in engine.pool.stats()
+
+
+# an expert layer and a latent pool behind the engine (models/latent_moe.py)
+# ---------------------------------------------------------------------------
+
+
+def _latent_moe(**kw):
+    """1 dense + 2 expert layers, 4 heads of 8 + 4 / 8 over a 12-wide latent
+    row, 8 experts in 4 groups (2 stay, 3 a token) of which this holder has
+    experts 2-5, a shared expert, YaRN."""
+    from llmtrain_tpu.models.latent_moe import LatentMoE
+
+    base = dict(
+        vocab_size=VOCAB, block_size=64, d_model=32, n_layers=3, n_heads=4, d_ff=48, q_lora_rank=12,
+        kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8, moe_intermediate_size=16,
+        n_routed_experts=8, num_experts_per_tok=3, n_group=4, topk_group=2, routed_scaling_factor=2.5,
+        experts_held=(2, 4),
+        rope_scaling=(("factor", 32.0), ("original_max_position_embeddings", 16.0), ("beta_fast", 32.0),
+                      ("beta_slow", 1.0), ("mscale", 1.0), ("mscale_all_dim", 1.0)),
+    )
+    return LatentMoE(**{**base, **kw})
+
+
+def _shaken_params(model, seed):
+    """The initialiser's draw with noise on every leaf: away from its
+    symmetry, routers that disagree, norms off 1."""
+    leaves, tree = jax.tree.flatten(_unboxed_params(model))
+    keys = jax.random.split(jax.random.key(seed), len(leaves))
+    return jax.tree.unflatten(
+        tree, [x + 0.3 * jax.random.normal(k, x.shape, x.dtype) for x, k in zip(leaves, keys)]
+    )
+
+
+@pytest.fixture(scope="module")
+def latent_moe_model():
+    model = _latent_moe()
+    return model, _shaken_params(model, 11)
+
+
+class TestExpertLayerBehindTheEngine:
+    def test_served_tokens_agree_with_the_full_forward_and_the_fetch_span_counts_the_experts(self, latent_moe_model):
+        """Seven requests on three slots through the latent pool: prefill
+        materialises keys and values, decode attends the latent rows
+        absorbed, the expert layers regroup each call's tokens; float32
+        throughout, so the served tokens ARE the full forward's greedy tokens."""
+        from contextlib import nullcontext
+
+        model, params = latent_moe_model
+        engine = _state_engine(model, params)
+        assert engine.state_bytes_per_row == 0 and engine.pool.state_rows == 0  # the latent is paged, not a state row
+        leaves = jax.tree.leaves(engine._cache)
+        assert len(leaves) == 3 and {leaf.shape for leaf in leaves} == {(25, 1, 8 * 12)}  # one leaf a layer
+        spans = []
+        engine.span_factory = lambda n, **a: (spans.append((n, a)), nullcontext(a))[1]
+        scheduler = ContinuousBatchingScheduler(engine)
+        reqs = _falcon_requests(
+            np.random.default_rng(0), [(5, 6), (17, 9), (9, 3), (30, 12), (3, 20), (12, 5), (8, 8)]
+        )
+        for r in reqs:
+            scheduler.submit(r)
+        _drain(scheduler, reqs)
+        for r in reqs:
+            assert r.finish_reason == "length", r.error
+            want, gap = _full_forward_tokens(model, params, r)
+            assert r.tokens == want and gap == 0.0
+        assert engine.pool.stats()["allocated_blocks"] == 0 and engine.compile_stats()["within_budget"]
+        # Two counters beside the tokens, on the fetch span of a DECODE call only.
+        fetch = [a for n, a in spans if n == "serve/engine.fetch"]
+        decode = [a for a in fetch if a["call"] == "decode"]
+        assert decode and all({"expert_pairs", "experts_hit"} <= set(a) for a in decode)
+        assert all("expert_pairs" not in a for a in fetch if a["call"] == "prefill")
+        # 3 rows (the bucket) x 3 experts a token x 2 expert layers bound the pairs; 4 held x 2 the hits.
+        assert all(0 <= a["experts_hit"] <= min(a["expert_pairs"], 8) and a["expert_pairs"] <= 18 for a in decode)
+        assert sum(a["expert_pairs"] for a in decode) > 0
+        stage = [a for n, a in spans if n == "serve/engine.stage" and a["call"] == "decode"]
+        assert all(a["kv_gathered_tokens"] == 3 * 64 and 0 < a["kv_live_tokens"] <= 3 * 64 for a in stage)
+
+    def test_the_counters_are_the_layers_own_and_a_holder_of_everything_sees_every_pair(self, latent_moe_model):
+        from contextlib import nullcontext
+
+        model, params = latent_moe_model
+        whole = _latent_moe(experts_held=None)
+        engine = _state_engine(whole, _shaken_params(whole, 12))
+        spans = []
+        engine.span_factory = lambda n, **a: (spans.append((n, a)), nullcontext(a))[1]
+        scheduler = ContinuousBatchingScheduler(engine)
+        reqs = _falcon_requests(np.random.default_rng(1), [(6, 4), (11, 4), (4, 4)])
+        for r in reqs:
+            scheduler.submit(r)
+        _drain(scheduler, reqs)
+        decode = [a for n, a in spans if n == "serve/engine.fetch" and a["call"] == "decode"]
+        assert decode and all(a["expert_pairs"] == 3 * 3 * 2 for a in decode)  # rows x top-k x expert layers
+        # without a span factory the call returns its tokens and nothing else
+        bare = _state_engine(model, params)
+        other = ContinuousBatchingScheduler(bare)
+        req = _falcon_requests(np.random.default_rng(2), [(7, 5)])[0]
+        other.submit(req)
+        _drain(other, [req])
+        assert len(req.tokens) == 5 and req.tokens == _full_forward_tokens(model, params, req)[0]
+
+    def test_cow_copy_prefix_reuse_and_chunked_prefill_hold_for_a_latent_leaf(self, latent_moe_model):
+        """`_cow_impl` indexes a leaf's block axis only, the prefix cache
+        hands a request the latent blocks another wrote, and a chunk of a
+        prompt attends the cached rest through the materialised path."""
+        model, params = latent_moe_model
+        rng = np.random.default_rng(3)
+        prefix = rng.integers(0, VOCAB, 16).astype(np.int32)
+        tails = [rng.integers(0, VOCAB, n).astype(np.int32) for n in (5, 9, 3)]
+        reqs = [ServeRequest(prompt_ids=np.concatenate([prefix, t]), max_new_tokens=6, temperature=0.0,
+                             eos_token_id=None, seed=i) for i, t in enumerate(tails)]
+        engine = _state_engine(model, params, prefix_cache=True, prefill_chunk=8)
+        scheduler = ContinuousBatchingScheduler(engine)
+        for r in reqs:
+            scheduler.submit(r)
+            _drain(scheduler, [r])  # one after another, so the later ones find the prefix cached
+        assert engine.pool.stats()["prefix_tokens_reused"] >= 2 * 16  # the later two found the prefix's latent blocks
+        for r in reqs:
+            assert r.tokens == _full_forward_tokens(model, params, r)[0]
+        before = [np.asarray(leaf) for leaf in jax.tree.leaves(engine._cache)]
+        engine.cow_copy(1, 2)
+        for old, new in zip(before, jax.tree.leaves(engine._cache)):
+            np.testing.assert_array_equal(np.asarray(new)[2], old[1])
+            np.testing.assert_array_equal(np.asarray(new)[3:], old[3:])
+        profiles = engine.cost_profile(full=False)  # the AOT cost profile takes the leaf as it is
+        assert {p["name"] for p in profiles} == {"prefill_T32", "decode_B3"}

@@ -646,3 +646,122 @@ class TestStateRowsUpdateInPlace:
                 else:
                     assert op in POOL_IN_PLACE, f"state-sized `{op}` in {program}: {line.strip()[:300]}"
         assert writers == STATE_LAYERS
+
+
+# -- the latent pool and the held experts (models/latent_moe.py, PR 31) -------
+
+LATENT_SLOTS, LATENT_CONTEXT, LATENT_LAYERS = 96, 4096, 2
+
+
+@pytest.fixture(scope="module")
+def latent_programs(one_chip):
+    """Decode (96 rows) and prefill (one prompt in the 1,024 bucket) of the
+    A.X-K1 configuration the benchmark runs, at its published widths, its
+    share of the experts and its slice of the vocabulary, the cell's 96 slots
+    x 4,096 positions, 2 layers (the dense one and ONE expert layer), as
+    ``PagedDecodeEngine`` jits them; abstract shapes only."""
+    import functools
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    from benchmarks.reference import axk1 as ref
+    from llmtrain_tpu.config.schemas import RunConfig
+    from llmtrain_tpu.models.lora import build_adapter
+    from llmtrain_tpu.registry import initialize_registries
+    from llmtrain_tpu.serving import engine
+
+    cfg = json.loads((root / "benchmarks/configs/ax-k1.json").read_text())
+    cfg["num_hidden_layers"] = LATENT_LAYERS
+    initialize_registries()
+    run = RunConfig.model_validate({
+        "schema_version": 1, "run": {"name": "aot", "seed": 1, "device": "cpu"}, "model": ref.program_model(cfg),
+        "data": {"name": "dummy_text"}, "trainer": {"max_steps": 1, "micro_batch_size": 1, "warmup_steps": 0},
+        "mlflow": {"enabled": False},
+    })
+    mb = LATENT_CONTEXT // POOL_BLOCK_TOKENS
+    paged = build_adapter(run).build_model(run).for_paged_decoding(
+        num_blocks=1 + LATENT_SLOTS * mb, block_tokens=POOL_BLOCK_TOKENS
+    )
+    variables = jax.eval_shape(
+        lambda: paged.init(
+            jax.random.key(0), jnp.zeros((1, 1), jnp.int32), deterministic=True,
+            positions=jnp.zeros((1,), jnp.int32), block_tables=jnp.zeros((1, mb), jnp.int32),
+        )
+    )
+
+    def on_chip(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # What the server holds: bf16 but the router, which the program declares float32.
+    params = jax.tree.map(
+        lambda s: on_chip(*s.shape, dtype=s.dtype if s.dtype == jnp.float32 else jnp.bfloat16),
+        variables["params"],
+    )
+    cache = jax.tree.map(lambda s: on_chip(*s.shape, dtype=s.dtype), variables["cache"])
+
+    def sampling(rows):
+        return (on_chip(rows, dtype=jnp.uint32), on_chip(rows, dtype=jnp.float32),
+                on_chip(rows), on_chip(rows, dtype=jnp.float32))
+
+    seeds, *knobs = sampling(LATENT_SLOTS)
+    shapes = {
+        "decode": (functools.partial(engine._decode_impl, paged),
+                   (params, cache, on_chip(LATENT_SLOTS), on_chip(LATENT_SLOTS), on_chip(LATENT_SLOTS, mb),
+                    seeds, on_chip(LATENT_SLOTS), *knobs)),
+        "prefill": (functools.partial(engine._prefill_impl, paged),
+                    (params, cache, on_chip(1, 1024), on_chip(1), on_chip(1), on_chip(1, mb), *sampling(1))),
+    }
+    return {
+        name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text() for name, (fn, args) in shapes.items()
+    }, {leaf.shape for leaf in jax.tree.leaves(cache)}
+
+
+class TestLatentPoolKeepsItsLayout:
+    """PR 25's lesson read before the first chip run of the latent cache: a
+    pool row of 576 values (4.5 lane tiles) the compiler kept ``num_blocks``
+    minor and copied whole, twice a layer, in every program; padded to 640
+    lanes the leaf is row-major, the donated input aliases the output in
+    that layout, and nothing as large as the leaf exists but the leaf itself
+    passed along and the ONE fusion a layer that scatters the call's rows
+    into it. And the expert layer regroups its tokens: no array pairs the
+    tokens with all 192 experts beyond the router's own (tokens, experts)
+    scores, and the held experts run as grouped products."""
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_latent_leaf_is_row_major_and_updated_in_place(self, latent_programs, program):
+        texts, leaves = latent_programs
+        assert leaves == {(1 + 96 * 256, 16, 640)}
+        text = texts[program]
+        params, results, aliased = _entry_layout(text)
+        pool = [i for i, p in enumerate(params) if "[24577,16,640]" in p]
+        assert len(pool) == LATENT_LAYERS
+        for i in pool:
+            assert "{2,1,0:" in params[i], f"the latent leaf is not row-major: {params[i]}"
+            assert i in aliased and results[aliased[i]] == params[i], "not donated in place in one layout"
+        leaf = 24577 * 16 * 640  # (the prefill's float32 scores, 64 x 1,024 x 4,096, are larger: not a pool)
+        for op, result, called, line in _hlo_instructions(text):
+            if _elements(result) != leaf:
+                continue
+            in_place = op in POOL_IN_PLACE or (
+                op == "fusion" and re.search(r" (scatter|dynamic-update-slice)\(", _computation(text, called))
+            )
+            assert in_place, f"pool-sized `{op}` in {program}: {line.strip()[:300]}"
+
+    def test_decode_regroups_its_tokens_and_holds_no_one_hot_over_the_experts(self, latent_programs):
+        text = latent_programs[0]["decode"]
+        assert "ragged-dot" in text  # the held experts: grouped products over the sorted pairs
+        routed = [(result, line) for _op, result, _called, line in _hlo_instructions(text) if "/moe/" in line]
+        assert len(routed) > 20  # the expert layer's instructions carry its scope
+        for result, line in routed:
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+                sizes = [int(d) for d in dims.split(",")]
+                # (tokens, experts) is the router's scores and (tokens, groups, experts a group)
+                # their grouping; anything over tokens or pairs AND all 192 experts (or the
+                # 12 held) AND a third axis would be a dispatch one-hot.
+                over_tokens = any(n in (96, 96 * 8) for n in sizes)
+                over_experts = 192 in sizes or (12 in sizes and 7168 not in sizes and 2048 not in sizes)
+                assert not (over_tokens and over_experts and len(sizes) > 2), line.strip()[:300]
