@@ -1,0 +1,36 @@
+"""engine (index-selected attention + held experts): over the window's decode
+calls, the least time the chip could take for the bytes a call MUST move,
+over the time the calls took (summed ``serve/decode`` spans), in %.
+
+Bytes of one call: the weights every call reads (``weight_bytes``: every layer
+outside its routed experts, and the head), the routed experts some token of
+the call picked (the ``experts_hit`` counter of the call's
+``serve/engine.fetch`` span x ``expert_bytes``), the index key of every live
+position, which the call must SCORE (``kv_live_tokens`` of its
+``serve/engine.stage`` span x ``index_bytes_per_position``), and K and V of
+the positions it ATTENDS (``kv_selected_tokens`` of the same span x
+``kv_bytes_per_position``); at the device's HBM bandwidth (lib/peaks.py). The
+byte functions are the family's (``reference/<family>.py``). A program that
+counts no ``kv_selected_tokens`` (a model that selects nothing, or the parent
+of the PR that added the counter) gives nothing to read."""
+
+from benchmarks.lib.peaks import peaks_for
+from benchmarks.lib.span_tree import spans
+
+
+def read(run):
+    ref, cfg = run["reference"], run["config"]
+    if run["device"]["platform"] != "tpu" or not hasattr(ref, "index_bytes_per_position"):
+        return None  # a share of a chip's bandwidth exists only on the chip
+    decodes = lambda name: [s[3] for s in spans(run, name) if s[3].get("call") == "decode"]  # noqa: E731
+    hits = [a["experts_hit"] for a in decodes("serve/engine.fetch") if "experts_hit" in a]
+    stages = [a for a in decodes("serve/engine.stage") if "kv_selected_tokens" in a]
+    taken_s = sum(t1 - t0 for _, t0, t1, _ in spans(run, "serve/decode"))
+    if not hits or not stages or not taken_s:
+        return None
+    moved = (
+        len(stages) * ref.weight_bytes(cfg) + sum(hits) * ref.expert_bytes(cfg)
+        + sum(a["kv_live_tokens"] for a in stages) * ref.index_bytes_per_position(cfg)
+        + sum(a["kv_selected_tokens"] for a in stages) * ref.kv_bytes_per_position(cfg)
+    )
+    return 100.0 * moved / float(peaks_for(run["device"]["kind"])["hbm_bytes_per_s"]) / taken_s

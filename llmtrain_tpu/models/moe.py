@@ -311,7 +311,8 @@ class DroplessMoE(nn.Module):
     """Routed SwiGLU experts with no capacity: every chosen pair whose
     expert this layer holds is computed.
 
-    ``scores = sigmoid(x W_router)`` over ALL ``n_experts`` (float32);
+    ``scores = sigmoid(x W_router)`` over ALL ``n_experts`` (float32;
+    ``scoring = "softmax"``: ``softmax(x W_router)`` over all of them);
     :func:`group_limited_top_k` picks ``top_k`` a token; their weights are
     ``scale * s_i / sum_chosen s_j`` (``normalize``; else ``scale * s_i``),
     the sum over all chosen experts, held here or not.
@@ -346,6 +347,7 @@ class DroplessMoE(nn.Module):
     topk_group: int = 1
     normalize: bool = True
     scale: float = 1.0
+    scoring: str = "sigmoid"
     experts_held: tuple[int, int] | None = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -355,6 +357,8 @@ class DroplessMoE(nn.Module):
         lead, d_model = x.shape[:-1], x.shape[-1]
         first, count = self.experts_held or (0, self.n_experts)
         k = self.top_k
+        if self.scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring {self.scoring!r} unknown; expected 'sigmoid' or 'softmax'")
         if not 0 < k <= self.n_experts:
             raise ValueError(f"top_k {k} must lie in 1..n_experts ({self.n_experts})")
         if self.n_experts % self.n_group or not 0 < self.topk_group <= self.n_group:
@@ -373,7 +377,8 @@ class DroplessMoE(nn.Module):
 
         with jax.named_scope("moe_router"):
             router = _router(self.n_experts, jax.lax.Precision.HIGHEST)
-            scores = jax.nn.sigmoid(router(tokens.astype(jnp.float32)))
+            logits = router(tokens.astype(jnp.float32))
+            scores = jax.nn.sigmoid(logits) if self.scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
             picked, weights = group_limited_top_k(
                 scores, top_k=k, n_group=self.n_group, topk_group=self.topk_group
             )

@@ -43,8 +43,9 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   those leaves alone, and the prefix cache and ``verify`` are refused by
   name. When it holds none, nothing of this is staged, compiled or counted.
 * **A pool leaf is whatever the model pages.** Per-head K and V
-  (``paged_key`` / ``paged_value``) or one latent row a position
-  (``paged_latent``, models/latent_moe.py): the engine indexes a pool
+  (``paged_key`` / ``paged_value``), one latent row a position
+  (``paged_latent``, models/latent_moe.py) or an index key beside K and V
+  (``paged_index``, models/indexed_moe.py): the engine indexes a pool
   leaf's leading (block) axis only, and ``kv_live_tokens`` /
   ``kv_gathered_tokens`` count positions, whatever a position holds.
 * **What the expert layers of a decode call did.** A model that says it
@@ -58,6 +59,16 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   layers). They are on ``fetch`` and not on ``stage`` because they exist
   only once the program has run. A model without expert layers compiles
   and reads back exactly what it did before.
+* **What a selection scored and attended.** A model whose queries attend
+  a chosen subset of the cache says how many positions at most
+  (``selects_positions``; models/indexed_moe.py). The ``stage`` span of its
+  calls then also carries, computed on the host from positions alone: of a
+  decode ``kv_selected_tokens`` (sum over rows of ``min(live, topk)``: the
+  positions whose K/V the call must read, where ``kv_live_tokens`` are those
+  it must score) and ``rows_past_topk`` (rows that select at all); of a
+  prefill ``index_pairs`` (sum over the slab's positions ``p`` of ``p + 1``:
+  (query, position) pairs scored) and ``selected_pairs`` (sum of ``min(p +
+  1, topk)``: pairs attended). Other models' spans are what they were.
 """
 
 from __future__ import annotations
@@ -382,6 +393,7 @@ class PagedDecodeEngine:
         self._state_leaves = len(state_leaves)
         self._scan_chunk = int(getattr(self.decode_model, "state_scan_chunk", 0))
         self._counts_experts = bool(getattr(self.decode_model, "expert_layers", 0))
+        self._selects = int(getattr(self.decode_model, "selects_positions", 0))
         # Raises by name on prefix_cache with state rows (paged_kv.py).
         self.pool = PagedKVPool(
             num_blocks,
@@ -512,6 +524,10 @@ class PagedDecodeEngine:
             counted["state_bytes"] = (2 if offset else 1) * self.state_bytes_per_row
             if self._scan_chunk:
                 counted["scan_chunks"] = -(-tb // self._scan_chunk)
+        if self._selects:
+            seen = np.arange(int(offset), int(offset) + tp, dtype=np.int64) + 1  # positions a query may see
+            counted["index_pairs"] = int(seen.sum())
+            counted["selected_pairs"] = int(np.minimum(seen, self._selects).sum())
         with self._span("stage", "prefill", **counted):
             prompt = np.zeros((1, tb), np.int32)
             prompt[0, :tp] = prompt_ids
@@ -574,6 +590,9 @@ class PagedDecodeEngine:
             # What the call must move: each real row's state, read and written.
             counted["state_rows"] = n
             counted["state_bytes"] = 2 * n * self.state_bytes_per_row
+        if self._selects:
+            counted["kv_selected_tokens"] = sum(min(int(r["position"]) + 1, self._selects) for r in rows)
+            counted["rows_past_topk"] = sum(int(r["position"]) + 1 > self._selects for r in rows)
         with self._span("stage", "decode", **counted):
             tables = np.zeros((bb, mb), np.int32)
             for i, r in enumerate(rows):

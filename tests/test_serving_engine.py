@@ -1113,3 +1113,116 @@ class TestExpertLayerBehindTheEngine:
             np.testing.assert_array_equal(np.asarray(new)[3:], old[3:])
         profiles = engine.cost_profile(full=False)  # the AOT cost profile takes the leaf as it is
         assert {p["name"] for p in profiles} == {"prefill_T32", "decode_B3"}
+
+
+# attention over a chosen subset of the cache behind the engine (models/indexed_moe.py)
+# ---------------------------------------------------------------------------
+
+
+def _indexed_moe(**kw):
+    """2 layers, 4 query / 2 K/V heads of 8 with per-head q/k norms, an
+    indexer of 3 heads of 4 that picks topk 6 positions a query (queries in
+    chunks of 8), 8 softmax-routed experts (3 a token) of which this holder
+    has experts 2-5."""
+    from llmtrain_tpu.models.indexed_moe import IndexedMoE
+
+    base = dict(
+        vocab_size=VOCAB, block_size=64, d_model=32, n_layers=2, n_heads=4, num_key_value_heads=2, head_dim=8,
+        indexer_num_heads=3, indexer_head_dim=4, topk=6, q_chunk_size=8, kv_chunk_size=8, moe_intermediate_size=16,
+        num_experts=8, num_experts_per_tok=3, experts_held=(2, 4), rope_theta=1e7,
+    )
+    return IndexedMoE(**{**base, **kw})
+
+
+@pytest.fixture(scope="module")
+def indexed_moe_model():
+    model = _indexed_moe()
+    return model, _shaken_params(model, 21)
+
+
+class TestSelectionBehindTheEngine:
+    def test_served_tokens_agree_with_the_full_forward_and_the_stage_span_counts_the_selection(
+            self, indexed_moe_model, latent_moe_model):
+        """Seven requests on three slots through three pool leaves a layer:
+        prefill selects under a mask in chunks of queries, decode scores the
+        row's table, takes the top 6 and gathers THEIR K/V rows; prompts
+        under and past ``topk``, answers that cross it; float32 throughout,
+        so the served tokens ARE the full forward's greedy tokens."""
+        from contextlib import nullcontext
+
+        model, params = indexed_moe_model
+        engine = _state_engine(model, params)
+        assert engine.state_bytes_per_row == 0 and engine.pool.state_rows == 0  # the index key is paged, not a state row
+        leaves = {jax.tree_util.keystr(p): leaf.shape for p, leaf in jax.tree_util.tree_leaves_with_path(engine._cache)}
+        assert len(leaves) == 6  # K, V and the index key, in each of two layers
+        assert {s for n, s in leaves.items() if "paged_index" in n} == {(25, 8, 128)}  # 4 wide, padded to a lane tile
+        assert {s for n, s in leaves.items() if "paged_index" not in n} == {(25, 1, 8 * 16)}
+        spans = []
+        engine.span_factory = lambda n, **a: (spans.append((n, a)), nullcontext(a))[1]
+        scheduler = ContinuousBatchingScheduler(engine)
+        shapes = [(5, 6), (17, 9), (9, 3), (30, 12), (3, 20), (12, 5), (8, 8)]
+        reqs = _falcon_requests(np.random.default_rng(0), shapes)
+        for r in reqs:
+            scheduler.submit(r)
+        _drain(scheduler, reqs)
+        for r in reqs:
+            assert r.finish_reason == "length", r.error
+            want, gap = _full_forward_tokens(model, params, r)
+            assert r.tokens == want and gap == 0.0
+        assert engine.pool.stats()["allocated_blocks"] == 0 and engine.compile_stats()["within_budget"]
+        # What a selection scored and attended, from positions alone, on the stage span.
+        stage = [a for n, a in spans if n == "serve/engine.stage"]
+        prefill = sorted((a["prompt_tokens"], a["index_pairs"], a["selected_pairs"]) for a in stage if a["call"] == "prefill")
+        assert prefill == sorted(
+            (n, n * (n + 1) // 2, sum(min(p + 1, 6) for p in range(n))) for n, _ in shapes)
+        decode = [a for a in stage if a["call"] == "decode"]
+        assert decode and all({"kv_selected_tokens", "rows_past_topk"} <= set(a) for a in decode)
+        assert all(a["kv_selected_tokens"] <= min(a["kv_live_tokens"], 3 * 6) and 0 <= a["rows_past_topk"] <= 3
+                   for a in decode)
+        assert any(a["kv_selected_tokens"] < a["kv_live_tokens"] for a in decode)  # the selection was live ...
+        assert min(a["rows_past_topk"] for a in decode) < 3 <= max(a["rows_past_topk"] for a in decode)  # ... not in every row
+        fetch = [a for n, a in spans if n == "serve/engine.fetch" and a["call"] == "decode"]
+        assert fetch and all({"expert_pairs", "experts_hit"} <= set(a) for a in fetch)  # the expert layers' counters ride along
+        # a model that selects nothing counts none of it
+        assert _state_engine(*latent_moe_model)._selects == 0
+
+    def test_cow_copy_recovery_prefix_reuse_and_chunked_prefill_hold_for_the_index_leaf(self, indexed_moe_model):
+        """`_cow_impl` copies a block in all three leaves, the prefix cache
+        hands a request the K, V AND index keys another wrote, a chunk of a
+        prompt selects among the cached rest, and a failed call that consumed
+        the index leaf gets it rebuilt."""
+        model, params = indexed_moe_model
+        rng = np.random.default_rng(3)
+        prefix = rng.integers(0, VOCAB, 16).astype(np.int32)
+        tails = [rng.integers(0, VOCAB, n).astype(np.int32) for n in (5, 9, 3)]
+        reqs = [ServeRequest(prompt_ids=np.concatenate([prefix, t]), max_new_tokens=6, temperature=0.0,
+                             eos_token_id=None, seed=i) for i, t in enumerate(tails)]
+        engine = _state_engine(model, params, prefix_cache=True, prefill_chunk=8)
+        scheduler = ContinuousBatchingScheduler(engine)
+        for r in reqs:
+            scheduler.submit(r)
+            _drain(scheduler, [r])  # one after another, so the later ones find the prefix cached
+        assert engine.pool.stats()["prefix_tokens_reused"] >= 2 * 16
+        for r in reqs:
+            assert r.tokens == _full_forward_tokens(model, params, r)[0]
+        before = [np.asarray(leaf) for leaf in jax.tree.leaves(engine._cache)]
+        assert any(leaf[1].any() for leaf in before)
+        engine.cow_copy(1, 2)
+        for old, new in zip(before, jax.tree.leaves(engine._cache)):
+            np.testing.assert_array_equal(np.asarray(new)[2], old[1])
+            np.testing.assert_array_equal(np.asarray(new)[3:], old[3:])
+        after = {jax.tree_util.keystr(p): np.asarray(leaf)
+                 for p, leaf in jax.tree_util.tree_leaves_with_path(engine._cache)}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(engine._cache):
+            if "paged_index" in jax.tree_util.keystr(path):
+                leaf.delete()
+        engine._recover_cache_after_error()
+        assert engine.cache_epoch == 1
+        for path, leaf in jax.tree_util.tree_leaves_with_path(engine._cache):
+            name = jax.tree_util.keystr(path)
+            if "paged_index" in name:
+                assert leaf.shape == after[name].shape and not np.asarray(leaf).any()
+            else:
+                np.testing.assert_array_equal(np.asarray(leaf), after[name])
+        profiles = engine.cost_profile(full=False)  # the AOT cost profile takes the three leaves as they are
+        assert {p["name"] for p in profiles} == {"prefill_T32", "decode_B3"}

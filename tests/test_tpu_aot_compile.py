@@ -653,14 +653,15 @@ class TestStateRowsUpdateInPlace:
 LATENT_SLOTS, LATENT_CONTEXT, LATENT_LAYERS = 96, 4096, 2
 
 
-@pytest.fixture(scope="module")
-def latent_programs(one_chip):
-    """Decode (96 rows) and prefill (one prompt in the 1,024 bucket) of the
-    A.X-K1 configuration the benchmark runs, at its published widths, its
-    share of the experts and its slice of the vocabulary, the cell's 96 slots
-    x 4,096 positions, 2 layers (the dense one and ONE expert layer), as
-    ``PagedDecodeEngine`` jits them; abstract shapes only."""
+def _cell_programs(one_chip, family: str, config: str, *, layers: int, slots: int, context: int, bucket: int):
+    """``({"decode": text, "prefill": text}, [cache leaf shapes])``: the decode
+    (``slots`` rows) and prefill (one prompt in ``bucket``) programs of a
+    benchmark configuration at its published widths, its share of the experts
+    and its slice of the vocabulary, ``slots`` x ``context`` positions,
+    ``layers`` of its layers, as ``PagedDecodeEngine`` jits them; abstract
+    shapes only."""
     import functools
+    import importlib
     import json
     import sys
     from pathlib import Path
@@ -668,23 +669,23 @@ def latent_programs(one_chip):
     root = Path(__file__).resolve().parents[1]
     if str(root) not in sys.path:
         sys.path.insert(0, str(root))
-    from benchmarks.reference import axk1 as ref
+    ref = importlib.import_module(f"benchmarks.reference.{family}")
     from llmtrain_tpu.config.schemas import RunConfig
     from llmtrain_tpu.models.lora import build_adapter
     from llmtrain_tpu.registry import initialize_registries
     from llmtrain_tpu.serving import engine
 
-    cfg = json.loads((root / "benchmarks/configs/ax-k1.json").read_text())
-    cfg["num_hidden_layers"] = LATENT_LAYERS
+    cfg = json.loads((root / "benchmarks/configs" / config).read_text())
+    cfg["num_hidden_layers"] = layers
     initialize_registries()
     run = RunConfig.model_validate({
         "schema_version": 1, "run": {"name": "aot", "seed": 1, "device": "cpu"}, "model": ref.program_model(cfg),
         "data": {"name": "dummy_text"}, "trainer": {"max_steps": 1, "micro_batch_size": 1, "warmup_steps": 0},
         "mlflow": {"enabled": False},
     })
-    mb = LATENT_CONTEXT // POOL_BLOCK_TOKENS
+    mb = context // POOL_BLOCK_TOKENS
     paged = build_adapter(run).build_model(run).for_paged_decoding(
-        num_blocks=1 + LATENT_SLOTS * mb, block_tokens=POOL_BLOCK_TOKENS
+        num_blocks=1 + slots * mb, block_tokens=POOL_BLOCK_TOKENS
     )
     variables = jax.eval_shape(
         lambda: paged.init(
@@ -707,17 +708,26 @@ def latent_programs(one_chip):
         return (on_chip(rows, dtype=jnp.uint32), on_chip(rows, dtype=jnp.float32),
                 on_chip(rows), on_chip(rows, dtype=jnp.float32))
 
-    seeds, *knobs = sampling(LATENT_SLOTS)
+    seeds, *knobs = sampling(slots)
     shapes = {
         "decode": (functools.partial(engine._decode_impl, paged),
-                   (params, cache, on_chip(LATENT_SLOTS), on_chip(LATENT_SLOTS), on_chip(LATENT_SLOTS, mb),
-                    seeds, on_chip(LATENT_SLOTS), *knobs)),
+                   (params, cache, on_chip(slots), on_chip(slots), on_chip(slots, mb), seeds, on_chip(slots), *knobs)),
         "prefill": (functools.partial(engine._prefill_impl, paged),
-                    (params, cache, on_chip(1, 1024), on_chip(1), on_chip(1), on_chip(1, mb), *sampling(1))),
+                    (params, cache, on_chip(1, bucket), on_chip(1), on_chip(1), on_chip(1, mb), *sampling(1))),
     }
     return {
         name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text() for name, (fn, args) in shapes.items()
-    }, {leaf.shape for leaf in jax.tree.leaves(cache)}
+    }, sorted(leaf.shape for leaf in jax.tree.leaves(cache))
+
+
+@pytest.fixture(scope="module")
+def latent_programs(one_chip):
+    """Decode (96 rows) and prefill (one prompt in the 1,024 bucket) of the
+    A.X-K1 configuration the benchmark runs, the cell's 96 slots x 4,096
+    positions, 2 layers (the dense one and ONE expert layer)."""
+    texts, leaves = _cell_programs(one_chip, "axk1", "ax-k1.json", layers=LATENT_LAYERS, slots=LATENT_SLOTS,
+                                   context=LATENT_CONTEXT, bucket=1024)
+    return texts, set(leaves)
 
 
 class TestLatentPoolKeepsItsLayout:
@@ -765,3 +775,71 @@ class TestLatentPoolKeepsItsLayout:
                 over_tokens = any(n in (96, 96 * 8) for n in sizes)
                 over_experts = 192 in sizes or (12 in sizes and 7168 not in sizes and 2048 not in sizes)
                 assert not (over_tokens and over_experts and len(sizes) > 2), line.strip()[:300]
+
+
+# -- three pool leaves a layer and a gather of chosen rows (models/indexed_moe.py, PR 35) --
+
+INDEXED_SLOTS, INDEXED_CONTEXT, INDEXED_LAYERS, INDEXED_BUCKET = 64, 6656, 2, 6144
+
+
+@pytest.fixture(scope="module")
+def indexed_programs(one_chip):
+    """Decode (64 rows) and prefill (one prompt in the 6,144 bucket) of the
+    Keye-VL-2.0 configuration the benchmark runs, the cell's 64 slots x 6,656
+    positions, 2 of its 8 layers (all alike)."""
+    return _cell_programs(one_chip, "keye_vl2", "keye-vl2-30b-a3b.json", layers=INDEXED_LAYERS, slots=INDEXED_SLOTS,
+                          context=INDEXED_CONTEXT, bucket=INDEXED_BUCKET)
+
+
+class TestIndexedPoolKeepsItsLayout:
+    """Read before the first chip run of index-selected attention: K, V and
+    the index key are three leaves a layer, each row-major with a lane-dense
+    minor dimension (the 64-wide index key is zero-padded to a lane tile),
+    each donated in place in one layout, and nothing as large as a leaf
+    exists but the leaf passed along and the scatter of the call's rows into
+    it. In particular the decode program's gather of the CHOSEN rows reads the
+    K/V leaf through its ``(positions, width)`` view, which is the leaf
+    itself: seen as ``(positions, heads, head_dim)`` the compiler re-tiled
+    the whole 436 MB leaf, twice a layer (PERF.md section 6, PR 35)."""
+
+    NUM_BLOCKS = 1 + INDEXED_SLOTS * (INDEXED_CONTEXT // POOL_BLOCK_TOKENS)  # 26,625
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_three_leaves_a_layer_are_row_major_and_updated_in_place(self, indexed_programs, program):
+        texts, leaves = indexed_programs
+        n = self.NUM_BLOCKS
+        assert leaves == sorted([(n, 16, 128)] * INDEXED_LAYERS + [(n, 16, 512)] * 2 * INDEXED_LAYERS)
+        text = texts[program]
+        params, results, aliased = _entry_layout(text)
+        pool = [i for i, p in enumerate(params) if f"[{n}," in p]
+        assert len(pool) == 3 * INDEXED_LAYERS
+        for i in pool:
+            assert "{2,1,0:" in params[i], f"a pool leaf is not row-major: {params[i]}"
+            assert i in aliased and results[aliased[i]] == params[i], "not donated in place in one layout"
+        sizes = {n * 16 * 128, n * 16 * 512}
+        for op, result, called, line in _hlo_instructions(text):
+            if _elements(result) not in sizes:
+                continue
+            in_place = op in POOL_IN_PLACE or (
+                op == "fusion" and re.search(r" (scatter|dynamic-update-slice)\(", _computation(text, called))
+            )
+            assert in_place, f"pool-sized `{op}` in {program}: {line.strip()[:300]}"
+
+    def test_decode_gathers_the_chosen_rows_and_prefill_holds_no_score_matrix(self, indexed_programs):
+        texts, _ = indexed_programs
+        decode, prefill = texts["decode"], texts["prefill"]
+        # 64 rows x 2,048 chosen positions x 512 lanes, K and V of each layer: gathered, never the whole table
+        gathered = [r for op, r, _c, _l in _hlo_instructions(decode) if "bf16[64,2048,512]" in r or "bf16[131072,512]" in r]
+        assert gathered
+        assert "bf16[64,6656,512]" not in decode and "bf16[64,416,16,512]" not in decode
+        assert "ragged-dot" in decode  # the held experts: grouped products over the sorted pairs
+        # prefill: scores exist for a chunk of 512 queries and a block of 512 keys, index dots for a chunk,
+        # the indexer's 16 heads and the table (the largest thing the attention makes); nothing of it pairs
+        # all 6,144 queries with all 6,656 positions
+        scoped = [(result, line) for _op, result, _called, line in _hlo_instructions(prefill) if "/attn/" in line]
+        assert len(scoped) > 50  # the attention's instructions carry its scope
+        for result, line in scoped:
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+                sizes = [int(d) for d in dims.split(",")]
+                assert not (INDEXED_BUCKET in sizes and INDEXED_CONTEXT in sizes), line.strip()[:300]
+                assert math.prod(sizes) <= 512 * 16 * INDEXED_CONTEXT or self.NUM_BLOCKS in sizes, line.strip()[:300]
