@@ -13,4 +13,9 @@ over a named device mesh (data/fsdp/tensor/sequence axes) with XLA
 collectives over ICI/DCN — not a DDP wrapper.
 """
 
+import time as _time
+
+# Start-up's first stamp (telemetry/timeline.py). Nothing else belongs up here.
+_T_IMPORT = _time.perf_counter()
+
 __version__ = "0.1.0"

@@ -197,7 +197,15 @@ def configure_compilation_cache(config_dir: str | None = None) -> None:
     reference has no compiled artifacts to cache) so restarts, sweep
     candidates and podFailurePolicy-restarted k8s Jobs pay each compile
     once. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has already read
-    it and this sets NO directory in code. Safe to call multiple times."""
+    it and this sets NO directory in code. Safe to call multiple times.
+
+    Every entry point passes here before its first compile, so this is
+    also where start-up recording begins (``telemetry/timeline.py``:
+    ``startup/import`` closes, JAX's compile and cache events become
+    spans, the stall watch runs)."""
+    from ..telemetry.timeline import watch_startup
+
+    watch_startup()
     # Cache everything that took noticeable compile time; tiny programs
     # aren't worth the disk round-trip.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)

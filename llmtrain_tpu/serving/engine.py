@@ -24,7 +24,7 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   reads/writes the SAME donated cache buffers — join/evict never copies
   K/V.
 * **Every call says where its host time went.** With a ``span_factory``
-  (the scheduler's, wired like ``on_compile``) each call records
+  (the scheduler's) each call records
   ``serve/engine.stage`` (numpy columns, tables, ``jnp.asarray``),
   ``serve/engine.dispatch`` (the jitted call until it returns) and
   ``serve/engine.fetch`` (the blocking read of the result), with ``call``
@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..telemetry.timeline import process_span
 from ..utils.logging import get_logger
 from .paged_kv import PagedKVPool
 
@@ -298,6 +299,7 @@ class PagedDecodeEngine:
     decides admission.
     """
 
+    @process_span("startup/build", kind="engine")
     def __init__(
         self,
         model: Any,
@@ -401,9 +403,13 @@ class PagedDecodeEngine:
             prefix_cache=prefix_cache,
             state_rows=self.max_batch_slots if state_leaves else 0,
         )
-        self._cache = jax.tree.map(
-            lambda s: jnp.zeros(s.shape, s.dtype), self._cache_struct
-        )
+        with process_span("startup/pool", num_blocks=int(num_blocks)) as counted:
+            self._cache = jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype), self._cache_struct
+            )
+            counted["bytes"] = sum(
+                math.prod(s.shape) * s.dtype.itemsize for s in jax.tree.leaves(self._cache_struct)
+            )
         # Bumped whenever a failed step forces a cache rebuild: the
         # scheduler compares epochs to learn that in-flight KV was lost.
         self.cache_epoch = 0
@@ -434,11 +440,6 @@ class PagedDecodeEngine:
         self._decode_shapes: set[int] = set()
         self._verify_shapes: set[tuple[int, int]] = set()
         self._cow_used = False
-        # Optional ``(kind, bucket) -> None`` hook, fired the first time a
-        # bucket shape is seen (= an XLA compile is about to happen). The
-        # scheduler wires it to a timeline instant: a request whose
-        # prefill span brackets a compile instant explains its own tail.
-        self.on_compile: Any = None
         # Optional ``(name, **args) -> context manager`` (the scheduler's
         # timeline span): stage / dispatch / fetch of every call land as
         # children of the scheduler span that made the call.
@@ -449,15 +450,15 @@ class PagedDecodeEngine:
             return nullcontext()
         return self.span_factory(f"serve/engine.{phase}", call=call, **counted)
 
-    def _note_shape(self, shapes: set, key: Any, kind: str, bucket: int) -> None:
+    def _first_call(self, shapes: set, key: Any, kind: str, bucket: int):
+        """The call in which a shape is first seen runs under a
+        ``startup/first_call`` span: JAX's own trace / lower / compile /
+        cache-load events land inside it as spans (telemetry/timeline.py),
+        and what they leave of it is the program's first execution."""
         if key in shapes:
-            return
+            return nullcontext()
         shapes.add(key)
-        if self.on_compile is not None:
-            try:
-                self.on_compile(kind, bucket)
-            except Exception:  # noqa: BLE001 — telemetry must not fail a step
-                pass
+        return process_span("startup/first_call", kind=kind, bucket=bucket)
 
     # --------------------------------------------------------- validation
 
@@ -517,43 +518,43 @@ class PagedDecodeEngine:
         chunks — one program either way, the bounded-compile contract)."""
         tp = int(prompt_ids.shape[0])
         tb = bucket_for(tp, self.prompt_buckets)
-        self._note_shape(self._prefill_shapes, tb, "prefill", tb)
-        counted = {"prompt_tokens": tp, "bucket": tb}
-        if self.state_bytes_per_row:
-            # The row is read (unless the slab starts the sequence) and written.
-            counted["state_bytes"] = (2 if offset else 1) * self.state_bytes_per_row
-            if self._scan_chunk:
-                counted["scan_chunks"] = -(-tb // self._scan_chunk)
-        if self._selects:
-            seen = np.arange(int(offset), int(offset) + tp, dtype=np.int64) + 1  # positions a query may see
-            counted["index_pairs"] = int(seen.sum())
-            counted["selected_pairs"] = int(np.minimum(seen, self._selects).sum())
-        with self._span("stage", "prefill", **counted):
-            prompt = np.zeros((1, tb), np.int32)
-            prompt[0, :tp] = prompt_ids
-            staged = (
-                jnp.asarray(prompt),
-                jnp.asarray([tp], jnp.int32),
-                jnp.asarray([int(offset)], jnp.int32),
-                jnp.asarray([table_padded], jnp.int32),
-                jnp.asarray([seed & 0xFFFFFFFF], jnp.uint32),
-                jnp.asarray([temperature], jnp.float32),
-                jnp.asarray([0 if top_k is None else top_k], jnp.int32),
-                jnp.asarray([0.0 if top_p is None else top_p], jnp.float32),
-            )
+        with self._first_call(self._prefill_shapes, tb, "prefill", tb):
+            counted = {"prompt_tokens": tp, "bucket": tb}
             if self.state_bytes_per_row:
-                staged += (jnp.asarray([int(state_row)], jnp.int32),)
-        try:
-            with self._span("dispatch", "prefill"):
-                cache, tok = self._prefill_jit(
-                    self.params if params is None else params, self._cache, *staged
+                # The row is read (unless the slab starts the sequence) and written.
+                counted["state_bytes"] = (2 if offset else 1) * self.state_bytes_per_row
+                if self._scan_chunk:
+                    counted["scan_chunks"] = -(-tb // self._scan_chunk)
+            if self._selects:
+                seen = np.arange(int(offset), int(offset) + tp, dtype=np.int64) + 1  # positions a query may see
+                counted["index_pairs"] = int(seen.sum())
+                counted["selected_pairs"] = int(np.minimum(seen, self._selects).sum())
+            with self._span("stage", "prefill", **counted):
+                prompt = np.zeros((1, tb), np.int32)
+                prompt[0, :tp] = prompt_ids
+                staged = (
+                    jnp.asarray(prompt),
+                    jnp.asarray([tp], jnp.int32),
+                    jnp.asarray([int(offset)], jnp.int32),
+                    jnp.asarray([table_padded], jnp.int32),
+                    jnp.asarray([seed & 0xFFFFFFFF], jnp.uint32),
+                    jnp.asarray([temperature], jnp.float32),
+                    jnp.asarray([0 if top_k is None else top_k], jnp.int32),
+                    jnp.asarray([0.0 if top_p is None else top_p], jnp.float32),
                 )
-        except Exception:
-            self._recover_cache_after_error()
-            raise
-        self._cache = cache
-        with self._span("fetch", "prefill"):
-            return int(tok[0])
+                if self.state_bytes_per_row:
+                    staged += (jnp.asarray([int(state_row)], jnp.int32),)
+            try:
+                with self._span("dispatch", "prefill"):
+                    cache, tok = self._prefill_jit(
+                        self.params if params is None else params, self._cache, *staged
+                    )
+            except Exception:
+                self._recover_cache_after_error()
+                raise
+            self._cache = cache
+            with self._span("fetch", "prefill"):
+                return int(tok[0])
 
     def decode(
         self, rows: list[dict[str, Any]], *, params: Any | None = None
@@ -571,63 +572,63 @@ class PagedDecodeEngine:
         if n == 0:
             return []
         bb = bucket_for(n, self.batch_buckets)
-        self._note_shape(self._decode_shapes, bb, "decode", bb)
-        mb = self.max_blocks_per_seq
+        with self._first_call(self._decode_shapes, bb, "decode", bb):
+            mb = self.max_blocks_per_seq
 
-        def col(key: str, fill: Any, dtype: Any) -> np.ndarray:
-            out = np.full((bb,), fill, dtype=dtype)
-            for i, r in enumerate(rows):
-                out[i] = r[key]
-            return out
+            def col(key: str, fill: Any, dtype: Any) -> np.ndarray:
+                out = np.full((bb,), fill, dtype=dtype)
+                for i, r in enumerate(rows):
+                    out[i] = r[key]
+                return out
 
-        # Every padded row gathers its whole block table
-        # (``_paged_decode_attention``), whatever the real rows attend.
-        counted = {
-            "kv_live_tokens": sum(int(r["position"]) + 1 for r in rows),
-            "kv_gathered_tokens": bb * mb * self.block_tokens,
-        }
-        if self.state_bytes_per_row:
-            # What the call must move: each real row's state, read and written.
-            counted["state_rows"] = n
-            counted["state_bytes"] = 2 * n * self.state_bytes_per_row
-        if self._selects:
-            counted["kv_selected_tokens"] = sum(min(int(r["position"]) + 1, self._selects) for r in rows)
-            counted["rows_past_topk"] = sum(int(r["position"]) + 1 > self._selects for r in rows)
-        with self._span("stage", "decode", **counted):
-            tables = np.zeros((bb, mb), np.int32)
-            for i, r in enumerate(rows):
-                tables[i] = r["table"]
-            staged = (
-                jnp.asarray(col("token", 0, np.int32)),
-                jnp.asarray(col("position", 0, np.int32)),
-                jnp.asarray(tables),
-                jnp.asarray(
-                    np.array(
-                        [r["seed"] & 0xFFFFFFFF for r in rows] + [0] * (bb - n),
-                        dtype=np.uint32,
-                    )
-                ),
-                jnp.asarray(col("emit_idx", 0, np.int32)),
-                jnp.asarray(col("temperature", 0.0, np.float32)),
-                jnp.asarray(col("top_k", 0, np.int32)),
-                jnp.asarray(col("top_p", 0.0, np.float32)),
-            )
+            # Every padded row gathers its whole block table
+            # (``_paged_decode_attention``), whatever the real rows attend.
+            counted = {
+                "kv_live_tokens": sum(int(r["position"]) + 1 for r in rows),
+                "kv_gathered_tokens": bb * mb * self.block_tokens,
+            }
             if self.state_bytes_per_row:
-                staged += (jnp.asarray(col("state_row", 0, np.int32)),)
-        try:
-            with self._span("dispatch", "decode"):
-                cache, tok = self._decode_jit(
-                    self.params if params is None else params, self._cache, *staged
+                # What the call must move: each real row's state, read and written.
+                counted["state_rows"] = n
+                counted["state_bytes"] = 2 * n * self.state_bytes_per_row
+            if self._selects:
+                counted["kv_selected_tokens"] = sum(min(int(r["position"]) + 1, self._selects) for r in rows)
+                counted["rows_past_topk"] = sum(int(r["position"]) + 1 > self._selects for r in rows)
+            with self._span("stage", "decode", **counted):
+                tables = np.zeros((bb, mb), np.int32)
+                for i, r in enumerate(rows):
+                    tables[i] = r["table"]
+                staged = (
+                    jnp.asarray(col("token", 0, np.int32)),
+                    jnp.asarray(col("position", 0, np.int32)),
+                    jnp.asarray(tables),
+                    jnp.asarray(
+                        np.array(
+                            [r["seed"] & 0xFFFFFFFF for r in rows] + [0] * (bb - n),
+                            dtype=np.uint32,
+                        )
+                    ),
+                    jnp.asarray(col("emit_idx", 0, np.int32)),
+                    jnp.asarray(col("temperature", 0.0, np.float32)),
+                    jnp.asarray(col("top_k", 0, np.int32)),
+                    jnp.asarray(col("top_p", 0.0, np.float32)),
                 )
-        except Exception:
-            self._recover_cache_after_error()
-            raise
-        self._cache = cache
-        with self._span("fetch", "decode") as counted:
-            host = np.asarray(jax.device_get(tok))
-            if self._counts_experts and counted is not None:
-                counted.update(zip(EXPERT_COUNTERS, map(int, host[bb:])))
-            return [int(t) for t in host[:n]]
+                if self.state_bytes_per_row:
+                    staged += (jnp.asarray(col("state_row", 0, np.int32)),)
+            try:
+                with self._span("dispatch", "decode"):
+                    cache, tok = self._decode_jit(
+                        self.params if params is None else params, self._cache, *staged
+                    )
+            except Exception:
+                self._recover_cache_after_error()
+                raise
+            self._cache = cache
+            with self._span("fetch", "decode") as counted:
+                host = np.asarray(jax.device_get(tok))
+                if self._counts_experts and counted is not None:
+                    counted.update(zip(EXPERT_COUNTERS, map(int, host[bb:])))
+                return [int(t) for t in host[:n]]
 
     def verify(
         self,
@@ -655,34 +656,34 @@ class PagedDecodeEngine:
         if n == 0:
             return []
         bb = bucket_for(n, self.batch_buckets)
-        self._note_shape(self._verify_shapes, (bb, width), "verify", bb)
-        mb = self.max_blocks_per_seq
-        with self._span("stage", "verify"):
-            tokens = np.zeros((bb, width), np.int32)
-            positions = np.zeros((bb,), np.int32)
-            tables = np.zeros((bb, mb), np.int32)
-            for i, r in enumerate(rows):
-                if len(r["tokens"]) != width:
-                    raise ValueError(
-                        f"verify row {i} holds {len(r['tokens'])} tokens, "
-                        f"expected width {width}"
+        with self._first_call(self._verify_shapes, (bb, width), "verify", bb):
+            mb = self.max_blocks_per_seq
+            with self._span("stage", "verify"):
+                tokens = np.zeros((bb, width), np.int32)
+                positions = np.zeros((bb,), np.int32)
+                tables = np.zeros((bb, mb), np.int32)
+                for i, r in enumerate(rows):
+                    if len(r["tokens"]) != width:
+                        raise ValueError(
+                            f"verify row {i} holds {len(r['tokens'])} tokens, "
+                            f"expected width {width}"
+                        )
+                    tokens[i] = r["tokens"]
+                    positions[i] = r["position"]
+                    tables[i] = r["table"]
+                staged = (jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(tables))
+            try:
+                with self._span("dispatch", "verify"):
+                    cache, out = self._verify_jit(
+                        self.params if params is None else params, self._cache, *staged
                     )
-                tokens[i] = r["tokens"]
-                positions[i] = r["position"]
-                tables[i] = r["table"]
-            staged = (jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(tables))
-        try:
-            with self._span("dispatch", "verify"):
-                cache, out = self._verify_jit(
-                    self.params if params is None else params, self._cache, *staged
-                )
-        except Exception:
-            self._recover_cache_after_error()
-            raise
-        self._cache = cache
-        with self._span("fetch", "verify"):
-            host = np.asarray(jax.device_get(out))
-            return [[int(t) for t in host[i]] for i in range(n)]
+            except Exception:
+                self._recover_cache_after_error()
+                raise
+            self._cache = cache
+            with self._span("fetch", "verify"):
+                host = np.asarray(jax.device_get(out))
+                return [[int(t) for t in host[i]] for i in range(n)]
 
     def cow_copy(self, src: int, dst: int) -> None:
         """Device-side copy-on-write: pool block ``src`` → ``dst`` in every

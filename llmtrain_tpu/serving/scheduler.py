@@ -64,6 +64,7 @@ from typing import Any
 import jax
 import numpy as np
 
+from ..telemetry.timeline import process_span
 from ..telemetry.tracing import Tracer
 from ..utils.logging import get_logger
 from .engine import PagedDecodeEngine
@@ -247,7 +248,6 @@ class ContinuousBatchingScheduler:
             # Pool-level KV events (evictions, COW) land as timeline
             # instants: they explain latency the per-request spans can't.
             engine.pool.observer = self._kv_event
-            engine.on_compile = self._compile_event
             # The engine's stage / dispatch / fetch spans nest under the
             # scheduler span that made the call (docs/observability.md).
             engine.span_factory = self._span
@@ -432,15 +432,6 @@ class ContinuousBatchingScheduler:
         copies) become serving timeline instants."""
         if self.timeline is not None:
             self.timeline.instant(f"serve/kv_{name}", cat="serve", **args)
-
-    def _compile_event(self, kind: str, bucket: int) -> None:
-        """Engine first-bucket hook: the XLA compile about to happen lands
-        as an instant — a prefill span bracketing one explains its own
-        tail latency in ``llmtrain trace show``."""
-        if self.timeline is not None:
-            self.timeline.instant(
-                "serve/compile", cat="serve", kind=kind, bucket=bucket
-            )
 
     def _finish_trace(self, req: ServeRequest) -> None:
         """Resolve the request's distributed trace: add the decode-phase
@@ -1480,6 +1471,7 @@ class ContinuousBatchingScheduler:
             if not self.step():
                 time.sleep(poll_sec)
 
+    @process_span("startup/build", kind="scheduler")
     def start(self) -> "ContinuousBatchingScheduler":
         self._thread = threading.Thread(
             target=self.run_forever, name="serve-scheduler", daemon=True

@@ -45,6 +45,7 @@ from pathlib import Path
 from typing import Any, Iterable
 
 from ..utils.logging import get_logger
+from .timeline import startup_phase_seconds
 
 logger = get_logger()
 
@@ -67,6 +68,14 @@ CATEGORIES = (
 _DATA_SPANS = frozenset({"data_wait"})
 _CKPT_SPANS = frozenset({"checkpoint_save", "checkpoint_wait", "rollback_restore"})
 _EVAL_SPANS = frozenset({"eval"})
+# The measured parts of ``compile`` (telemetry/timeline.py's start-up spans),
+# listed beneath its total: phase of ``startup_phase_seconds`` -> row label.
+_COMPILE_PARTS = {
+    "trace_lower": "trace + lower",
+    "compile": "compile",
+    "cache_load": "cache load",
+    "first_call": "first call",
+}
 
 _MANIFEST_RE = re.compile(r"step_(\d+)\.manifest\.json$")
 
@@ -212,6 +221,7 @@ def compute_goodput(
 
     seg_rows: list[dict[str, Any]] = []
     totals = {c: 0.0 for c in CATEGORIES}
+    compile_parts: dict[str, float] | None = None
     exec_cursor = 0
     for idx, seg in enumerate(segments):
         cats = {c: 0.0 for c in CATEGORIES}
@@ -238,6 +248,7 @@ def compute_goodput(
         gap = 0.0
         if idx == 0:
             cats["compile"] = pre_step
+            compile_parts = _compile_parts(seg.events, pre_step)
         else:
             gap = max(0.0, seg.start - segments[idx - 1].end)
             suspended = _carve_suspensions(segments[idx - 1].end, seg.start, windows)
@@ -297,10 +308,29 @@ def compute_goodput(
             "suspension_windows": len(windows),
         },
     }
+    if compile_parts is not None:
+        ledger["compile_parts"] = compile_parts
     promotions = _promotions_block(run_dir)
     if promotions is not None:
         ledger["promotions"] = promotions
     return ledger
+
+
+def _compile_parts(events: list[dict[str, Any]], pre_step: float) -> dict[str, float] | None:
+    """What the ``compile`` window (segment start -> first step) was
+    measured to hold, where the timeline has ``startup/*`` spans: exclusive
+    seconds of trace + lower, compile, cache load and the first call's own
+    time. Parts of the category's total, never a category: the sum and the
+    invariant do not know them."""
+    spans = [
+        (e["name"], e.get("ts_us", 0) / 1e6, (e.get("ts_us", 0) + e.get("dur_us", 0)) / 1e6)
+        for e in events
+        if e.get("ph") == "X" and str(e.get("name", "")).startswith("startup/")
+    ]
+    if not spans:
+        return None
+    phases = startup_phase_seconds(spans, 0.0, pre_step)
+    return {label: round(phases[phase], 3) for phase, label in _COMPILE_PARTS.items()}
 
 
 def _promotions_block(run_dir: Path) -> dict[str, Any] | None:
@@ -347,6 +377,10 @@ def render_goodput_md(ledger: dict[str, Any]) -> str:
         sec = ledger["categories"].get(cat, 0.0)
         frac = (sec / wall) if wall > 0 else 0.0
         lines.append(f"| {cat} | {sec} | {frac:.4f} |")
+        if cat == "compile":
+            for label, part in (ledger.get("compile_parts") or {}).items():
+                frac = (part / wall) if wall > 0 else 0.0
+                lines.append(f"| - of which {label} | {part} | {frac:.4f} |")
     lines += [
         "",
         "| segment | dur_s | steps | productive | recomputed | "

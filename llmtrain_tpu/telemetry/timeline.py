@@ -33,17 +33,34 @@ dict append, far below the numpy work inside any span).
 
 from __future__ import annotations
 
+import functools
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 from contextlib import contextmanager, nullcontext
 from pathlib import Path
-from typing import Any, Iterator
+from typing import Any, Callable, Iterator
 
+from .. import _T_IMPORT
 from ..utils.logging import get_logger
 
 logger = get_logger()
+
+# Spans open on each thread, innermost last, as (name, timeline or None):
+# ONE stack for every timeline and for the process buffer below, so a span
+# recorded by either names the span that was open around it as ``parent``.
+_OPEN = threading.local()
+
+
+def _open_stack() -> list[tuple[str, Any]]:
+    try:
+        return _OPEN.stack
+    except AttributeError:
+        stack = _OPEN.stack = []
+        return stack
 
 
 def step_annotation(step: int, *, enabled: bool = True):
@@ -88,9 +105,6 @@ class EventTimeline:
         self._max_events = max(1000, int(max_events))
         self._xprof = xprof_annotations
         self._lock = threading.Lock()
-        # Names of the spans open on each thread, innermost last: a span
-        # opened inside another records it as its ``parent``.
-        self._open = threading.local()
         self._events: list[dict[str, Any]] = []
         self._flushed = 0  # events [0, _flushed) are already on disk
         self._dropped = 0
@@ -109,6 +123,10 @@ class EventTimeline:
         self._segment_ended = False
         if self._enabled and self._jsonl_path is not None:
             self._segment_id = self._write_segment_header()
+        if self._enabled:
+            # What the process recorded before any timeline existed
+            # (start-up spans, host stalls) becomes this timeline's.
+            _PROCESS.adopt(self)
 
     # ------------------------------------------------------------- recording
 
@@ -159,7 +177,11 @@ class EventTimeline:
     def end_segment(self) -> None:
         """Append the clean-exit footer (idempotent). Crashed segments
         never reach this; the goodput ledger then infers the end from the
-        newest event timestamp and the heartbeat mtime instead."""
+        newest event timestamp and the heartbeat mtime instead. Also hands
+        the process buffer back and stops its stall watch (memory-only
+        timelines too)."""
+        if self._enabled:
+            _PROCESS.release(self)
         if not self._enabled or self._jsonl_path is None or self._segment_ended:
             return
         self._segment_ended = True
@@ -183,6 +205,8 @@ class EventTimeline:
         return int((time.perf_counter() - self._t0) * 1e6)
 
     def _append(self, event: dict[str, Any]) -> None:
+        if _PROCESS.armed:
+            _PROCESS.settle()
         with self._lock:
             self._events.append(event)
             if len(self._events) > self._max_events:
@@ -210,13 +234,10 @@ class EventTimeline:
         if not self._enabled:
             yield args
             return
-        try:
-            stack = self._open.stack
-        except AttributeError:
-            stack = self._open.stack = []
+        stack = _open_stack()
         if stack:
-            args.setdefault("parent", stack[-1])
-        stack.append(name)
+            args.setdefault("parent", stack[-1][0])
+        stack.append((name, self))
         start = self._now_us()
         cm = _trace_annotation(name) if self._xprof else nullcontext()
         try:
@@ -253,21 +274,42 @@ class EventTimeline:
         already took — the hot loop's path: its interval accumulators and
         the timeline share ONE set of clock reads, so the span record and
         the `train/data_wait_ms` family can never drift apart."""
-        if not self._enabled:
-            return
+        if self._enabled:
+            self._record_event(name, t0, t1, cat, threading.current_thread().name, args, step)
+
+    def _record_event(
+        self, name: str, t0: float, t1: float, cat: str, thread: str,
+        args: dict[str, Any], step: int | None = None,
+    ) -> None:
         event: dict[str, Any] = {
             "name": name,
             "cat": cat,
             "ph": "X",
             "ts_us": int((t0 - self._t0) * 1e6),
             "dur_us": max(0, int((t1 - t0) * 1e6)),
-            "thread": threading.current_thread().name,
+            "thread": thread,
         }
         if step is not None:
             event["step"] = int(step)
         if args:
             event["args"] = args
         self._append(event)
+
+    @contextmanager
+    def opened(self, name: str) -> Iterator[None]:
+        """Declare ``name`` open on this thread WITHOUT recording it: the
+        hot loop records its spans from stamps afterwards (:meth:`record`),
+        and what is recorded meanwhile (a recompile inside a step) still
+        names it as ``parent``."""
+        if not self._enabled:
+            yield
+            return
+        stack = _open_stack()
+        stack.append((name, self))
+        try:
+            yield
+        finally:
+            stack.pop()
 
     def instant(
         self,
@@ -438,4 +480,461 @@ class EventTimeline:
             return None
 
 
-__all__ = ["EventTimeline", "step_annotation"]
+# ------------------------------------------------------- the process buffer
+#
+# Start-up begins before any EventTimeline exists (the serving CLI builds
+# its timeline after the engine; a library caller may build none). What is
+# recorded meanwhile waits here, on the timelines' own clock
+# (``perf_counter``), and the first timeline built adopts it. Everything
+# recorded through the buffer also STAYS in it, bounded, keeping the
+# earliest: :func:`process_spans` hands it to a reader that has no timeline.
+# docs/observability.md, "Start-up", has the span tree.
+
+_MAX_PROCESS_SPANS = 8192
+
+# Booked to the first phase of this order that covers an instant, so nested
+# spans (a cache load inside a compile inside a first call inside a build)
+# never count a second twice.
+STARTUP_PHASES = ("cache_load", "compile", "trace_lower", "first_call", "build", "import")
+_PHASE_OF = {
+    "startup/cache_load": "cache_load",
+    "startup/compile": "compile",
+    "startup/trace": "trace_lower",
+    "startup/lower": "trace_lower",
+    "startup/first_call": "first_call",
+    "startup/build": "build",
+    "startup/import": "import",
+}
+
+
+def exclusive_seconds(
+    intervals: list[tuple[str, float, float]],
+    order: tuple[str, ...],
+    lo: float = float("-inf"),
+    hi: float = float("inf"),
+) -> dict[str, float]:
+    """Wall seconds of ``[lo, hi]`` under ``(phase, t0, t1)`` intervals,
+    each instant booked ONCE: to the first phase of ``order`` covering it."""
+    edges = []
+    for phase, t0, t1 in intervals:
+        t0, t1 = max(t0, lo), min(t1, hi)
+        if t1 > t0:
+            edges += [(t0, 1, phase), (t1, -1, phase)]
+    edges.sort(key=lambda edge: edge[0])
+    covering = dict.fromkeys(order, 0)
+    out = dict.fromkeys(order, 0.0)
+    prev = lo
+    for t, delta, phase in edges:
+        top = next((p for p in order if covering[p]), None)
+        if top is not None:
+            out[top] += t - prev
+        covering[phase] += delta
+        prev = t
+    return out
+
+
+def startup_phase_seconds(
+    spans: list[tuple[str, float, float]], lo: float = float("-inf"), hi: float = float("inf")
+) -> dict[str, float]:
+    """:data:`STARTUP_PHASES` -> exclusive seconds, from ``(span name, t0,
+    t1)`` on any one clock; children of ``startup/build`` count as build."""
+    named = [(_PHASE_OF[n], a, b) for n, a, b in spans if n in _PHASE_OF]
+    return exclusive_seconds(named, STARTUP_PHASES, lo, hi)
+
+
+class _StallWatch(threading.Thread):
+    """Sleeps ``PERIOD_S`` over and over and records ``host/stall`` when it
+    wakes more than ``LATE_S`` late: the whole process stood still (a
+    sandbox's pause, a GIL held through a long C call, a swapped-out host).
+    A pause shorter than the period is seen only when it covers a wake-up.
+
+    It does NOT run while the process starts up (:meth:`_ProcessBuffer.settle`
+    starts it once start-up has been quiet). On the chip machine a Python
+    thread that wakes now and then beside the thread that loads the cached
+    programs made the runtime load them three times slower, most runs (5.7 s
+    -> 20 s; at 50 wake-ups a second, at 5, and at 1 through the loads
+    themselves: PERF.md section 6, PR 39), and tracing up to 30% slower at
+    50 a second. Five a second is what a served token or a train step can
+    be shown to afford."""
+
+    PERIOD_S = 0.200
+    LATE_S = 0.050
+
+    def __init__(self, clock: Callable[[], float] = time.perf_counter) -> None:
+        super().__init__(name="host-stall-watch", daemon=True)
+        self._clock = clock
+        self._halt = threading.Event()
+
+    def tick(self, due: float) -> float:
+        """One wake-up that was due at ``due``; returns the time it woke."""
+        now = self._clock()
+        if now - due > self.LATE_S:
+            record_process_span(
+                "host/stall", due, now, cat="host", thread=self.name,
+                late_ms=round((now - due) * 1e3, 3),
+            )
+        return now
+
+    def run(self) -> None:
+        _PROCESS.summarise()
+        woke = self._clock()
+        while not self._halt.wait(self.PERIOD_S):
+            woke = self.tick(woke + self.PERIOD_S)
+
+    def halt(self) -> None:
+        self._halt.set()
+
+
+class _ProcessBuffer:
+    QUIET_S = 5.0  # start-up is over when nothing of it was recorded for this long
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.on = True
+        self.watch: _StallWatch | None = None
+        self.reset()
+
+    def reset(self) -> None:
+        with self.lock:
+            self.spans: list[dict[str, Any]] = []
+            self.dropped = 0
+            self.counters: dict[str, list[float]] = {}
+            self.adopter: weakref.ref | None = None
+            self.open_spans = 0
+            # Start-up recorded and the stall watch not yet running: the hot
+            # paths look at this ONE flag and call settle() while it is up.
+            self.armed = False
+            self.last_startup_end: float | None = None
+            self.first_call_seen = False
+            self.summarised = False
+        watch, self.watch = self.watch, None
+        if watch is not None:
+            watch.halt()
+
+    # ----------------------------------------------------------- recording
+
+    def _sink(self, stack: list[tuple[str, Any]]) -> "EventTimeline | None":
+        """The timeline a span recorded on this thread belongs to: the one
+        whose span is open around it, else the one that adopted the buffer."""
+        for _, owner in reversed(stack):
+            if owner is not None:
+                return owner
+        return self.adopter() if self.adopter is not None else None
+
+    def record(
+        self, name: str, t0: float, t1: float, cat: str, thread: str | None, args: dict[str, Any]
+    ) -> None:
+        stack = _open_stack()
+        if stack:
+            args.setdefault("parent", stack[-1][0])
+        thread = thread or threading.current_thread().name
+        with self.lock:
+            sink = self._sink(stack)
+            entry = {"name": name, "cat": cat, "t0": t0, "t1": t1, "thread": thread,
+                     "args": args, "adopted": sink is not None}
+            if len(self.spans) < _MAX_PROCESS_SPANS:
+                self.spans.append(entry)
+            else:
+                self.dropped += 1
+            if cat == "startup":
+                self.last_startup_end = max(t1, self.last_startup_end or t1)
+                self.first_call_seen |= name == "startup/first_call"
+                self.armed = self.watch is None and name != "startup/summary"
+        if sink is not None and sink._enabled:
+            sink._record_event(name, t0, t1, cat, thread, dict(args))
+
+    def count(self, name: str) -> None:
+        with self.lock:
+            stamps = self.counters.setdefault(name, [])
+            if len(stamps) < _MAX_PROCESS_SPANS:
+                stamps.append(time.perf_counter())
+
+    # ------------------------------------------------------------ adoption
+
+    def adopt(self, timeline: "EventTimeline") -> None:
+        """``timeline`` takes every span no timeline holds yet and, until
+        its segment ends, every later one recorded outside another
+        timeline's spans. A second timeline beside a live adopter gets
+        nothing: a span is adopted once."""
+        with self.lock:
+            holder = self.adopter() if self.adopter is not None else None
+            if holder is not None or not self.on:
+                return
+            self.adopter = weakref.ref(timeline)
+            waiting = [e for e in self.spans if not e["adopted"]]
+            for entry in waiting:
+                entry["adopted"] = True
+        for e in waiting:
+            timeline._record_event(
+                e["name"], e["t0"], e["t1"], e["cat"], e["thread"], dict(e["args"])
+            )
+
+    def release(self, timeline: "EventTimeline") -> None:
+        if self.adopter is None or self.adopter() is not timeline:
+            return
+        self.summarise()  # a run too short to have settled still says where its start-up went
+        with self.lock:
+            self.adopter = None
+            self.armed = False
+            watch, self.watch = self.watch, None
+        if watch is not None:
+            watch.halt()
+
+    def settle(self) -> None:
+        """Called from the hot paths while ``armed``: once a first call has
+        been seen and nothing of start-up was recorded (or is open) for
+        ``QUIET_S``, start-up is over: the stall watch starts and logs the
+        start-up table."""
+        last = self.last_startup_end
+        if not self.first_call_seen or self.open_spans or last is None:
+            return
+        if time.perf_counter() - last < self.QUIET_S:
+            return
+        with self.lock:
+            if not self.armed or not self.on:
+                return
+            self.armed = False
+            self.watch = _StallWatch()
+        self.watch.start()
+
+    def summarise(self) -> None:
+        """Log the start-up table, ONCE a process buffer's life."""
+        with self.lock:
+            if self.summarised or not self.first_call_seen:
+                return
+            self.summarised = True
+        summary = startup_summary()
+        logger.info("start-up: %s", json.dumps(summary, sort_keys=True))
+        t = self.last_startup_end or time.perf_counter()
+        self.record("startup/summary", t, t, "startup", None, summary)
+
+
+_PROCESS = _ProcessBuffer()
+
+
+def record_process_span(
+    name: str, t0: float, t1: float, *, cat: str = "startup", thread: str | None = None, **args: Any
+) -> None:
+    """A span from ``perf_counter`` stamps, into the process buffer and the
+    timeline it belongs to (``parent`` = the span open on this thread)."""
+    if _PROCESS.on:
+        _PROCESS.record(name, t0, t1, cat, thread, args)
+
+
+@contextmanager
+def process_span(name: str, *, cat: str = "startup", **args: Any) -> Iterator[dict[str, Any]]:
+    """:meth:`EventTimeline.span` for code that may run before any timeline
+    exists; yields the span's ``args``. As a decorator it spans the whole
+    call (``@process_span("startup/build", kind="engine")``)."""
+    if not _PROCESS.on:
+        yield args
+        return
+    stack = _open_stack()
+    if stack:
+        args.setdefault("parent", stack[-1][0])
+    stack.append((name, None))
+    with _PROCESS.lock:
+        _PROCESS.open_spans += 1
+    t0 = time.perf_counter()
+    try:
+        yield args
+    finally:
+        t1 = time.perf_counter()
+        stack.pop()
+        with _PROCESS.lock:
+            _PROCESS.open_spans -= 1
+        _PROCESS.record(name, t0, t1, cat, None, args)
+
+
+def first_call_span(fn: Callable, **args: Any) -> Callable:
+    """``fn`` with its FIRST call under ``startup/first_call``: the call
+    that traces, lowers and compiles (or loads) a jitted program."""
+    pending = [True]
+
+    @functools.wraps(fn)
+    def call(*a: Any, **kw: Any) -> Any:
+        if not pending:
+            if _PROCESS.armed:  # a caller with no timeline's events: the step loop itself
+                _PROCESS.settle()
+            return fn(*a, **kw)
+        pending.clear()
+        with process_span("startup/first_call", **args):
+            return fn(*a, **kw)
+
+    return call
+
+
+def process_spans() -> dict[str, Any]:
+    """Everything recorded through the process buffer so far, adopted or
+    not, on ``perf_counter``: ``t_package`` (the stamp on the package's
+    first line), ``spans`` (``name``, ``cat``, ``t0``, ``t1``, ``thread``,
+    ``args``), ``counters`` (name -> the stamp of each count) and how many
+    spans the bound ``dropped``."""
+    with _PROCESS.lock:
+        keys = ("name", "cat", "t0", "t1", "thread", "args")
+        return {
+            "t_package": _T_IMPORT,
+            "spans": [{k: e[k] for k in keys} for e in _PROCESS.spans],
+            "counters": {k: list(v) for k, v in _PROCESS.counters.items()},
+            "dropped": _PROCESS.dropped,
+        }
+
+
+def startup_summary() -> dict[str, Any]:
+    """Where start-up went, from the package's first line to the end of
+    the last ``startup/*`` span: exclusive seconds a phase, what no span
+    names, the stalls inside, the compile cache's counts and sizes."""
+    buffered = process_spans()
+    spans = buffered["spans"]
+    startup = [(s["name"], s["t0"], s["t1"]) for s in spans if s["cat"] == "startup"]
+    end = max((t1 for _, _, t1 in startup), default=_T_IMPORT)
+    phases = startup_phase_seconds(startup, _T_IMPORT, end)
+    out: dict[str, Any] = {f"{p}_s": round(v, 3) for p, v in phases.items()}
+    out["span_s"] = round(end - _T_IMPORT, 3)
+    out["unnamed_s"] = round(end - _T_IMPORT - sum(phases.values()), 3)
+    stalls = [s for s in spans if s["name"] == "host/stall" and s["t1"] <= end]
+    out["stall_s"] = round(sum(s["args"].get("late_ms", 0.0) for s in stalls) / 1e3, 3)
+    out["first_calls"] = sum(1 for n, _, _ in startup if n == "startup/first_call")
+    for name, stamps in buffered["counters"].items():
+        out[name] = sum(1 for t in stamps if t <= end)
+    out.update(_cache_dir_sizes())
+    return out
+
+
+def _cache_dir_sizes() -> dict[str, int]:
+    """Bytes of the persistent compile cache: ``cache_dir_bytes``, every
+    entry of the directory, and, where JAX keeps the cache under a size
+    limit (it then stamps an ``-atime`` twin at each read),
+    ``cache_read_bytes``, the entries THIS process read. A cache_load span
+    cannot carry its entry's size: JAX's event has the time and no key."""
+    jax = sys.modules.get("jax")
+    path = getattr(jax.config, "jax_compilation_cache_dir", None) if jax is not None else None
+    if not path or "://" in str(path) or not os.path.isdir(path):
+        return {}
+    started_ns = time.time_ns() - int((time.perf_counter() - _T_IMPORT) * 1e9)
+    total, read, stamped = 0, 0, False
+    try:
+        for entry in os.scandir(path):
+            if not entry.name.endswith("-cache"):
+                continue
+            size = entry.stat().st_size
+            total += size
+            try:
+                with open(entry.path[: -len("-cache")] + "-atime", "rb") as fh:
+                    stamped = True
+                    if int.from_bytes(fh.read(8), "little") >= started_ns:
+                        read += size
+            except OSError:
+                pass
+    except OSError:
+        return {}
+    return {"cache_dir_bytes": total, **({"cache_read_bytes": read} if stamped else {})}
+
+
+# JAX's own monitoring events (jax 0.9: dispatch.py, pxla.py, compiler.py)
+# -> the span each becomes. A listener fires at an event's END with its
+# duration, on the thread that did the work.
+_JAX_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "startup/trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "startup/lower",
+    "/jax/core/compile/backend_compile_duration": "startup/compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "startup/cache_load",
+}
+_JAX_COUNTERS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_COMPILING = threading.local()  # what the compile now ending on this thread met in the cache
+
+
+def _on_jax_duration(event: str, duration: float, **kwargs: Any) -> None:
+    name = _JAX_DURATION_SPANS.get(event)
+    if name == "startup/trace":
+        # A jitted function called while another is traced is traced inside
+        # it (thousands of them in a model's step): the outermost trace
+        # covers them all and is the one recorded. Counted whether or not
+        # recording is on, as the starts are: the two must stay in step.
+        _COMPILING.depth = depth = max(0, getattr(_COMPILING, "depth", 1) - 1)
+        if depth:
+            return
+    if name is None or not _PROCESS.on:
+        return
+    t1 = time.perf_counter()
+    t0 = t1 - float(duration)
+    args: dict[str, Any] = {}
+    if kwargs.get("fun_name"):
+        args["fun"] = str(kwargs["fun_name"])
+    if name == "startup/cache_load":
+        _COMPILING.load_t0 = t0
+    elif name == "startup/compile":
+        # ``backend_compile_duration`` encloses the cache's read on a hit:
+        # the compile span then ends where the cache_load span began (what
+        # is left is the cache key's hashing), and nothing counts twice.
+        load_t0 = getattr(_COMPILING, "load_t0", None)
+        wrote = getattr(_COMPILING, "wrote", False)
+        _COMPILING.load_t0, _COMPILING.wrote = None, False
+        if load_t0 is not None and load_t0 >= t0:
+            args["cache"], t1 = "hit", load_t0
+        else:
+            args["cache"] = "miss" if wrote else "none"
+    _PROCESS.record(name, t0, t1, "startup", None, args)
+
+
+def _on_jax_scalar(event: str, value: float, **kwargs: Any) -> None:
+    # JAX records an event's START as a scalar of the same name.
+    if event == "/jax/core/compile/jaxpr_trace_duration":
+        _COMPILING.depth = getattr(_COMPILING, "depth", 0) + 1
+
+
+def _on_jax_event(event: str, **kwargs: Any) -> None:
+    name = _JAX_COUNTERS.get(event)
+    if name is None or not _PROCESS.on:
+        return
+    if name == "cache_misses":
+        _COMPILING.wrote = True
+    _PROCESS.count(name)
+
+
+_LISTENING = False
+
+
+def watch_startup() -> None:
+    """Idempotent; called where every entry point passes before its first
+    compile (``distributed.configure_compilation_cache``). The first call
+    closes ``startup/import`` (the package's first line -> here) and
+    registers the ``jax.monitoring`` listeners. The stall watch starts
+    later, when start-up is over (:meth:`_ProcessBuffer.settle`)."""
+    global _LISTENING
+    if not _PROCESS.on:
+        return
+    if not _LISTENING:
+        _LISTENING = True
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        jax.monitoring.register_scalar_listener(_on_jax_scalar)
+        jax.monitoring.register_event_listener(_on_jax_event)
+        record_process_span("startup/import", _T_IMPORT, time.perf_counter())
+
+
+def process_recording(on: bool) -> None:
+    """Switch the process buffer (and with it the listeners and the stall
+    watch) off or on, emptied: the test suite's, whose hundreds of
+    timelines would each adopt the spans of the tests before."""
+    _PROCESS.on = bool(on)
+    _PROCESS.reset()
+
+
+__all__ = [
+    "EventTimeline",
+    "exclusive_seconds",
+    "first_call_span",
+    "process_recording",
+    "process_span",
+    "process_spans",
+    "record_process_span",
+    "startup_phase_seconds",
+    "startup_summary",
+    "step_annotation",
+    "watch_startup",
+]

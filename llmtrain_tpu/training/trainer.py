@@ -67,6 +67,7 @@ from ..resilience.elastic import (
     resume_batch_index,
 )
 from ..telemetry import Telemetry
+from ..telemetry.timeline import first_call_span, process_span
 from ..tracking.base import Tracker
 from ..utils.hw import mfu as compute_mfu
 from ..utils.hw import peak_flops_per_chip, transformer_flops_per_token
@@ -106,6 +107,7 @@ class TrainResult:
 
 
 class Trainer:
+    @process_span("startup/build", kind="trainer")
     def __init__(
         self,
         cfg: RunConfig,
@@ -172,17 +174,18 @@ class Trainer:
         # pod; the per-rank seeded RNG keeps a multi-host fleet's retries
         # decorrelated so a shared-dependency hiccup doesn't turn into a
         # synchronized thundering herd.
-        retry(
-            self._faults.flaky(
-                "dataset_load", lambda: self._data_module.setup(cfg, tokenizer)
-            ),
-            attempts=cfg.resilience.retry_attempts,
-            base_delay=cfg.resilience.retry_base_delay,
-            description="dataset setup",
-            rng=retry_rng(
-                cfg.run.seed, dist_state.process_index if dist_state else 0
-            ),
-        )
+        with process_span("startup/data_setup", data=cfg.data.name):
+            retry(
+                self._faults.flaky(
+                    "dataset_load", lambda: self._data_module.setup(cfg, tokenizer)
+                ),
+                attempts=cfg.resilience.retry_attempts,
+                base_delay=cfg.resilience.retry_base_delay,
+                description="dataset setup",
+                rng=retry_rng(
+                    cfg.run.seed, dist_state.process_index if dist_state else 0
+                ),
+            )
 
         self._model = self._adapter.build_model(cfg)
 
@@ -255,7 +258,7 @@ class Trainer:
                 on_commit=self._on_checkpoint_commit,
             )
 
-        with self._mesh, nn.logical_axis_rules(self._rules):
+        with self._mesh, nn.logical_axis_rules(self._rules), process_span("startup/init_state"):
             self._state = self._init_state()
 
         # Metrics come out replicated (out_shardings) so every process can
@@ -301,6 +304,9 @@ class Trainer:
             self._train_step_fn = step_with_host_opt
         else:
             self._train_step_fn = step_fn
+        # The first call traces, lowers and compiles (or loads) the step:
+        # a ``startup/first_call`` span, JAX's own events inside it.
+        self._train_step_fn = first_call_span(self._train_step_fn, kind="train_step")
         # The raw jitted step (not the host-roundtrip wrapper): the cost
         # attribution hook lowers THIS to read XLA's cost_analysis —
         # lowering only traces, so the donation annotation never consumes
@@ -999,7 +1005,9 @@ class Trainer:
                             ),
                             batch,
                         )
-                    with self._telemetry.step_annotation(step):
+                    # ``host_dispatch`` is recorded from stamps below; declared
+                    # open here, a recompile inside the step names it as parent.
+                    with self._telemetry.step_annotation(step), tl.opened("host_dispatch"):
                         self._state, metrics = self._train_step_fn(
                             self._state, batch, run_key
                         )

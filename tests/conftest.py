@@ -64,6 +64,14 @@ from llmtrain_tpu.distributed import configure_compilation_cache  # noqa: E402
 
 configure_compilation_cache()
 
+# Start-up recording (telemetry/timeline.py's process buffer, its listeners
+# and stall watch) is off for the suite: every EventTimeline a test builds
+# would otherwise adopt the compile spans of the tests before it. The tests
+# of the buffer switch it on for themselves (tests/test_startup_spans.py).
+from llmtrain_tpu.telemetry.timeline import process_recording  # noqa: E402
+
+process_recording(False)
+
 import pytest  # noqa: E402
 
 
