@@ -32,10 +32,8 @@ from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-COMPILE_EVENTS = (
-    "/jax/core/compile/backend_compile_duration",
-    "/jax/compilation_cache/cache_retrieval_time_sec",
-)
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"  # recorded on a hit only
+COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration", CACHE_LOAD_EVENT)
 
 
 def load_module(kind: str, name: str):
@@ -79,17 +77,23 @@ def resolve_cell(workload: str) -> dict:
 
 
 class _CompileCounter:
-    """Counts JAX's backend compiles and persistent-cache loads."""
+    """Counts JAX's backend compiles and persistent-cache loads, and keeps
+    when each load ran (``perf_counter``): the seconds ``setup_s`` leaves out
+    are the harness's own reading, not the program's spans."""
 
     def __init__(self) -> None:
         import jax.monitoring
 
         self.count = 0
+        self.cache_loads: list[tuple[float, float]] = []
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
     def _on_event(self, event: str, duration: float, **_) -> None:
         if event in COMPILE_EVENTS:
             self.count += 1
+        if event == CACHE_LOAD_EVENT:  # fires at the load's end, on the thread that loaded
+            t1 = time.perf_counter()
+            self.cache_loads.append((t1 - float(duration), t1))
 
 
 class _Since:
@@ -123,6 +127,7 @@ class Context:
         self.config = config
         self.reference = load_module("reference", config["family"])
         self.window_start: float | None = None
+        self.stamps: dict[str, float] = {}  # seconds before the program's first line, for the log
         self._compiles = _CompileCounter()
         self.work_dir.mkdir(parents=True, exist_ok=True)
 
@@ -131,6 +136,9 @@ class Context:
 
     def compile_counter(self) -> _Since:
         return _Since(self._compiles)
+
+    def cache_loads(self) -> list[tuple[float, float]]:
+        return list(self._compiles.cache_loads)
 
     def mark_window_start(self, t: float) -> None:
         self.window_start = t
@@ -170,7 +178,9 @@ class Context:
 def require_devices(ctx: Context) -> dict:
     import jax
 
-    devices = jax.devices()
+    t0 = time.perf_counter()
+    devices = jax.devices()  # the TPU runtime starts here
+    ctx.stamps["jax_devices_s"] = time.perf_counter() - t0
     dev = devices[0]
     info = {"platform": dev.platform, "kind": dev.device_kind, "count": len(devices)}
     if ctx.rehearse:
@@ -245,7 +255,12 @@ def main(argv: list[str] | None = None) -> int:
 
     sys.path.insert(0, str(ROOT))
     resolved = resolve_cell(args.workload)
+    t0 = time.perf_counter()
+    import jax  # noqa: F401 — this process's first import of JAX, stamped for the log
+
+    import_jax_s = time.perf_counter() - t0
     ctx = Context(args, resolved)
+    ctx.stamps["import_jax_s"] = import_jax_s
     device = require_devices(ctx)
     import llmtrain_tpu  # noqa: F401 — alone with its own files this fails here, no result line
 
@@ -253,7 +268,6 @@ def main(argv: list[str] | None = None) -> int:
     result = runner.run(ctx)
     if ctx.window_start is None:
         raise RuntimeError("the runner never marked the start of its window")
-    setup_s = ctx.window_start - _T_PROCESS
 
     correct = True
     for check in result["checks"]:
@@ -267,9 +281,29 @@ def main(argv: list[str] | None = None) -> int:
         ctx.log(f"FAIL: {result['compiles_in_window']} compilation(s) inside the window")
         correct = False
 
+    from benchmarks.lib import startup
+
     values = dict(result["end_to_end"])
-    values["setup_s"] = setup_s
-    ctx.log(f"end to end: {values}; memory_stats: {getattr(ctx, 'memory_stats', None)}")
+    values["setup_wall_s"] = wall = ctx.window_start - _T_PROCESS
+    reduced = reduce_run_trace(ctx, result["records"]) if ctx.trace else None
+    run_view = {
+        "records": result["records"], "end_to_end": values, "trace": reduced,
+        "config": ctx.config, "reference": ctx.reference, "traffic": ctx.traffic, "device": device,
+        "chips": ctx.chips, "t_process": _T_PROCESS, "cache_loads": ctx.cache_loads(),
+    }
+    # ``setup_s``: the wall clock less the persistent cache's loads before the
+    # ramp (lib/startup.py). Where they are not read, nothing is subtracted.
+    loads_s = startup.read(run_view, "cache_load_s")
+    values["setup_s"] = wall if loads_s is None else wall - loads_s
+    loads = (
+        "not read (off the chip): nothing subtracted" if loads_s is None
+        else f"{loads_s:.6f} s (those before the ramp, of {len(run_view['cache_loads'])} in the process)"
+    )
+    stamps = ", ".join(f"{k} {v:.3f}" for k, v in ctx.stamps.items())
+    ctx.log(
+        f"end to end: {values}; set-up: wall {wall:.6f} s less cache loads {loads} = setup_s "
+        f"{values['setup_s']:.6f} s; before the program: {stamps}; memory_stats: {getattr(ctx, 'memory_stats', None)}"
+    )
     metrics: dict[str, dict] = {}
     device["memory_peak_bytes"] = int(result["memory_peak_bytes"])
     line: dict = {
@@ -280,12 +314,6 @@ def main(argv: list[str] | None = None) -> int:
         "device": device,
     }
     if ctx.trace:
-        reduced = reduce_run_trace(ctx, result["records"])
-        run_view = {
-            "records": result["records"], "end_to_end": values, "trace": reduced,
-            "config": ctx.config, "reference": ctx.reference, "traffic": ctx.traffic, "device": device,
-            "chips": ctx.chips,
-        }
         for metric in resolved["per_layer"]:
             value = load_module("metrics", metric["name"]).read(run_view)
             if value is not None:

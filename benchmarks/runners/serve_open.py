@@ -63,7 +63,8 @@ def drive(ctx, server, traffic: dict, seconds: float) -> dict:
         "waiting_for_first_token_at_end": waiting_at_end,
         "ttft_p50_ms": loadgen.percentile(ttft, 50), "ttft_p95_ms": loadgen.p95_with_missing(ttft, len(measured)),
         "ttft_p50_second_half_ms": loadgen.percentile(half, 50), "ttft_max_ms": max(ttft) if ttft else None,
-        "itl_p50_ms": loadgen.percentile(itl, 50), "itl_p95_ms": loadgen.percentile(itl, 95), "itl_gaps": len(itl),
+        "itl_p50_ms": loadgen.percentile(itl, 50), "itl_p95_ms": loadgen.percentile(itl, 95),
+        "itl_p99_ms": loadgen.percentile(itl, 99), "itl_gaps": len(itl),
         "lateness_p95_ms": loadgen.percentile(late, 95), "lateness_max_ms": max(late, default=None),
     }
     ctx.log(f"open loop: {stats}")
@@ -78,7 +79,12 @@ def run(ctx) -> dict:
     programs_before = server.programs()
     ctx.mark_window_start(time.perf_counter() + float(traffic.get("ramp_seconds", 0.0)))
     out = drive(ctx, server, traffic, ctx.seconds)
-    end_to_end = {"serve_itl_p95_ms": out["stats"]["itl_p95_ms"]}
+    # The 99th percentile, not the 95th: a gap that holds a prefill call is longer by the prompt
+    # bucket's call (85 -> 101 / 106 / 123 ms at gpt2-xl), so the gaps are four plateaus with steps
+    # between them, and a percentile that lies ON a step (the 95th did: 4.5-5.0% of the gaps hold a
+    # prefill call at 0.6 requests/s) reads 86 or 100 ms by chance (PERF.md, PR 40). The 99th lies
+    # inside the longest bucket's plateau and has some 28 of 2,800 gaps beyond it.
+    end_to_end = {"serve_itl_p99_ms": out["stats"]["itl_p99_ms"]}
     return common.finish(
         ctx, server, out["plans"], out["finished"], t_start_pc=out["t_start_pc"], window_s=ctx.seconds,
         end_to_end=end_to_end, trace_info=out["trace_info"], compiles=out["compiled"],

@@ -170,6 +170,18 @@ def test_latency_counts_from_due_time_and_missing_counts_as_late():
 # ------------------------------------------------------------ flops and peaks
 
 
+def test_the_chat_tail_is_the_99th_percentile_and_the_95th_is_read_per_layer():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert e2e["serve_itl_p99_ms"]["workloads"] == ["gpt2-xl.serve-chat"] and "serve_itl_p95_ms" not in e2e
+    # Plateaus of gaps as the chat cell has them (plain ticks, ticks with a prefill call of a short
+    # and of the longest bucket): 4.9% or 5.1% of them slow moves the 95th a whole step, not the 99th.
+    for slow, p95 in ((49, 85.0), (51, 101.0)):
+        gaps = [85.0] * (1000 - slow) + [101.0] * (slow - 20) + [123.0] * 20
+        assert loadgen.percentile(gaps, 95) == p95 and loadgen.percentile(gaps, 99) == 123.0
+    read = harness.load_module("metrics", "serve_itl_p95_ms.chat").read
+    assert read({"records": {"stats": {"itl_p95_ms": 86.3}}}) == 86.3 and read({"records": {}}) is None
+
+
 def test_flop_formula_and_param_counts():
     small = json.loads((ROOT / "benchmarks/configs/gpt2-small.json").read_text())
     assert family.total_params(small) == 124_439_808  # GPT-2 124M as published
@@ -241,6 +253,9 @@ def test_rehearsal_prints_contract_line_and_no_device_metric(cell):
         assert line["metrics"] == {} and line["rehearsal"] is True
         assert "busy_s" not in line["device"] and line["attempted"] > 0
         assert any("check " in ln and "against limit" in ln for ln in proc.stdout.splitlines())
+        if trace_flag == "0":  # the runner reports every end-to-end metric the cell lists, under its name
+            said = next(ln for ln in proc.stdout.splitlines() if "values are not device numbers" in ln)
+            assert all(f"'{m['name']}'" in said for m in harness.resolve_cell(cell)["end_to_end"]), said
 
 
 def test_off_the_chip_there_is_no_result_line():

@@ -1,6 +1,8 @@
-"""The fourteen ``start-up`` metrics (PR 39): each reader against arithmetic
-done by hand on a hand-made ``records["startup"]``, a parent-shaped run and
-a run off the chip reading ``None``, and the entries of BENCHMARK.json.
+"""The ``start-up`` metrics (fourteen of PR 39, ``setup_wall_s`` of PR 40): each
+reader against arithmetic done by hand on a hand-made ``records["startup"]``
+and the harness's own cache loads, a parent-shaped run, a run off the chip
+reading ``None``, the entries of BENCHMARK.json, and ``setup_s`` as the result
+line has it since PR 40: the wall clock less the cache loads before the ramp.
 """
 
 from __future__ import annotations
@@ -23,7 +25,7 @@ CELLS = [c["name"] for c in BENCH["workloads"]]
 SIX = ["gpt2-small.train-64k", "gpt2-xl.serve-chat", "gpt2-small.serve-batch", "falcon-h1-34b.serve-batch",
        "ax-k1.serve-reason", "keye-vl2-30b-a3b.serve-video"]
 CLOSED = [c for c in SIX if c not in ("gpt2-small.train-64k", "gpt2-xl.serve-chat")]
-# name -> (unit, moves, workloads)
+# name -> (unit, moves, workloads); the source is ``program_span`` but for the HARNESS_OWN
 ENTRIES = {
     "setup_before_program_s": ("s", "setup_s", SIX),
     "setup_import_s": ("s", "setup_s", SIX),
@@ -37,9 +39,12 @@ ENTRIES = {
     "setup_stall_s": ("s", "setup_s", SIX),
     "setup_unnamed_s": ("s", "setup_s", SIX),
     "host_stall_share.closed": ("%", "serve_tokens_per_s", CLOSED),
-    "host_stall_share.open": ("%", "serve_itl_p95_ms", ["gpt2-xl.serve-chat"]),
+    "host_stall_share.open": ("%", "serve_itl_p99_ms", ["gpt2-xl.serve-chat"]),
     "host_stall_share.train": ("%", "train_tokens_per_s", ["gpt2-small.train-64k"]),
+    "setup_wall_s": ("s", "setup_s", SIX),
 }
+# What the harness reads with its own clock and listener: there without the program's buffer too.
+HARNESS_OWN = ("setup_cache_load_s", "setup_wall_s")
 
 T_PROCESS = 100.0  # the harness's stamp; set-up of 70 s ends at 170; a ramp of 25 s begins at 145
 
@@ -92,27 +97,25 @@ WANT = {
     "host_stall_share.closed": 100.0 * 1.45 / 45.0,  # 1 s of the straddling stall and 0.45 s, of a 45 s window
     "host_stall_share.open": 100.0 * 1.45 / 45.0,
     "host_stall_share.train": 100.0 * 1.45 / 45.0,
+    "setup_wall_s": 70.0,
 }
+# The cache loads as the harness's listener stamps them: (t0, t1) on ``perf_counter``.
+LOADS = [(121.5, 127.5), (150.0, 151.0)]
 
 
-def _run(startup_records, *, setup_s=70.0, ramp=25, platform="tpu", window=(170.0, 215.0)):
+def _run(startup_records, *, wall=70.0, ramp=25, platform="tpu", window=(170.0, 215.0), loads=LOADS):
     records = {"window": window, "spans": []}
     if startup_records is not None:
         records["startup"] = startup_records
     traffic = {"runner": "serve_closed"} | ({"ramp_seconds": ramp} if ramp is not None else {})
-    return {"records": records, "end_to_end": {"setup_s": setup_s, "serve_tokens_per_s": 650.0},
-            "traffic": traffic, "device": {"platform": platform}, "trace": None, "config": {}, "chips": 1}
+    return {"records": records, "end_to_end": {"setup_wall_s": wall, "serve_tokens_per_s": 650.0},
+            "traffic": traffic, "device": {"platform": platform}, "trace": None, "config": {}, "chips": 1,
+            "t_process": T_PROCESS, "cache_loads": list(loads)}
 
 
 def _records(spans=SPANS):
     return {"t_package": 113.0, "spans": spans, "counters": {"cache_misses": [139.0, 181.0], "cache_hits": [127.5]},
             "dropped": 0}
-
-
-@pytest.fixture(autouse=True)
-def _process_stamp(monkeypatch):
-    """What ``setup_s`` subtracts: ``benchmarks/run.py`` is ``__main__`` in a real run."""
-    monkeypatch.setattr(sys.modules["__main__"], "_T_PROCESS", T_PROCESS, raising=False)
 
 
 def _read(metric: str, run: dict):
@@ -127,7 +130,8 @@ def test_entry_has_its_file_its_layer_and_an_explicit_list_of_cells(name):
     by_name = {m["name"]: m for m in BENCH["per_layer"]}
     unit, moves, workloads = ENTRIES[name]
     entry = by_name[name]
-    assert entry == {"name": name, "unit": unit, "better": "lower", "source": "program_span",
+    source = "host_clock" if name in HARNESS_OWN else "program_span"
+    assert entry == {"name": name, "unit": unit, "better": "lower", "source": source,
                      "layer": "start-up", "moves": moves, "workloads": workloads}
     assert (ROOT / "benchmarks" / "metrics" / f"{name}.py").is_file()
     end_to_end = {m["name"]: m for m in BENCH["end_to_end"]}
@@ -137,7 +141,7 @@ def test_entry_has_its_file_its_layer_and_an_explicit_list_of_cells(name):
 def test_the_fourteen_are_appended_together_and_nothing_else_names_the_layer():
     names = [m["name"] for m in BENCH["per_layer"]]
     first = names.index("setup_before_program_s")
-    assert names[first:first + 14] == list(ENTRIES)
+    assert names[first:first + 15] == list(ENTRIES)  # PR 40's ``setup_wall_s`` is the fifteenth
     assert [m["name"] for m in BENCH["per_layer"] if m["layer"] == "start-up"] == list(ENTRIES)
 
 
@@ -153,6 +157,14 @@ def test_the_parts_the_ramp_and_the_unnamed_make_up_setup_s():
     run = _run(_records())
     parts = [startup.read(run, f"{part}_s") for part in startup.PARTS] + [startup.read(run, "unnamed_s")]
     assert sum(parts) == pytest.approx(70.0) and startup.read(run, "ramp_s") == 25.0
+    assert sum(parts) == pytest.approx(_read("setup_wall_s", run), abs=1e-9)  # the wall, loads and all
+
+
+def test_the_loads_booked_are_the_harness_own_and_not_the_programs_spans():
+    inflated = SPANS + [_span("startup/cache_load", 140.0, 144.0)]  # a program that calls more of its time a load
+    run = _run(_records(inflated))
+    assert _read("setup_cache_load_s", run) == pytest.approx(6.0) and _read("setup_unnamed_s", run) == pytest.approx(6.25)
+    assert _read("setup_cache_load_s", _run(_records(), loads=[])) == 0.0  # nothing loaded: 0, and the spans do not count
 
 
 def test_a_cell_without_a_ramp_books_everything_before_the_window():
@@ -188,7 +200,10 @@ def test_a_parent_shaped_run_reads_none_and_does_not_raise(name, monkeypatch):
     from llmtrain_tpu.telemetry import timeline
 
     monkeypatch.delattr(timeline, "process_spans")  # the program as it was before PR 39
-    assert _read(name, _run(None)) is None
+    if name in HARNESS_OWN:  # the harness's own clock and listener need no buffer
+        assert _read(name, _run(None)) == pytest.approx(WANT[name])
+    else:
+        assert _read(name, _run(None)) is None
 
 
 @pytest.mark.parametrize("name", list(ENTRIES))
@@ -202,5 +217,93 @@ def test_on_the_chip_the_reader_takes_the_programs_own_buffer(monkeypatch):
     monkeypatch.setattr(timeline, "process_spans", _records)
     assert _read("setup_first_call_s", _run(None)) == pytest.approx(5.0)
     run = _run(None)
-    del run["end_to_end"]["setup_s"]  # nothing to cut into parts
+    del run["end_to_end"]["setup_wall_s"]  # nothing to cut into parts
     assert _read("setup_first_call_s", run) is None
+
+
+# ------------------------------- ``setup_s`` on the result line (PR 40)
+#
+# ``harness.main`` itself, with a runner that does nothing but replay the hand-made set-up above on
+# the harness's clock: each cache load is JAX's own event, so the harness's listener stamps it.
+
+CELL = "keye-vl2-30b-a3b.serve-video"  # a ramp of 25 s, as the hand-made set-up has it
+TPU = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+# case -> (the loads as (end, seconds), the program's buffer or none, what the harness subtracts)
+SETUP_S_CASES = {
+    "a load nested in a compile in a first call is subtracted once": ([(127.5, 6.0)], True, 6.0),
+    "two loads at once on two threads count once": ([(127.5, 6.0), (126.0, 3.0)], True, 6.0),
+    "a load inside the ramp is the ramp's": ([(127.5, 6.0), (151.0, 1.0)], True, 6.0),
+    "a load that straddles the ramp's start is cut there": ([(127.5, 6.0), (147.0, 3.0)], True, 7.0),
+    "no load: the wall": ([], True, 0.0),
+    "a program without the buffer is held to the same clock": ([(127.5, 6.0), (151.0, 1.0)], False, 6.0),
+}
+
+
+class _Clock:
+    """``time`` as ``benchmarks/run.py`` sees it: the replaying runner sets ``now``."""
+
+    def __init__(self, now: float) -> None:
+        self.now = now
+
+    def perf_counter(self) -> float:
+        return self.now
+
+
+def _main_with_replayed_setup(monkeypatch, capsys, loads, *, buffer=True, argv=()):
+    import jax.monitoring
+
+    from llmtrain_tpu.telemetry import timeline
+
+    clock = _Clock(T_PROCESS + 5.0)
+    monkeypatch.setattr(harness, "time", clock)
+    monkeypatch.setattr(harness, "_T_PROCESS", T_PROCESS)
+    if not buffer:
+        monkeypatch.delattr(timeline, "process_spans")
+
+    class Runner:
+        @staticmethod
+        def run(ctx):
+            for t1, seconds in loads:
+                clock.now = t1
+                jax.monitoring.record_event_duration_secs(harness.CACHE_LOAD_EVENT, seconds)
+            clock.now = 145.0  # warm: the ramp begins, the window opens 25 s on
+            ctx.mark_window_start(clock.now + float(ctx.traffic["ramp_seconds"]))
+            records = {"window": (170.0, 215.0), "spans": []} | ({"startup": _records()} if buffer else {})
+            return {"checks": [], "attempted": 3, "failed": 0, "memory_peak_bytes": 1 << 30, "records": records,
+                    "end_to_end": {"serve_tokens_per_s": 650.0}}
+
+    real = harness.load_module
+    monkeypatch.setattr(harness, "load_module", lambda kind, name: Runner if kind == "runners" else real(kind, name))
+    assert harness.main(["--workload", CELL, "--seed", "2147483999", "--seconds", "45", *argv]) == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    return json.loads(out[-1]), next(ln for ln in out if "end to end:" in ln)
+
+
+@pytest.mark.parametrize("trace", [0, 1])
+@pytest.mark.parametrize("case", list(SETUP_S_CASES))
+def test_setup_s_on_the_result_line_is_the_wall_less_the_loads_before_the_ramp(case, trace, monkeypatch, capsys):
+    loads, buffer, subtracted = SETUP_S_CASES[case]
+    monkeypatch.setattr(harness, "require_devices", lambda ctx: dict(TPU))
+    line, log = _main_with_replayed_setup(monkeypatch, capsys, loads, buffer=buffer, argv=["--trace", str(trace)])
+    assert f"wall 70.000000 s less cache loads {subtracted:.6f} s" in log and f"setup_s {70.0 - subtracted:.6f} s" in log
+    got = {k: v["value"] for k, v in line["metrics"].items()}
+    if not trace:
+        assert got == {"serve_tokens_per_s": 650.0, "setup_s": pytest.approx(70.0 - subtracted, abs=1e-9)}
+        return
+    assert "setup_s" not in got and got["setup_wall_s"] == 70.0
+    assert got["setup_cache_load_s"] == pytest.approx(subtracted, abs=1e-9)
+    parts = [f"setup_{part}_s" for part in startup.PARTS if part != "ramp"] + ["setup_unnamed_s"]
+    if buffer:  # the parts, the ramp and the unnamed make up the wall; ``setup_s`` is that less the loads
+        assert sum(got[p] for p in parts) + 25.0 == pytest.approx(got["setup_wall_s"], abs=1e-9)
+        if loads:  # neither the compile nor the first call around it holds the load
+            assert (got["setup_compile_s"], got["setup_first_call_s"]) == pytest.approx((8.5, 5.0))
+    else:
+        assert [p for p in parts if p in got] == ["setup_cache_load_s"]
+
+
+def test_a_cpu_rehearsal_reports_the_wall_and_says_that_nothing_was_subtracted(monkeypatch, capsys):
+    line, log = _main_with_replayed_setup(monkeypatch, capsys, [(127.5, 6.0)], argv=["--rehearse-cpu"])
+    assert "less cache loads not read (off the chip): nothing subtracted = setup_s 70.000000 s" in log
+    assert "'setup_s': 70.0" in log and "'setup_wall_s': 70.0" in log
+    assert "import_jax_s" in log and "jax_devices_s" in log  # what lies before the program's first line
+    assert line["metrics"] == {} and line["rehearsal"] is True
