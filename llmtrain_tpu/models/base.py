@@ -58,9 +58,13 @@ class ModelAdapter(ABC):
         return 1
 
     @staticmethod
-    def _positive_extra(cfg: RunConfig, key: str, default: int) -> int:
-        """Validated ``model.extra`` integer knob (>= 1), shared by adapters."""
-        value = int(cfg.model.extra.get(key, default))
+    def _positive_extra(cfg: RunConfig, key: str, default: int | None) -> int | None:
+        """Validated ``model.extra`` integer knob (>= 1), shared by adapters;
+        a ``None`` default leaves an unset key unset."""
+        value = cfg.model.extra.get(key, default)
+        if value is None:
+            return None
+        value = int(value)
         if value < 1:
             raise ValueError(f"model.extra.{key} must be >= 1, got {value}")
         return value

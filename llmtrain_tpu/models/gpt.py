@@ -1025,13 +1025,13 @@ class GPT(nn.Module):
     # "dense" materializes logits; "chunked_ce" streams the CE over vocab
     # chunks of ce_chunk (ops/chunked_ce.py) — the forward then returns
     # hidden states via return_hidden and never builds [B,T,V];
-    # "fused_ce" computes the loss in a Pallas kernel (ops/fused_ce.py)
-    # tiled (fused_ce_block_t x fused_ce_block_v) so no logits tile ever
-    # reaches HBM.
+    # "fused_ce" computes the loss in Pallas kernels (ops/fused_ce.py) so
+    # no logits tile ever reaches HBM; its tiles are chosen from the call's
+    # shapes unless fused_ce_block_t / fused_ce_block_v override them.
     loss_impl: str = "dense"
     ce_chunk: int = 8192
-    fused_ce_block_t: int = 256
-    fused_ce_block_v: int = 512
+    fused_ce_block_t: int | None = None
+    fused_ce_block_v: int | None = None
     # Pallas fused residual-add + LayerNorm in every block
     # (ops/fused_norm.py); cleared on decode clones — the kernels are
     # trained-shape tuned and decode runs T=1 slices.
@@ -1341,8 +1341,8 @@ class GPTAdapter(ModelAdapter):
             interpret=pallas_interpret,
         )
         ce_chunk = self._positive_extra(cfg, "ce_chunk", 8192)
-        fused_ce_block_t = self._positive_extra(cfg, "fused_ce_block_t", 256)
-        fused_ce_block_v = self._positive_extra(cfg, "fused_ce_block_v", 512)
+        fused_ce_block_t = self._positive_extra(cfg, "fused_ce_block_t", None)
+        fused_ce_block_v = self._positive_extra(cfg, "fused_ce_block_v", None)
         z_loss = float(cfg.model.extra.get("z_loss", 0.0))
         if z_loss < 0.0:
             raise ValueError(f"model.extra.z_loss must be >= 0, got {z_loss}")
@@ -1502,8 +1502,8 @@ class GPTAdapter(ModelAdapter):
                 cls.vocab_matrix(model, params),
                 labels,
                 attention_mask,
-                block_t=getattr(model, "fused_ce_block_t", 256),
-                block_v=getattr(model, "fused_ce_block_v", 512),
+                block_t=getattr(model, "fused_ce_block_t", None),
+                block_v=getattr(model, "fused_ce_block_v", None),
                 z_loss=getattr(model, "z_loss", 0.0),
                 interpret=bool(getattr(model, "pallas_interpret", False)),
             )

@@ -291,8 +291,8 @@ class Llama(nn.Module):
     ce_chunk: int = 8192
     # Fused lm-head + CE Pallas kernel knobs (models/gpt.py GPT fields;
     # the loss machinery is shared via GPTAdapter).
-    fused_ce_block_t: int = 256
-    fused_ce_block_v: int = 512
+    fused_ce_block_t: int | None = None
+    fused_ce_block_v: int | None = None
     pallas_interpret: bool = False
     z_loss: float = 0.0
     n_kv_heads: int = 0

@@ -46,6 +46,9 @@ BT, BV = 16, 64
 # python-loop overhead per grid step, and the padding paths are already
 # covered by the kernel tests above at (BT, BV).
 WBT, WBV = 64, 128
+# What ``_choose_tiles`` gives gpt2-small.train-64k (PERF.md section 6, PR 41).
+CELL_FWD_TILES = (4096, 256)
+CELL_BWD_TILES = (2048, 512)
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -109,33 +112,62 @@ def _rand_problem(b=2, t=13, d=32, v=117, seed=0):
 # --------------------------------------------------------------------------
 
 
+# Kernel-level grids, tiles passed by hand: (b, t, v, block_t, block_v).
+# Every N and V is ragged against its tile. "3x3" and "3x5" visit each
+# HBM-accumulated dW block three times (once a token block); "one-token-block"
+# and "one-vocab-block" are the two degenerate axes of the backward's grid
+# (the second is the revisited-accumulator path of ``_bwd_kernel``);
+# "chosen" leaves both tiles to ``_choose_tiles``.
+GRIDS = {
+    "2x2": (2, 13, 117, BT, BV),
+    "3x3": (2, 21, 150, 16, 64),
+    "3x5": (3, 15, 290, 16, 64),
+    "one-token-block": (2, 13, 117, 64, 32),
+    "one-vocab-block": (2, 13, 117, 8, 128),
+    "chosen": (2, 13, 117, None, None),
+}
+
+
 class TestFusedCEKernel:
+    @pytest.mark.parametrize("grid", GRIDS)
     @pytest.mark.parametrize("z_loss", [0.0, 1e-3])
-    def test_forward_matches_dense_and_chunked(self, z_loss):
-        h, w, labels = _rand_problem()
-        fused = fused_ce_per_token(h, w, labels, BT, BV, None, z_loss, True)
+    def test_forward_matches_dense_and_chunked(self, z_loss, grid):
+        b, t, v, bt, bv = GRIDS[grid]
+        h, w, labels = _rand_problem(b, t, v=v)
+        fused = fused_ce_per_token(h, w, labels, bt, bv, None, z_loss, True)
         dense = _dense_ce_ref(h, w, labels, z_loss)
         chunked = chunked_ce_per_token(h, w, labels, BV, None, z_loss)
         np.testing.assert_allclose(fused, dense, atol=1e-5, rtol=1e-5)
         np.testing.assert_allclose(fused, chunked, atol=1e-5, rtol=1e-5)
 
+    @pytest.mark.parametrize("grid", GRIDS)
     @pytest.mark.parametrize("z_loss", [0.0, 1e-3])
-    def test_grads_match_chunked_vjp(self, z_loss):
-        h, w, labels = _rand_problem(seed=1)
+    def test_grads_match_chunked_vjp(self, z_loss, grid):
+        b, t, v, bt, bv = GRIDS[grid]
+        h, w, labels = _rand_problem(b, t, v=v, seed=1)
         # Non-uniform cotangent: a mean-loss-only check would hide
-        # per-token cotangent bugs (every g identical).
+        # per-token cotangent bugs (every g identical). The last three
+        # positions of every row are masked: a zero cotangent.
         g = jax.random.normal(jax.random.PRNGKey(9), labels.shape)
+        g = g * (jnp.arange(t) < t - 3)
 
         def fused_loss(h, w):
-            return jnp.sum(fused_ce_per_token(h, w, labels, BT, BV, None, z_loss, True) * g)
+            return jnp.sum(fused_ce_per_token(h, w, labels, bt, bv, None, z_loss, True) * g)
 
         def chunked_loss(h, w):
             return jnp.sum(chunked_ce_per_token(h, w, labels, BV, None, z_loss) * g)
 
+        def dense_loss(h, w):
+            return jnp.sum(_dense_ce_ref(h, w, labels, z_loss) * g)
+
         dh_f, dw_f = jax.grad(fused_loss, argnums=(0, 1))(h, w)
         dh_c, dw_c = jax.grad(chunked_loss, argnums=(0, 1))(h, w)
+        dh_d, dw_d = jax.grad(dense_loss, argnums=(0, 1))(h, w)
         np.testing.assert_allclose(dh_f, dh_c, atol=1e-5, rtol=1e-4)
         np.testing.assert_allclose(dw_f, dw_c, atol=1e-5, rtol=1e-4)
+        np.testing.assert_allclose(dh_f, dh_d, atol=1e-5, rtol=1e-4)
+        np.testing.assert_allclose(dw_f, dw_d, atol=1e-5, rtol=1e-4)
+        assert bool(jnp.all(dh_f[:, t - 3:] == 0.0)), "masked rows leaked gradient"
 
     def test_block_sizes_larger_than_problem(self):
         # One grid cell total: blocks exceeding N and V must still pad
@@ -176,6 +208,108 @@ class TestFusedCEKernel:
         dh = jax.grad(loss)(h)
         assert bool(jnp.all(dh[:, 7:] == 0.0)), "padded tokens leaked gradient"
         assert bool(jnp.any(dh[:, :7] != 0.0))
+
+
+# --------------------------------------------------------------------------
+# the tile chooser, and the contract with the benchmark's readers
+# --------------------------------------------------------------------------
+
+
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+class TestTileChooser:
+    @pytest.mark.parametrize("kind", ["fwd", "bwd"])
+    @pytest.mark.parametrize("vocab", [1000, 50257, 128256])
+    @pytest.mark.parametrize("width", [2, 4])
+    @pytest.mark.parametrize("d", [768, 1600, 4096, 8192])
+    def test_tiles_are_legal_and_fit_the_vmem_limit(self, d, width, vocab, kind):
+        n = 8192
+        block_t, block_v = fused_ce_mod._choose_tiles(kind, n, vocab, d, width)
+        # Tokens lie on the lanes of the logits tile and of the per-token
+        # rows, and on the sublanes of the hidden block; the vocabulary on
+        # the sublanes of the weight and dW blocks and, as the contraction
+        # of dh's product, on lanes. 128 is a multiple of every dtype's
+        # sublane count (8 x 4 / width).
+        assert block_t % 128 == 0 and block_v % 128 == 0
+        limit = fused_ce_mod._compiler_params("arbitrary", "arbitrary").vmem_limit_bytes
+        assert limit == fused_ce_mod._VMEM_LIMIT_BYTES
+        assert fused_ce_mod._vmem_bytes(kind, block_t, block_v, d, width) <= limit
+        # dW's read-modify-write never revisits a block 2 or 3 steps later.
+        n_vb = -(-vocab // block_v)
+        assert kind == "fwd" or n_vb == 1 or n_vb >= fused_ce_mod._MIN_RMW_BLOCKS
+        # The token tile pads N by at most 1/32.
+        assert (-(-n // block_t) * block_t - n) * 32 <= n
+
+    def test_the_train_cell_gets_the_tiles_measured_fastest(self):
+        # gpt2-small.train-64k: 32 x 1,024 tokens, d 768, V 50,257, bf16
+        # (PERF.md section 6, PR 41: the sweep on the chip).
+        assert fused_ce_mod._choose_tiles("fwd", 32768, 50257, 768, 2) == CELL_FWD_TILES
+        assert fused_ce_mod._choose_tiles("bwd", 32768, 50257, 768, 2) == CELL_BWD_TILES
+
+    @pytest.mark.parametrize(
+        "n, vocab, want_t, want_vb",
+        [(26, 117, 128, 1), (1000, 300, 1024, 1), (10240, 600, 2048, 5), (4096 + 128, 2000, 256, 4)],
+    )
+    def test_small_and_ragged_shapes(self, n, vocab, want_t, want_vb):
+        block_t, block_v = fused_ce_mod._choose_tiles("bwd", n, vocab, 768, 2)
+        assert block_t == want_t
+        if want_vb is not None:
+            assert -(-vocab // block_v) == want_vb
+
+    def test_an_override_that_risks_stale_dw_sums_is_refused_on_the_chip(self, monkeypatch):
+        # Two vocabulary blocks under several token blocks: the interpreter
+        # runs it (grid steps one after another), the chip must not.
+        h, w, labels = _rand_problem(seed=5)
+        loss = lambda interpret: jax.grad(  # noqa: E731
+            lambda h: jnp.sum(fused_ce_per_token(h, w, labels, BT, BV, None, 0.0, interpret))
+        )
+        assert np.all(np.isfinite(loss(True)(h)))
+        with pytest.raises(ValueError, match="needs 1 block or at least 4"):
+            jax.eval_shape(loss(False), h)
+
+    def test_chosen_tiles_are_logged_once(self, caplog):
+        fused_ce_mod._TILES_LOGGED.clear()
+        with caplog.at_level(logging.INFO, logger="llmtrain_tpu.ops.fused_ce"):
+            for _ in range(2):
+                fused_ce_mod._tiles("bwd", 32768, 50257, 768, 2, None, None)
+        lines = [r.getMessage() for r in caplog.records if "fused_ce bwd tiles" in r.getMessage()]
+        assert len(lines) == 1
+        assert "chosen from shapes" in lines[0] and "MiB of VMEM" in lines[0]
+
+
+class TestBenchmarkContract:
+    def test_one_forward_and_one_backward_kernel_under_names_the_readers_know(self):
+        """``benchmarks/lib/kernel_costs.py`` finds the kernels by name in the
+        trace and divides by ONE call's cost: a second backward kernel, or a
+        name it does not list, and ``kernel_roofline_share.fused_ce_*`` reads
+        nothing."""
+        import sys
+        from pathlib import Path
+
+        root = str(Path(__file__).resolve().parents[1])
+        if root not in sys.path:
+            sys.path.insert(0, root)
+        from benchmarks.lib.kernel_costs import KERNELS
+
+        h, w, labels = _rand_problem()
+        jaxpr = jax.make_jaxpr(
+            jax.grad(
+                lambda h, w: jnp.sum(fused_ce_per_token(h, w, labels, BT, BV, None, 0.0, True)),
+                argnums=(0, 1),
+            )
+        )(h, w)
+        names = [eqn.params["name"] for eqn in _pallas_calls(jaxpr.jaxpr)]
+        fwd_names, _ = KERNELS["fused_ce_fwd"]
+        bwd_names, _ = KERNELS["fused_ce_bwd"]
+        assert len(names) == 2
+        assert sum(name in fwd_names for name in names) == 1
+        assert sum(name in bwd_names for name in names) == 1
 
 
 # --------------------------------------------------------------------------
