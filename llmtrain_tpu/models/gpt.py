@@ -65,6 +65,70 @@ def _scaled_init(n_layers: int) -> nn.initializers.Initializer:
     return nn.initializers.normal(stddev=0.02 / math.sqrt(2 * n_layers))
 
 
+class RowsDenseGeneral(nn.Module):
+    """``nn.DenseGeneral`` over trailing input axes with the product formed
+    as ONE matrix product: the same parameters (``kernel`` of shape
+    ``(*inputs, *features)``, ``bias`` of shape ``features``, initialised
+    value for value as the stock module initialises them), the contracted
+    axes and the feature axes each merged for the product and the bias add,
+    and the output in the stock module's shape, ``(..., *features)``.
+
+    What differs is the shape the compiler sees. Handed a product, or a bias
+    add, of shape ``(B, T, 3, H, 64)`` it lays T on the lanes (64 would
+    half-fill them), and every consumer that wants rows of ``H * 64``
+    values, the attention kernels among them, pays a transposing copy of the
+    activation. Handed ``(B, T, 3*H*64)`` it keeps rows, and a reshape on
+    either side moves nothing.
+    """
+
+    features: int | tuple[int, ...]
+    axis: int | tuple[int, ...] = -1
+    use_bias: bool = True
+    dtype: Any = None
+    param_dtype: Any = jnp.float32
+    kernel_init: Any = nn.initializers.lecun_normal()
+    bias_init: Any = nn.initializers.zeros_init()
+    dot_general: Any = None
+
+    @nn.compact
+    def __call__(self, inputs: jax.Array) -> jax.Array:
+        features = (self.features,) if isinstance(self.features, int) else tuple(self.features)
+        axis = (self.axis,) if isinstance(self.axis, int) else tuple(self.axis)
+        kept = inputs.ndim - len(axis)
+        if tuple(a % inputs.ndim for a in axis) != tuple(range(kept, inputs.ndim)):
+            raise ValueError(f"axis {self.axis} is not the trailing axes of {inputs.shape}")
+        in_shape = inputs.shape[kept:]
+
+        def flat(init, flat_shape):
+            # As nn.DenseGeneral: initialise flat, then shape (so fan-in and
+            # fan-out are the matrix's), keeping the partitioning's names.
+            def wrap(rng, shape, dtype=jnp.float32):
+                value = init(rng, flat_shape, dtype)
+                if isinstance(value, nn.meta.AxisMetadata):
+                    return nn.meta.replace_boxed(value, jnp.reshape(value.unbox(), shape))
+                return jnp.reshape(value, shape)
+
+            return wrap
+
+        k, f = math.prod(in_shape), math.prod(features)
+        kernel = self.param(
+            "kernel", flat(self.kernel_init, (k, f)), in_shape + features, self.param_dtype
+        )
+        bias = (
+            self.param("bias", flat(self.bias_init, (f,)), features, self.param_dtype)
+            if self.use_bias
+            else None
+        )
+        inputs, kernel, bias = nn.dtypes.promote_dtype(inputs, kernel, bias, dtype=self.dtype)
+        rows = inputs.reshape(inputs.shape[:kept] + (k,))
+        out = (self.dot_general or jax.lax.dot_general)(
+            rows, kernel.reshape(k, f), (((kept,), (0,)), ((), ()))
+        )
+        if bias is not None:
+            out = out + bias.reshape(f)
+        return out.reshape(inputs.shape[:kept] + features)
+
+
 def scaled(x: jax.Array, scale: float) -> jax.Array:
     """``x * scale`` computed in float32 and cast back (a bf16 multiplier
     would carry its own rounding into every element); 1.0 returns ``x``.
@@ -330,9 +394,22 @@ class CausalSelfAttention(nn.Module):
         from ..ops.quant import quant_dot_general
 
         quant_dg = quant_dot_general(self.matmul_precision)
+        # The attention kernels take and give rows of H * head_dim values:
+        # on their branch the projections' products keep that shape (the
+        # same parameters; every other branch's program is as it was).
+        # A mesh that splits the heads over devices keeps the stock
+        # products: a merged 3*H*D axis sharded inside its H factor is
+        # nothing GSPMD can say, and the kernels' shard_map hands each
+        # device its own heads either way.
+        from ..parallel.sharding import ambient_mesh
+
+        mesh = ambient_mesh()
+        heads_split = mesh is not None and mesh.shape.get("tensor", 1) > 1
+        rows = self.attention == "flash" and not self.decode and not heads_split
+        dense = RowsDenseGeneral if rows else nn.DenseGeneral
 
         if kv_heads == self.n_heads:
-            qkv = nn.DenseGeneral(
+            qkv = dense(
                 features=(3, self.n_heads, head_dim),
                 axis=-1,
                 use_bias=qkv_use_bias,
@@ -352,7 +429,7 @@ class CausalSelfAttention(nn.Module):
                     f"n_heads ({self.n_heads}) must be divisible by "
                     f"n_kv_heads ({kv_heads})"
                 )
-            q = nn.DenseGeneral(
+            q = dense(
                 features=(self.n_heads, head_dim),
                 axis=-1,
                 use_bias=qkv_use_bias,
@@ -365,7 +442,7 @@ class CausalSelfAttention(nn.Module):
                 dot_general=quant_dg,
                 name="q_proj",
             )(x)
-            kv = nn.DenseGeneral(
+            kv = dense(
                 features=(2, kv_heads, head_dim),
                 axis=-1,
                 use_bias=qkv_use_bias,
@@ -428,14 +505,28 @@ class CausalSelfAttention(nn.Module):
             # gpt.py:60-64 semantics) — the Pallas kernels take the (B, T)
             # key mask directly. assume_packed drops the operand when the
             # data is provably packed (all-ones masks ≡ no mask).
-            from ..ops.flash_attention import flash_attention
+            # Where q, k and v are still the one projection's output as it
+            # was made (no K/V grouping, no rotation, no key multiplier),
+            # the kernels take that array whole: they read the three out of
+            # it in place and hand back one gradient for it, so no slice,
+            # transpose or concatenation of an activation stands between
+            # the projections' matmuls and the kernels.
+            from ..ops.flash_attention import flash_attention, flash_attention_qkv
 
-            out = flash_attention(
-                q, k, v,
-                attention_mask=None if self.assume_packed else attention_mask,
-                causal=True,
-                window=self.sliding_window,
-            )
+            key_mask = None if self.assume_packed else attention_mask
+            if kv_heads == self.n_heads and not self.rope and self.key_scale == 1.0:
+                out = flash_attention_qkv(
+                    nn.with_logical_constraint(
+                        qkv, ("batch", "length", None, "act_heads", "act_kv")
+                    ),
+                    attention_mask=key_mask,
+                    window=self.sliding_window,
+                )
+            else:
+                out = flash_attention(
+                    q, k, v, attention_mask=key_mask, causal=True,
+                    window=self.sliding_window,
+                )
         elif self.attention == "ring":
             # Sequence-parallel exact attention over the mesh's `sequence`
             # axis (ops/ring_attention.py); falls back to blockwise when no
@@ -473,7 +564,7 @@ class CausalSelfAttention(nn.Module):
                 window=self.sliding_window,
             )
 
-        out = nn.DenseGeneral(
+        out = dense(
             features=self.d_model,
             axis=(-2, -1),
             use_bias=self.use_bias,

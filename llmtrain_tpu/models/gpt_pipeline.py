@@ -86,13 +86,18 @@ def make_block_apply(
             ) + p["kv_bias"].astype(dtype)
             k, v = kv[:, :, 0], kv[:, :, 1]  # (B, T, Hkv_l, Dh)
         if attention == "flash":
-            from ..ops.flash_attention import flash_attention
+            from ..ops.flash_attention import flash_attention, flash_attention_qkv
 
-            # Narrow GQA K/V consumed natively (Pallas index maps on TPU,
-            # grouped einsums in the blockwise fallback).
-            att = flash_attention(
-                q, k, v, attention_mask=key_mask, causal=True, window=window
-            )
+            # The fused projection's output goes to the kernels whole (they
+            # read q, k and v out of it in place); narrow GQA K/V are
+            # consumed natively (Pallas index maps on TPU, grouped einsums
+            # in the blockwise fallback).
+            if "qkv_kernel" in p:
+                att = flash_attention_qkv(qkv, attention_mask=key_mask, window=window)
+            else:
+                att = flash_attention(
+                    q, k, v, attention_mask=key_mask, causal=True, window=window
+                )
         else:
             if k.shape[2] != q.shape[2]:
                 reps = q.shape[2] // k.shape[2]

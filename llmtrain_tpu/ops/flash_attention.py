@@ -12,6 +12,18 @@ reference models/gpt.py:56-69). Training differentiates through a
 
 Both paths are O(T) memory — no (T, T) materialization.
 
+The kernels take and give the projections' own rows, ``(B, T, H*D)``
+(pallas_attention.py: a lane block is one head of a multiple of 128 or two
+64-wide heads), so the ``(B, T, H, D)`` operands of ``flash_attention``
+reach them by a reshape. A block whose q, k and v are the untouched output
+of ONE projection calls ``flash_attention_qkv`` with that ``(B, T, 3, H,
+D)`` array: the kernels read the three out of it where it lies and return
+its gradient as one array. Shapes without whole lane blocks (a 64-wide head
+under GQA, an odd head count at 64, another head width) take the folded
+``(B*H, T, D)`` arrays, XLA transposing around the call; the shapes decide,
+no option does. The custom_vjp's residuals are the operands as they were
+handed in and the forward's output and logsumexp.
+
 The platform decides, never the shape: on ``tpu`` a sequence length the
 kernels cannot tile is an error (no silent blockwise), and
 ``resolved_attention_impl`` names what a run will execute for its report.
@@ -92,93 +104,103 @@ def resolved_attention_impl(attention: str) -> str:
     return "pallas_flash" if jax.default_backend() == "tpu" else "blockwise"
 
 
-def _blockwise(q, k, v, key_mask=None, window=0):
+def _pallas_fwd(window, operands, maskf):
+    from .pallas_attention import (
+        pallas_flash_attention_fwd,
+        pallas_flash_attention_qkv_fwd,
+    )
+
+    resident, streamed = _auto_block(operands[0].shape[1])
+    fwd = pallas_flash_attention_fwd if len(operands) == 3 else pallas_flash_attention_qkv_fwd
+    return fwd(*operands, maskf, causal=True, block_q=resident, block_k=streamed, window=window)
+
+
+def _pallas_bwd(window, operands, maskf, out, lse, g):
+    """The operands' gradients, one each: ``(dq, dk, dv)`` or ``(dqkv,)``."""
+    from .pallas_attention import (
+        pallas_flash_attention_bwd,
+        pallas_flash_attention_qkv_bwd,
+    )
+
+    resident, streamed = _auto_block(operands[0].shape[1])
+    bwd = pallas_flash_attention_bwd if len(operands) == 3 else pallas_flash_attention_qkv_bwd
+    grads = bwd(
+        *operands, out, lse, g, maskf, causal=True, block_q=resident, block_k=streamed,
+        dkdv_block_q=streamed, dkdv_block_k=resident, window=window,
+    )
+    return grads if len(operands) == 3 else (grads,)
+
+
+def _blockwise(operands, maskf, window):
     # blockwise consumes grouped-query narrow K/V natively. query_mask =
     # key_mask upgrades to segment semantics (q and k cover the same
     # sequence here), matching the Pallas kernels and dense_attention.
-    return blockwise_attention(q, k, v, causal=True, key_mask=key_mask,
-                               query_mask=key_mask, window=window)
+    if len(operands) == 1:  # the fused (B, T, 3, H, D) projection output
+        operands = tuple(operands[0][:, :, i] for i in range(3))
+    q, k, v = operands
+    return blockwise_attention(q, k, v, causal=True, key_mask=maskf,
+                               query_mask=maskf, window=window)
 
 
-def _pallas_fwd(window, q, k, v, maskf=None):
-    from .pallas_attention import pallas_flash_attention_fwd
-
-    resident, streamed = _auto_block(q.shape[1])
-    return pallas_flash_attention_fwd(
-        q, k, v, maskf, causal=True, block_q=resident, block_k=streamed, window=window
-    )
-
-
-def _pallas_bwd(window, q, k, v, out, lse, g, maskf=None):
-    from .pallas_attention import pallas_flash_attention_bwd
-
-    resident, streamed = _auto_block(q.shape[1])
-    return pallas_flash_attention_bwd(
-        q, k, v, out, lse, g, maskf, causal=True,
-        block_q=resident, block_k=streamed,
-        dkdv_block_q=streamed, dkdv_block_k=resident, window=window,
-    )
-
-
-# ``window`` is a static Python int (0 = off) and travels as the leading
-# nondiff arg of both custom_vjps — Mistral-style sliding-window masking
-# with dead K/V blocks skipped in the Pallas kernels.
+# One custom_vjp for every call: ``operands`` is ``(q, k, v)`` or the one
+# fused ``(qkv,)``; ``maskf`` is None or the (B, T) key-padding mask as
+# float32, so that its cotangent is a well-typed zero. ``window`` is a static
+# Python int (0 = off) and travels as the leading nondiff arg —
+# Mistral-style sliding-window masking with dead K/V blocks skipped in the
+# Pallas kernels. The residuals are the operands as they were handed in:
+# nothing is laid out anew for the backward.
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash(window, q, k, v):
-    if _use_pallas(q.shape[1]):
-        return _pallas_fwd(window, q, k, v)[0]
-    return _blockwise(q, k, v, window=window)
+def _flash(window, operands, maskf):
+    if _use_pallas(operands[0].shape[1]):
+        return _pallas_fwd(window, operands, maskf)[0]
+    return _blockwise(operands, maskf, window)
 
 
-def _flash_fwd(window, q, k, v):
-    if _use_pallas(q.shape[1]):
-        out, lse = _pallas_fwd(window, q, k, v)
-        return out, (q, k, v, out, lse)
-    return _flash(window, q, k, v), (q, k, v, None, None)
+def _flash_fwd(window, operands, maskf):
+    if _use_pallas(operands[0].shape[1]):
+        out, lse = _pallas_fwd(window, operands, maskf)
+        return out, (operands, maskf, out, lse)
+    return _flash(window, operands, maskf), (operands, maskf, None, None)
 
 
 def _flash_bwd(window, residuals, g):
-    q, k, v, out, lse = residuals
+    operands, maskf, out, lse = residuals
     if out is not None:
-        return _pallas_bwd(window, q, k, v, out, lse, g)
-    _, vjp = jax.vjp(lambda q_, k_, v_: _blockwise(q_, k_, v_, window=window),
-                     q, k, v)
-    return vjp(g)
+        grads = _pallas_bwd(window, operands, maskf, out, lse, g)
+    else:
+        _, vjp = jax.vjp(lambda *ops: _blockwise(ops, maskf, window), *operands)
+        grads = vjp(g)
+    return tuple(grads), None if maskf is None else jnp.zeros_like(maskf)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-# Masked variant: the (B, T) key-padding mask travels as float32 so the
-# custom_vjp can return a well-typed zero cotangent for it.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash_masked(window, q, k, v, maskf):
-    if _use_pallas(q.shape[1]):
-        return _pallas_fwd(window, q, k, v, maskf)[0]
-    return _blockwise(q, k, v, key_mask=maskf, window=window)
-
-
-def _flash_masked_fwd(window, q, k, v, maskf):
-    if _use_pallas(q.shape[1]):
-        out, lse = _pallas_fwd(window, q, k, v, maskf)
-        return out, (q, k, v, maskf, out, lse)
-    return _flash_masked(window, q, k, v, maskf), (q, k, v, maskf, None, None)
-
-
-def _flash_masked_bwd(window, residuals, g):
-    q, k, v, maskf, out, lse = residuals
-    if out is not None:
-        dq, dk, dv = _pallas_bwd(window, q, k, v, out, lse, g, maskf)
-        return dq, dk, dv, jnp.zeros_like(maskf)
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: _blockwise(q_, k_, v_, key_mask=maskf, window=window),
-        q, k, v,
-    )
-    dq, dk, dv = vjp(g)
-    return dq, dk, dv, jnp.zeros_like(maskf)
-
-
-_flash_masked.defvjp(_flash_masked_fwd, _flash_masked_bwd)
+def _dispatch(operands, attention_mask, window: int) -> jax.Array:
+    """Run ``_flash`` on this device, or on each device's shard of a mesh."""
+    if window < 0:
+        raise ValueError(f"window must be >= 0, got {window}")
+    maskf = None if attention_mask is None else attention_mask.astype(jnp.float32)
+    mesh = kernel_mesh()
+    if mesh is None:
+        return _flash(int(window), operands, maskf)
+    # Attention is independent per batch row and per head, so the shards
+    # need no collective: batch over the batch axes, heads over tensor
+    # (q and the possibly-narrower GQA k/v must both divide), full T. A
+    # shard of the fused array is the local projection's own output, three
+    # column ranges of whole heads, so the kernels decide on local shapes.
+    batch = shard_axes(mesh, BATCH_AXES, operands[0].shape[0])
+    heads = shard_axes(mesh, ("tensor",), *(x.shape[-2] for x in operands))
+    spec = P(batch, None, heads, None)
+    fused = P(batch, None, None, heads, None)
+    return jax.shard_map(
+        lambda ops, m: _flash(int(window), ops, m),
+        mesh=mesh,
+        in_specs=(tuple(spec if x.ndim == 4 else fused for x in operands),
+                  None if maskf is None else P(batch, None)),
+        out_specs=spec,
+        check_vma=False,
+    )(operands, maskf)
 
 
 def flash_attention(
@@ -199,27 +221,23 @@ def flash_attention(
     (Mistral sliding-window semantics; requires ``causal``); the Pallas
     kernels skip dead K/V blocks, so compute is O(T·window).
     """
-    if window < 0:
-        raise ValueError(f"window must be >= 0, got {window}")
     if not causal:
         if window:
             raise ValueError("sliding window requires causal attention")
         return blockwise_attention(q, k, v, causal=False, key_mask=attention_mask)
-    fn = functools.partial(
-        _flash if attention_mask is None else _flash_masked, int(window)
-    )
-    args = (q, k, v)
-    if attention_mask is not None:
-        args += (attention_mask.astype(jnp.float32),)
-    mesh = kernel_mesh()
-    if mesh is None:
-        return fn(*args)
-    # Attention is independent per batch row and per head, so the shards
-    # need no collective: batch over the batch axes, heads over tensor
-    # (q and the possibly-narrower GQA k/v must both divide), full T.
-    batch = shard_axes(mesh, BATCH_AXES, q.shape[0])
-    spec = P(batch, None, shard_axes(mesh, ("tensor",), q.shape[2], k.shape[2]), None)
-    in_specs = (spec, spec, spec) + ((P(batch, None),) if len(args) == 4 else ())
-    return jax.shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False
-    )(*args)
+    return _dispatch((q, k, v), attention_mask, window)
+
+
+def flash_attention_qkv(
+    qkv: jax.Array,
+    *,
+    attention_mask: jax.Array | None = None,
+    window: int = 0,
+) -> jax.Array:
+    """``flash_attention`` (causal) for a block whose q, k and v are the
+    untouched output of ONE projection, (B, T, 3, H, Dh): returns (B, T, H,
+    Dh). On the chip the kernels read the three out of that array where it
+    lies and write its gradient as one array, so neither a slice nor a
+    concatenation of an activation stands beside them; a block that rotates
+    or scales q or k after the projection calls ``flash_attention``."""
+    return _dispatch((qkv,), attention_mask, window)

@@ -1,9 +1,42 @@
 """Pallas TPU flash-attention kernels: forward and backward.
 
 The MXU-resident hot path for causal attention: one grid program per
-(batch*head, q-block), K/V of the head whole in VMEM, online softmax, so
-nothing of shape (T, T) ever exists. Every per-block operation happens in
-the layout and the dtype the chip already holds its operands in:
+(batch, head block, q-block), K/V of the head block whole in VMEM, online
+softmax, so nothing of shape (T, T) ever exists.
+
+**The layout the kernels take and give is the projections' own: rows,
+``(B, T, H*D)``**, as the qkv projection's matmul leaves them and the out
+projection's takes them, so no transpose of an activation stands in XLA on
+either side of a call, forward or backward. A BlockSpec takes a column
+range of whole 128-lane tiles (``lane_block_heads``): one head where the
+head width is a multiple of 128, TWO adjacent heads where it is 64. Inside
+a program the two heads of a block are told apart by lane masks, never by
+lane slices: the resident operand of a contraction is split into two copies
+with the other head's lanes zeroed (``_split``: a contraction over 128 lanes
+of which 64 are zeros is exact, and costs the MXU the passes a 64-deep
+product costs: the systolic array is 128 deep either way), and a product
+whose output columns are the block's lanes comes out once a head and is
+joined by a lane select (``_join``). Statistics, accumulation and the
+skip-schedule are per head, two sets a program. Three hand-offs share the
+kernels (``_Handoff``), picked from the operands' shapes alone:
+
+* *fused*: q, k and v are the three column ranges of the ONE
+  ``(B, T, 3*H*D)`` array the qkv projection wrote
+  (``pallas_flash_attention_qkv_fwd`` / ``_bwd``); the gradient is one such
+  array, its first third written by the dq call and the rest, in place, by
+  the dk/dv call (``input_output_aliases``), so nothing is sliced before or
+  concatenated after;
+* *merged*: q, k and v apart, each ``(B, T, heads*D)`` (a reshape of the
+  ``(B, T, H, D)`` the wrappers are handed, no data moves): grouped-query
+  K/V at a head width of 128, or operands the block rotated or scaled after
+  the projection;
+* *folded*: ``(B*H, T, D)`` arrays made and unmade by XLA transposes around
+  the calls, for the shapes with no whole lane blocks: a 64-wide head under
+  GQA (a pair of query heads would want half a lane block of K/V), an odd
+  head count at 64, any other head width.
+
+Every per-block operation happens in the layout and the dtype the chip
+already holds its operands in:
 
 * **Softmax statistics stay on sublanes.** The running max ``m``, the
   running sum ``l`` and the output accumulator live in ``pltpu.VMEM``
@@ -11,7 +44,9 @@ the layout and the dtype the chip already holds its operands in:
   lane-replicated (what a row reduction leaves behind and what the next
   block's broadcast wants), never a 1-D ``(block_q,)`` value. The
   lane-major ``lse`` row the backward reads is produced once a program,
-  after the loops; in HBM ``lse`` and ``delta`` stay ``(B*H, T)`` float32.
+  after the loops; in HBM ``lse`` and ``delta`` are one float32 a row and
+  head, ``(B, H, 1, T)``. ``delta`` = rowsum(dO * O) is formed by the dq
+  kernel, which holds dO's rows anyway, and handed to the dk/dv kernel.
 * **No transposed left operand.** The dk/dv kernel computes the scores
   transposed (``k @ q^T``, keys on rows), so ``p^T`` and ``ds^T`` are born
   in the orientation ``dv = p^T dO`` and ``dk = ds^T q`` consume, and
@@ -47,7 +82,7 @@ Two capabilities beyond the plain causal kernel:
 * **Native grouped-query attention**: K/V may have fewer heads than Q
   (n_kv_heads). The forward and dq kernels map each query head to its
   K/V group via the BlockSpec index map — no jnp.repeat materialization
-  in HBM — and the dk/dv kernel grids over (batch*kv_head, k-block,
+  in HBM — and the dk/dv kernel grids over (batch, kv-head block, k-block,
   group member), accumulating the group in float32 VMEM scratch, so
   gradients are born at the narrow width and in the narrow dtype.
 
@@ -67,6 +102,7 @@ implementation.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -222,32 +258,57 @@ def _key_block_schedule(k0, block_k, block_q, seq_len, *, causal, window, strip,
           lambda qb: step(_span(qb, block_q), block_q, block_k, 0, False, 0))
 
 
-def _flash_kernel(
-    q_ref, k_ref, v_ref, *rest, block_k: int, scale: float, fold_scale: bool,
-    causal: bool, masked: bool, window: int = 0,
-):
-    """One q-block vs the K/V sequence of its head.
+def _split(x, hpb: int):
+    """The heads of one lane block apart: per head, ``x`` (rows, W) with
+    the other head's lanes zeroed, so that a contraction over all W lanes
+    sees that head alone (exact: the other head's terms are zeros)."""
+    if hpb == 1:
+        return [x]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    half = x.shape[1] // 2
+    zero = jnp.zeros_like(x)
+    return [jnp.where(lane < half, x, zero), jnp.where(lane >= half, x, zero)]
 
-    Ref shapes: q (1, BQ, D), k/v (1, T, D), o (1, BQ, D), l (1, 1, BQ),
-    optional mask (1, 1, T) int32 + its q-block view (1, 1, BQ) ahead of
-    the outputs when ``masked``; scratch m/l (BQ, lanes) and acc (BQ, D),
-    float32. Mask values are SEGMENT ids: nonzero = real token, equal
-    values = same document (plain 0/1 padding masks are the one-segment
-    special case). ``l`` is the per-row logsumexp of the scaled/masked
-    logits — the residual the backward kernels use to recompute P without
-    a re-softmax. It is carried with a singleton middle dim so its block
-    shape satisfies Mosaic's tiling rule (second-to-last block dim ==
-    array dim).
+
+def _join(parts):
+    """One (rows, W) value that takes each head's lanes from its own part:
+    the inverse of ``_split`` for products whose output columns are the
+    block's lanes."""
+    if len(parts) == 1:
+        return parts[0]
+    left, right = parts
+    lane = jax.lax.broadcasted_iota(jnp.int32, left.shape, 1)
+    return jnp.where(lane < left.shape[1] // 2, left, right)
+
+
+def _flash_kernel(
+    q_ref, k_ref, v_ref, *rest, hpb: int, block_k: int, scale: float,
+    fold_scale: bool, causal: bool, masked: bool, window: int = 0,
+):
+    """One q-block vs the K/V sequence of its head block (``hpb`` heads:
+    one, or two 64-wide heads side by side in a lane tile).
+
+    Ref shapes: q (1, BQ, W), k/v (1, T, W), o (1, BQ, W), l (1, hpb, 1,
+    BQ), optional mask (1, 1, T) int32 + its q-block view (1, 1, BQ) ahead
+    of the outputs when ``masked``; scratch m/l (hpb, BQ, lanes) and acc
+    (BQ, W), float32. Mask values are SEGMENT ids: nonzero = real token,
+    equal values = same document (plain 0/1 padding masks are the
+    one-segment special case). ``l`` is the per-row logsumexp of the
+    scaled/masked logits — the residual the backward kernels use to
+    recompute P without a re-softmax. It is carried with a singleton
+    second-to-last dim so its block shape satisfies Mosaic's tiling rule
+    (second-to-last block dim == array dim).
     """
     if masked:
         mask_ref, mask_q_ref, o_ref, l_ref, m_s, l_s, acc_s = rest
     else:
         o_ref, l_ref, m_s, l_s, acc_s = rest
-    block_q, head_dim = q_ref.shape[1:]
-    lanes = m_s.shape[1]
-    q0 = pl.program_id(1) * block_q
+    block_q, width = q_ref.shape[1:]
+    lanes = m_s.shape[2]
+    q0 = pl.program_id(2) * block_q
 
-    q = q_ref[0] * scale if fold_scale else q_ref[0]  # (BQ, D), operand dtype
+    q = q_ref[0] * scale if fold_scale else q_ref[0]  # (BQ, W), operand dtype
+    qs = _split(q, hpb)
     score_scale = None if fold_scale else scale
     if masked:
         q_seg = _to_col(mask_q_ref[0], lanes)  # (BQ, lanes) int32
@@ -255,31 +316,35 @@ def _flash_kernel(
     l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
     acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def step(keys, width, r0, rows, offset, diag, win):
+    def step(keys, tile, r0, rows, offset, diag, win):
         sl = pl.ds(r0, rows)
-        k_blk = k_ref[0, keys, :]  # (width, D)
+        k_blk = k_ref[0, keys, :]  # (tile, W)
         v_blk = v_ref[0, keys, :]
         segments = (q_seg[r0:r0 + rows], mask_ref[0, :, keys]) if masked else None
-        s = _scores(q[r0:r0 + rows], k_blk, score_scale, offset, diag=diag,
-                    window=win, segments=segments)  # (rows, width)
-        m_prev = m_s[sl, :]
-        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
-        p = jnp.exp(s - _bcast(m_new, width))
-        alpha = jnp.exp(m_prev - m_new)
-        m_s[sl, :] = m_new
-        l_s[sl, :] = alpha * l_s[sl, :] + p.sum(axis=1, keepdims=True)
-        acc_s[sl, :] = acc_s[sl, :] * _bcast(alpha, head_dim) + _dot(
-            p.astype(v_blk.dtype), v_blk, _NN
-        )
+        alphas, pvs = [], []
+        for j, qj in enumerate(qs):
+            s = _scores(qj[r0:r0 + rows], k_blk, score_scale, offset, diag=diag,
+                        window=win, segments=segments)  # (rows, tile)
+            m_prev = m_s[j, sl, :]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - _bcast(m_new, tile))
+            alpha = jnp.exp(m_prev - m_new)
+            m_s[j, sl, :] = m_new
+            l_s[j, sl, :] = alpha * l_s[j, sl, :] + p.sum(axis=1, keepdims=True)
+            alphas.append(_bcast(alpha, width))
+            pvs.append(_dot(p.astype(v_blk.dtype), v_blk, _NN))
+        acc_s[sl, :] = acc_s[sl, :] * _join(alphas) + _join(pvs)
 
     _query_block_schedule(
         q0, block_q, block_k, k_ref.shape[1], causal=causal, window=window,
         strip=_FWD_STRIP, step=step,
     )
 
-    row_sum = l_s[...]
-    o_ref[0] = (acc_s[...] * _bcast(1.0 / row_sum, head_dim)).astype(o_ref.dtype)
-    l_ref[0] = _to_row(m_s[...] + jnp.log(row_sum))
+    o_ref[0] = (
+        acc_s[...] * _join([_bcast(1.0 / l_s[j], width) for j in range(hpb)])
+    ).astype(o_ref.dtype)
+    for j in range(hpb):
+        l_ref[0, j] = _to_row(m_s[j] + jnp.log(l_s[j]))
 
 
 def _fold(x: jax.Array) -> jax.Array:
@@ -291,6 +356,93 @@ def _fold(x: jax.Array) -> jax.Array:
 def _unfold(x: jax.Array, b: int, h: int) -> jax.Array:
     bh, t, d = x.shape
     return jnp.moveaxis(x.reshape(b, h, t, d), 1, 2)
+
+
+def lane_block_heads(h: int, hkv: int, d: int) -> int | None:
+    """Heads in one lane block of a ``(B, T, H*D)`` array the kernels can
+    index in place, or None where they cannot and the call folds.
+
+    A block's last dimension is whole 128-lane tiles: one head where the
+    head width is a multiple of 128 (any K/V grouping: a query head's block
+    maps to its group's block), two adjacent heads where it is 64 and every
+    query head has a K/V head of its own (under GQA a pair of query heads
+    would want half a lane block of K/V). Anything else (an odd head count
+    at 64, another width) keeps the folded ``(B*H, T, D)`` hand-off.
+    """
+    if d % _LANES == 0:
+        return 1
+    if d == _LANES // 2 and h == hkv and h % 2 == 0:
+        return 2
+    return None
+
+
+@dataclasses.dataclass(frozen=True)
+class _Handoff:
+    """Where the kernels find a head block in the arrays they are handed.
+
+    Three hand-offs, one set of kernels: *folded* ``(B*H, T, D)`` arrays
+    (the transposes live in XLA, around the call), *merged* ``(B, T, H*D)``
+    arrays as the projections' matmuls leave and take them (a block is a
+    column range of whole lane tiles), and *fused*: merged, with q, k and v
+    the three column ranges of ONE ``(B, T, 3*H*D)`` array (the qkv
+    projection's output; the gradient is one such array too).
+    """
+
+    batch: int
+    seq: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    hpb: int  # heads a block holds
+    merged: bool
+    fused: bool = False
+
+    @property
+    def width(self) -> int:
+        return self.hpb * self.head_dim
+
+    @property
+    def q_blocks(self) -> int:
+        return self.heads // self.hpb
+
+    @property
+    def kv_blocks(self) -> int:
+        return self.kv_heads // self.hpb
+
+    def at(self, which: int, b, blk, rows):
+        """Block index of head block ``blk``, row block ``rows`` of batch
+        row ``b``, in the array of q (``which`` 0), k (1) or v (2)."""
+        if not self.merged:
+            return (b * (self.kv_heads if which else self.heads) + blk, rows, 0)
+        return (b, rows, blk + (which * self.q_blocks if self.fused else 0))
+
+    def hand(self, x: jax.Array) -> jax.Array:
+        """(B, T, H, D), or the fused (B, T, 3, H, D), as the kernels index it."""
+        if not self.merged:
+            return _fold(x)
+        return x.reshape(self.batch, self.seq, -1)
+
+    def shape(self, heads: int) -> tuple[int, int, int]:
+        """Of a kernel's output that ``take`` turns into ``heads`` heads."""
+        if not self.merged:
+            return (self.batch * heads, self.seq, self.head_dim)
+        return (self.batch, self.seq, heads * self.head_dim)
+
+    def take(self, x: jax.Array, heads: int) -> jax.Array:
+        """A kernel's output back as (B, T, heads, D)."""
+        if not self.merged:
+            return _unfold(x, self.batch, heads)
+        return x.reshape(self.batch, self.seq, heads, self.head_dim)
+
+
+def _handoff(q_shape, k_shape, fused: bool = False) -> _Handoff:
+    """The hand-off a call's shapes allow: merged where ``lane_block_heads``
+    finds whole lane blocks, folded otherwise."""
+    b, t, h, d = q_shape
+    hkv = k_shape[2]
+    _head_groups(h, hkv)
+    hpb = lane_block_heads(h, hkv, d)
+    return _Handoff(b, t, h, hkv, d, hpb or 1, merged=hpb is not None, fused=fused)
 
 
 def _check_blocks(t: int, block_q: int, block_k: int) -> tuple[int, int]:
@@ -327,16 +479,6 @@ def _head_groups(h: int, hkv: int) -> int:
     return h // hkv
 
 
-def _kv_index(h: int, hkv: int):
-    """Folded-q row (b*h + head) -> folded-kv row (b*hkv + head//group)."""
-    group = h // hkv
-
-    def kv_row(bh):
-        return (bh // h) * hkv + (bh % h) // group
-
-    return kv_row
-
-
 def _mask3(mask: jax.Array | None) -> jax.Array | None:
     """(B, T) padding mask -> (B, 1, T) int32 for legal (1, 1, BK) tiling."""
     if mask is None:
@@ -344,9 +486,68 @@ def _mask3(mask: jax.Array | None) -> jax.Array | None:
     return mask.astype(jnp.int32)[:, None, :]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret", "window")
-)
+def _forward(lay: _Handoff, q, k, v, mask, *, causal, block_q, block_k, interpret, window):
+    """The forward call on arrays already in ``lay``'s hand-off (the same
+    array three times when fused). Returns ``(out, lse)``: ``out`` shaped
+    ``lay.shape(heads)``, ``lse`` (B, H, 1, T) float32."""
+    b, t, d, hpb, w = lay.batch, lay.seq, lay.head_dim, lay.hpb, lay.width
+    group = lay.heads // lay.kv_heads
+    block_q, block_k = _check_blocks(t, block_q, block_k)
+    _check_window(window, causal)
+    scale = 1.0 / math.sqrt(d)
+    masked = mask is not None
+    lanes = _stat_lanes(block_q, block_k)
+
+    kernel = functools.partial(
+        _flash_kernel, hpb=hpb, block_k=block_k, scale=scale,
+        fold_scale=_fold_scale(scale, q.dtype), causal=causal, masked=masked,
+        window=window,
+    )
+    in_specs = [
+        pl.BlockSpec((1, block_q, w), lambda bi, hb, qi: lay.at(0, bi, hb, qi)),
+        pl.BlockSpec((1, t, w), lambda bi, hb, qi: lay.at(1, bi, hb // group, 0)),
+        pl.BlockSpec((1, t, w), lambda bi, hb, qi: lay.at(2, bi, hb // group, 0)),
+    ]
+    operands = [q, k, v]
+    if masked:
+        mask3 = _mask3(mask)
+        in_specs.append(pl.BlockSpec((1, 1, t), lambda bi, hb, qi: (bi, 0, 0)))
+        operands.append(mask3)
+        # The SAME mask array again, tiled per q-block (segment ids for
+        # this block's queries).
+        in_specs.append(pl.BlockSpec((1, 1, block_q), lambda bi, hb, qi: (bi, 0, qi)))
+        operands.append(mask3)
+    return pl.pallas_call(
+        kernel,
+        grid=(b, lay.q_blocks, t // block_q),
+        in_specs=in_specs,
+        out_specs=[
+            # The output is an array of its own: q's block index without
+            # the fused array's column offset (q's is 0).
+            pl.BlockSpec((1, block_q, w), lambda bi, hb, qi: lay.at(0, bi, hb, qi)),
+            pl.BlockSpec((1, hpb, 1, block_q), lambda bi, hb, qi: (bi, hb, 0, qi)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(lay.shape(lay.heads), q.dtype),
+            jax.ShapeDtypeStruct((b, lay.heads, 1, t), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((hpb, block_q, lanes), jnp.float32),
+            pltpu.VMEM((hpb, block_q, lanes), jnp.float32),
+            pltpu.VMEM((block_q, w), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")
+        ),
+        interpret=interpret,
+        name="flash_attention_fwd",
+    )(*operands)
+
+
+_STATIC = ("causal", "block_q", "block_k", "interpret", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
 def pallas_flash_attention_fwd(
     q: jax.Array,
     k: jax.Array,
@@ -368,64 +569,43 @@ def pallas_flash_attention_fwd(
     ``causal``) — dead K/V blocks are skipped, so compute is O(T·W).
     ``lse`` has shape (B*H, T), float32 — the backward residual.
     ``block_q`` rows are resident a program, ``block_k`` keys stream a
-    loop step; both fall back to T when T is smaller.
+    loop step; both fall back to T when T is smaller. The arrays reach the
+    kernel merged, ``(B, T, H*D)`` (a reshape, no data moves), wherever
+    ``lane_block_heads`` finds whole lane blocks, and folded otherwise.
     """
-    b, t, h, d = q.shape
-    hkv = k.shape[2]
-    _head_groups(h, hkv)
-    block_q, block_k = _check_blocks(t, block_q, block_k)
-    _check_window(window, causal)
-
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    scale = 1.0 / math.sqrt(d)
-    kv_row = _kv_index(h, hkv)
-    masked = mask is not None
-    lanes = _stat_lanes(block_q, block_k)
-
-    kernel = functools.partial(
-        _flash_kernel, block_k=block_k, scale=scale,
-        fold_scale=_fold_scale(scale, q.dtype), causal=causal, masked=masked,
-        window=window,
+    lay = _handoff(q.shape, k.shape)
+    out, lse = _forward(
+        lay, lay.hand(q), lay.hand(k), lay.hand(v), mask, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret, window=window,
     )
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, t, d), lambda bh, qi: (kv_row(bh), 0, 0)),
-        pl.BlockSpec((1, t, d), lambda bh, qi: (kv_row(bh), 0, 0)),
-    ]
-    operands = [qf, kf, vf]
-    if masked:
-        mask3 = _mask3(mask)
-        in_specs.append(pl.BlockSpec((1, 1, t), lambda bh, qi: (bh // h, 0, 0)))
-        operands.append(mask3)
-        # The SAME mask array again, tiled per q-block (segment ids for
-        # this block's queries).
-        in_specs.append(pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh // h, 0, qi)))
-        operands.append(mask3)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, t // block_q),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, 1, t), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, lanes), jnp.float32),
-            pltpu.VMEM((block_q, lanes), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")
-        ),
-        interpret=interpret,
-        name="flash_attention_fwd",
-    )(*operands)
+    return lay.take(out, lay.heads), lse.reshape(lay.batch * lay.heads, lay.seq)
 
-    return _unfold(out, b, h), lse.reshape(b * h, t)
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def pallas_flash_attention_qkv_fwd(
+    qkv: jax.Array,
+    mask: jax.Array | None = None,
+    *,
+    causal: bool = True,
+    block_q: int = 256,
+    block_k: int = 256,
+    interpret: bool = False,
+    window: int = 0,
+) -> tuple[jax.Array, jax.Array]:
+    """``pallas_flash_attention_fwd`` over the qkv projection's own output,
+    (B, T, 3, H, D): the kernel reads q, k and v as the three column ranges
+    of the one ``(B, T, 3*H*D)`` array, so no slice of it is ever made.
+    A shape without whole lane blocks (``lane_block_heads``) is sliced and
+    handed over apart."""
+    b, t, _, h, d = qkv.shape
+    tiles = dict(causal=causal, block_q=block_q, block_k=block_k,
+                 interpret=interpret, window=window)
+    if lane_block_heads(h, h, d) is None:
+        return pallas_flash_attention_fwd(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], mask, **tiles)
+    lay = _handoff((b, t, h, d), (b, t, h, d), fused=True)
+    merged = lay.hand(qkv)
+    out, lse = _forward(lay, merged, merged, merged, mask, **tiles)
+    return lay.take(out, h), lse.reshape(b * h, t)
 
 
 def pallas_flash_attention(
@@ -449,46 +629,57 @@ def pallas_flash_attention(
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, *rest,
-    block_k: int, scale: float, fold_scale: bool, causal: bool, masked: bool,
-    window: int = 0,
+    q_ref, k_ref, v_ref, do_ref, o_ref, l_ref, *rest,
+    hpb: int, block_k: int, scale: float, fold_scale: bool, causal: bool,
+    masked: bool, window: int = 0,
 ):
-    """dQ for one q-block against the K/V of its head (the forward's
-    schedule).
+    """dQ for one q-block against the K/V of its head block (the forward's
+    schedule), and D = rowsum(dO * O) of its rows, per head, for itself and
+    for the dk/dv kernel.
 
-    Ref shapes: q/do/dq (1, BQ, D), k/v (1, T, D), l/d (1, 1, BQ),
+    Ref shapes: q/do/o/dq (1, BQ, W), k/v (1, T, W), l/d (1, hpb, 1, BQ),
     optional mask (1, 1, T) + its q-block view (1, 1, BQ) ahead of the
-    output when ``masked`` (segment semantics — see ``_flash_kernel``);
-    scratch acc (BQ, D) float32. ``l`` / ``d`` turn from lane-major rows
-    into sublane-major columns once, before the loops.
+    outputs (dq, then d) when ``masked`` (segment semantics — see
+    ``_flash_kernel``); scratch acc (BQ, W) float32. ``l`` turns from a
+    lane-major row into a sublane-major column once, before the loops; ``d``
+    is born a column (a row reduction leaves it so) and leaves as a row.
     """
     if masked:
-        mask_ref, mask_q_ref, dq_ref, acc_s = rest
+        mask_ref, mask_q_ref, dq_ref, d_ref, acc_s = rest
     else:
-        dq_ref, acc_s = rest
+        dq_ref, d_ref, acc_s = rest
     block_q = q_ref.shape[1]
     lanes = _stat_lanes(block_q, block_k)
-    q0 = pl.program_id(1) * block_q
+    q0 = pl.program_id(2) * block_q
 
-    q = q_ref[0] * scale if fold_scale else q_ref[0]  # (BQ, D)
+    q = q_ref[0] * scale if fold_scale else q_ref[0]  # (BQ, W)
+    qs = _split(q, hpb)
+    dos = _split(do_ref[0], hpb)
     score_scale = None if fold_scale else scale
-    do = do_ref[0]
-    lse = _to_col(l_ref[0], lanes)  # (BQ, lanes)
-    delta = _to_col(d_ref[0], lanes)  # rowsum(dO * O)
+    lse = [_to_col(l_ref[0, j], lanes) for j in range(hpb)]  # (BQ, lanes)
+    delta = [
+        jnp.broadcast_to(part.sum(axis=1, keepdims=True), (block_q, lanes))
+        for part in _split(do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32), hpb)
+    ]
+    for j in range(hpb):
+        d_ref[0, j] = _to_row(delta[j])
     if masked:
         q_seg = _to_col(mask_q_ref[0], lanes)
     acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def step(keys, width, r0, rows, offset, diag, win):
+    def step(keys, tile, r0, rows, offset, diag, win):
         k_blk = k_ref[0, keys, :]
         v_blk = v_ref[0, keys, :]
         segments = (q_seg[r0:r0 + rows], mask_ref[0, :, keys]) if masked else None
-        s = _scores(q[r0:r0 + rows], k_blk, score_scale, offset, diag=diag,
-                    window=win, segments=segments)  # (rows, width)
-        p = jnp.exp(s - _bcast(lse[r0:r0 + rows], width))
-        dp = _dot(do[r0:r0 + rows], v_blk, _NT)
-        ds = p * (dp - _bcast(delta[r0:r0 + rows], width))
-        acc_s[pl.ds(r0, rows), :] += _dot(ds.astype(k_blk.dtype), k_blk, _NN)
+        parts = []
+        for j in range(hpb):
+            s = _scores(qs[j][r0:r0 + rows], k_blk, score_scale, offset, diag=diag,
+                        window=win, segments=segments)  # (rows, tile)
+            p = jnp.exp(s - _bcast(lse[j][r0:r0 + rows], tile))
+            dp = _dot(dos[j][r0:r0 + rows], v_blk, _NT)
+            ds = p * (dp - _bcast(delta[j][r0:r0 + rows], tile))
+            parts.append(_dot(ds.astype(k_blk.dtype), k_blk, _NN))
+        acc_s[pl.ds(r0, rows), :] += _join(parts)
 
     _query_block_schedule(
         q0, block_q, block_k, k_ref.shape[1], causal=causal, window=window,
@@ -499,73 +690,236 @@ def _bwd_dq_kernel(
 
 def _bwd_dkdv_kernel(
     q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, *rest,
-    block_q: int, scale: float, fold_scale: bool, causal: bool, masked: bool,
-    window: int = 0,
+    hpb: int, fused: bool, block_q: int, scale: float, fold_scale: bool,
+    causal: bool, masked: bool, window: int = 0,
 ):
-    """dK/dV for one (kv-head, k-block, group-member) grid point, walking
-    that query head's Q/dO/L/D from the causal diagonal down with the
+    """dK/dV for one (kv-head block, k-block, visit) grid point, walking
+    the query head block's Q/dO/L/D from the causal diagonal down with the
     scores TRANSPOSED: keys on rows, queries on lanes.
 
-    Ref shapes: k/v/dk/dv (1, BK, D), q/do (1, T, D), l/d (1, 1, T),
+    Ref shapes: k/v/dk/dv (1, BK, W), q/do (1, T, W), l/d (1, hpb, 1, T),
     optional mask (1, 1, BK) + the full-length mask (1, 1, T) for the
     streamed queries' segments, ahead of the outputs when ``masked``
-    (segment semantics — see ``_flash_kernel``); scratch dk/dv (BK, D)
-    float32. The query group (G = n_heads // n_kv_heads, 1 for classic
-    MHA) is the INNERMOST grid dimension: the scratch accumulates across
-    the G consecutive visits and the output block is written on the last,
-    in the K/V dtype — VMEM stays O(T·D) however large the group (MQA
-    makes G = n_heads).
+    (segment semantics — see ``_flash_kernel``); scratch dk/dv (BK, W)
+    float32. The INNERMOST grid dimension is the visits of one output
+    block. Apart (``fused`` false) they are the query group (G = n_heads //
+    n_kv_heads, 1 for classic MHA): the scratch accumulates across the G
+    consecutive visits and dk and dv, two arrays, are written on the last,
+    in the K/V dtype — VMEM stays O(T·W) however large the group (MQA
+    makes G = n_heads). Fused, dk and dv are two column ranges of the ONE
+    gradient array the dq call began (``rest`` then starts with that array,
+    aliased to the output and never read): visit 0 computes both and
+    writes dk's block, visit 1 (the output block index has moved to v's
+    columns, no input's has) writes dv's from the scratch.
     """
+    if fused:
+        rest = rest[1:]
     if masked:
-        mask_ref, mask_q_ref, dk_ref, dv_ref, dk_s, dv_s = rest
+        mask_ref, mask_q_ref, *rest = rest
+    if fused:
+        out_ref, dk_s, dv_s = rest
     else:
         dk_ref, dv_ref, dk_s, dv_s = rest
     block_k = k_ref.shape[1]
-    k0 = pl.program_id(1) * block_k
-    g = pl.program_id(2)
+    k0 = pl.program_id(2) * block_k
+    visit = pl.program_id(3)
 
-    k_blk = k_ref[0]  # (BK, D)
-    v_blk = v_ref[0]
-    k_scaled = k_blk * scale if fold_scale else k_blk
-    score_scale = None if fold_scale else scale
-    if masked:
-        k_seg = _to_col(mask_ref[0], _stat_lanes(block_k, block_q))  # (BK, lanes)
+    def accumulate():
+        k_blk = k_ref[0]  # (BK, W)
+        ks = _split(k_blk * scale if fold_scale else k_blk, hpb)
+        vs = _split(v_ref[0], hpb)
+        score_scale = None if fold_scale else scale
+        if masked:
+            k_seg = _to_col(mask_ref[0], _stat_lanes(block_k, block_q))  # (BK, lanes)
 
-    @pl.when(g == 0)
+        def step(queries, tile, rows, offset, diag, win):
+            q_blk = q_ref[0, queries, :]  # (tile, W)
+            do_blk = do_ref[0, queries, :]
+            segments = (k_seg[:rows], mask_q_ref[0, :, queries]) if masked else None
+            dvs, dks = [], []
+            for j in range(hpb):
+                st = _scores(ks[j][:rows], q_blk, score_scale, offset, diag=diag,
+                             window=win, segments=segments, transposed=True)  # s^T
+                pt = jnp.exp(st - l_ref[0, j, :, queries])  # (rows, tile)
+                dvs.append(_dot(pt.astype(do_blk.dtype), do_blk, _NN))
+                dpt = _dot(vs[j][:rows], do_blk, _NT)
+                dst = pt * (dpt - d_ref[0, j, :, queries])
+                dks.append(_dot(dst.astype(q_blk.dtype), q_blk, _NN))
+            dv_s[pl.ds(0, rows), :] += _join(dvs)
+            dk_s[pl.ds(0, rows), :] += _join(dks)
+
+        _key_block_schedule(
+            k0, block_k, block_q, q_ref.shape[1], causal=causal, window=window,
+            strip=_BWD_STRIP, step=step,
+        )
+
+    @pl.when(visit == 0)
     def _zero_init():
         dk_s[...] = jnp.zeros(dk_s.shape, jnp.float32)
         dv_s[...] = jnp.zeros(dv_s.shape, jnp.float32)
 
-    def step(queries, width, rows, offset, diag, win):
-        q_blk = q_ref[0, queries, :]  # (width, D)
-        do_blk = do_ref[0, queries, :]
-        segments = (k_seg[:rows], mask_q_ref[0, :, queries]) if masked else None
-        st = _scores(k_scaled[:rows], q_blk, score_scale, offset, diag=diag,
-                     window=win, segments=segments, transposed=True)  # s^T
-        pt = jnp.exp(st - l_ref[0, :, queries])  # (rows, width)
-        dv_s[pl.ds(0, rows), :] += _dot(pt.astype(do_blk.dtype), do_blk, _NN)
-        dpt = _dot(v_blk[:rows], do_blk, _NT)
-        dst = pt * (dpt - d_ref[0, :, queries])
-        dk_s[pl.ds(0, rows), :] += _dot(dst.astype(q_blk.dtype), q_blk, _NN)
+    if fused:
+        @pl.when(visit == 0)
+        def _dk():
+            accumulate()
+            out_ref[0] = (dk_s[...] * scale).astype(out_ref.dtype)
 
-    _key_block_schedule(
-        k0, block_k, block_q, q_ref.shape[1], causal=causal, window=window,
-        strip=_BWD_STRIP, step=step,
+        @pl.when(visit == 1)
+        def _dv():
+            out_ref[0] = dv_s[...].astype(out_ref.dtype)
+    else:
+        accumulate()
+
+        @pl.when(visit == pl.num_programs(3) - 1)
+        def _write():
+            dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _backward(
+    lay: _Handoff, q, k, v, out, lse, do, mask, *, causal, block_q, block_k,
+    dkdv_block_q, dkdv_block_k, interpret, window,
+):
+    """The two backward calls on arrays already in ``lay``'s hand-off:
+    ``out`` / ``do`` are the forward's output and its cotangent, ``lse`` is
+    (B*H, T). Returns ``(dq, dk, dv)`` shaped ``lay.shape(...)``, or the one
+    ``(B, T, 3*H*D)`` gradient when fused."""
+    b, t, h, d, hpb, w = lay.batch, lay.seq, lay.heads, lay.head_dim, lay.hpb, lay.width
+    group = h // lay.kv_heads
+    block_q, block_k = _check_blocks(t, block_q, block_k)
+    kv_block_q, kv_block_k = _check_blocks(
+        t, dkdv_block_q or block_q, dkdv_block_k or block_k
     )
+    _check_window(window, causal)
+    scale = 1.0 / math.sqrt(d)
+    fold_scale = _fold_scale(scale, q.dtype)
+    masked = mask is not None
+    mask_arr = _mask3(mask)
+    rows = pl.BlockSpec((1, block_q, w), lambda bi, hb, qi: lay.at(0, bi, hb, qi))
+    stats = pl.BlockSpec((1, hpb, 1, block_q), lambda bi, hb, qi: (bi, hb, 0, qi))
+    # lse and delta travel as (B, H, 1, T) so their (1, hpb, 1, block)
+    # specs tile legally.
+    lse4 = lse.reshape(b, h, 1, t)
+    seq_specs = [
+        rows,  # q
+        pl.BlockSpec((1, t, w), lambda bi, hb, qi: lay.at(1, bi, hb // group, 0)),  # k
+        pl.BlockSpec((1, t, w), lambda bi, hb, qi: lay.at(2, bi, hb // group, 0)),  # v
+        rows,  # do
+        rows,  # out
+        stats,  # lse
+    ]
+    dq_operands = [q, k, v, do, out, lse4]
+    if masked:
+        seq_specs.append(pl.BlockSpec((1, 1, t), lambda bi, hb, qi: (bi, 0, 0)))
+        dq_operands.append(mask_arr)
+        # Same mask, q-block tiled (the queries' segment ids).
+        seq_specs.append(pl.BlockSpec((1, 1, block_q), lambda bi, hb, qi: (bi, 0, qi)))
+        dq_operands.append(mask_arr)
+    # Fused, dq is the first third of the columns of the one gradient
+    # array; the dk/dv call below fills the rest in place.
+    dq, delta4 = pl.pallas_call(
+        functools.partial(
+            _bwd_dq_kernel, hpb=hpb, block_k=block_k, scale=scale,
+            fold_scale=fold_scale, causal=causal, masked=masked, window=window,
+        ),
+        grid=(b, lay.q_blocks, t // block_q),
+        in_specs=seq_specs,
+        out_specs=[rows, stats],
+        out_shape=[
+            jax.ShapeDtypeStruct(lay.shape(3 * h if lay.fused else h), q.dtype),
+            jax.ShapeDtypeStruct(lse4.shape, jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")
+        ),
+        interpret=interpret,
+        name="flash_attention_bwd_dq",
+    )(*dq_operands)
 
-    @pl.when(g == pl.num_programs(2) - 1)
-    def _write():
-        dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+    # dk/dv grid over (batch, kv-head block, k-block, visit). The visits of
+    # one output block are innermost so it stays resident across them:
+    # apart, the G members of the query group (head block r * G + m);
+    # fused (G == 1), dk's columns then dv's. ``held`` says whose inputs a
+    # grid step holds, as (batch, kv-head block, k-block, q head block): its
+    # own, except that the fused call's second visit, which only copies the
+    # scratch out and reads no input, already holds the NEXT program's, so
+    # that the pipeline fetches them under this program's products and not
+    # under that copy (1.75 -> 1.38 ms a call at the train cell's shape:
+    # PERF.md section 6, PR 44).
+    n_r, n_k = lay.kv_blocks, t // kv_block_k
+
+    def held(bi, r, ki, m):
+        if not lay.fused:
+            return bi, r, ki, r * group + m
+        step = jnp.minimum((bi * n_r + r) * n_k + ki + m, b * n_r * n_k - 1)
+        return step // (n_r * n_k), step // n_k % n_r, step % n_k, step // n_k % n_r
+
+    def spec(block, index):
+        return pl.BlockSpec(block, lambda *ids: index(*held(*ids)))
+
+    kv_specs = [
+        spec((1, t, w), lambda bi, r, ki, qb: lay.at(0, bi, qb, 0)),  # q
+        spec((1, kv_block_k, w), lambda bi, r, ki, qb: lay.at(1, bi, r, ki)),  # k
+        spec((1, kv_block_k, w), lambda bi, r, ki, qb: lay.at(2, bi, r, ki)),  # v
+        spec((1, t, w), lambda bi, r, ki, qb: lay.at(0, bi, qb, 0)),  # do
+        spec((1, hpb, 1, t), lambda bi, r, ki, qb: (bi, qb, 0, 0)),  # lse
+        spec((1, hpb, 1, t), lambda bi, r, ki, qb: (bi, qb, 0, 0)),  # delta
+    ]
+    dkdv_operands = [q, k, v, do, lse4, delta4]
+    if lay.fused:
+        kv_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        dkdv_operands.append(dq)
+    if masked:
+        kv_specs.append(spec((1, 1, kv_block_k), lambda bi, r, ki, qb: (bi, 0, ki)))
+        dkdv_operands.append(mask_arr)
+        # Full-length mask for the streamed queries' segment ids.
+        kv_specs.append(spec((1, 1, t), lambda bi, r, ki, qb: (bi, 0, 0)))
+        dkdv_operands.append(mask_arr)
+    if lay.fused:
+        out_specs = pl.BlockSpec(
+            (1, kv_block_k, w), lambda bi, r, ki, m: lay.at(1 + m, bi, r, ki)
+        )
+        out_shape = jax.ShapeDtypeStruct(dq.shape, dq.dtype)
+    else:
+        out_specs = [
+            pl.BlockSpec((1, kv_block_k, w), lambda bi, r, ki, m: lay.at(1, bi, r, ki)),
+            pl.BlockSpec((1, kv_block_k, w), lambda bi, r, ki, m: lay.at(2, bi, r, ki)),
+        ]
+        out_shape = [
+            jax.ShapeDtypeStruct(lay.shape(lay.kv_heads), k.dtype),
+            jax.ShapeDtypeStruct(lay.shape(lay.kv_heads), v.dtype),
+        ]
+    dkdv = pl.pallas_call(
+        functools.partial(
+            _bwd_dkdv_kernel, hpb=hpb, fused=lay.fused, block_q=kv_block_q,
+            scale=scale, fold_scale=fold_scale, causal=causal, masked=masked,
+            window=window,
+        ),
+        grid=(b, lay.kv_blocks, t // kv_block_k, 2 if lay.fused else group),
+        in_specs=kv_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        input_output_aliases={6: 0} if lay.fused else {},
+        scratch_shapes=[
+            pltpu.VMEM((kv_block_k, w), jnp.float32),
+            pltpu.VMEM((kv_block_k, w), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name="flash_attention_bwd_dkdv",
+    )(*dkdv_operands)
+    if lay.fused:
+        return dkdv
+    return (dq, *dkdv)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "causal", "block_q", "block_k", "dkdv_block_q", "dkdv_block_k",
-        "interpret", "window",
-    ),
-)
+_BWD_STATIC = _STATIC + ("dkdv_block_q", "dkdv_block_k")
+
+
+@functools.partial(jax.jit, static_argnames=_BWD_STATIC)
 def pallas_flash_attention_bwd(
     q: jax.Array,
     k: jax.Array,
@@ -593,115 +947,49 @@ def pallas_flash_attention_bwd(
     mirroring FlashAttention-2's backward. ``block_q`` / ``block_k`` tile
     the dq kernel (q rows resident, keys streamed) and, unless
     ``dkdv_block_q`` / ``dkdv_block_k`` say otherwise, the dk/dv kernel
-    (keys resident, queries streamed).
+    (keys resident, queries streamed). The hand-off is the forward's.
     """
-    b, t, h, d = q.shape
-    hkv = k.shape[2]
-    group = _head_groups(h, hkv)
-    block_q, block_k = _check_blocks(t, block_q, block_k)
-    kv_block_q, kv_block_k = _check_blocks(
-        t, dkdv_block_q or block_q, dkdv_block_k or block_k
+    lay = _handoff(q.shape, k.shape)
+    dq, dk, dv = _backward(
+        lay, lay.hand(q), lay.hand(k), lay.hand(v), lay.hand(out), lse, lay.hand(g), mask,
+        causal=causal, block_q=block_q, block_k=block_k,
+        dkdv_block_q=dkdv_block_q, dkdv_block_k=dkdv_block_k,
+        interpret=interpret, window=window,
     )
-    _check_window(window, causal)
+    return lay.take(dq, lay.heads), lay.take(dk, lay.kv_heads), lay.take(dv, lay.kv_heads)
 
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    of, gf = _fold(out), _fold(g)
-    scale = 1.0 / math.sqrt(d)
-    fold_scale = _fold_scale(scale, q.dtype)
-    kv_row = _kv_index(h, hkv)
-    masked = mask is not None
-    mask_arr = _mask3(mask)
 
-    # D = rowsum(dO * O): one cheap fused elementwise+reduce in XLA. lse and
-    # delta travel as (BH, 1, T) so their (1, 1, block) specs tile legally.
-    delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32), axis=-1)
-    lse3 = lse.reshape(b * h, 1, t)
-    delta3 = delta.reshape(b * h, 1, t)
-
-    seq_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),  # q
-        pl.BlockSpec((1, t, d), lambda bh, qi: (kv_row(bh), 0, 0)),  # k
-        pl.BlockSpec((1, t, d), lambda bh, qi: (kv_row(bh), 0, 0)),  # v
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),  # do
-        pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),  # lse
-        pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh, 0, qi)),  # delta
-    ]
-    dq_operands = [qf, kf, vf, gf, lse3, delta3]
-    if masked:
-        seq_specs.append(pl.BlockSpec((1, 1, t), lambda bh, qi: (bh // h, 0, 0)))
-        dq_operands.append(mask_arr)
-        # Same mask, q-block tiled (the queries' segment ids).
-        seq_specs.append(
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi: (bh // h, 0, qi))
+@functools.partial(jax.jit, static_argnames=_BWD_STATIC)
+def pallas_flash_attention_qkv_bwd(
+    qkv: jax.Array,
+    out: jax.Array,
+    lse: jax.Array,
+    g: jax.Array,
+    mask: jax.Array | None = None,
+    *,
+    causal: bool = True,
+    block_q: int = 256,
+    block_k: int = 256,
+    dkdv_block_q: int | None = None,
+    dkdv_block_k: int | None = None,
+    interpret: bool = False,
+    window: int = 0,
+) -> jax.Array:
+    """``pallas_flash_attention_bwd`` for ``pallas_flash_attention_qkv_fwd``:
+    the gradient of the (B, T, 3, H, D) qkv array as ONE such array. The dq
+    call writes its first third, the dk/dv call the other two in place
+    (``input_output_aliases``), so nothing is concatenated afterwards
+    (where the forward sliced, the three gradients are stacked)."""
+    b, t, _, h, d = qkv.shape
+    tiles = dict(causal=causal, block_q=block_q, block_k=block_k,
+                 dkdv_block_q=dkdv_block_q, dkdv_block_k=dkdv_block_k,
+                 interpret=interpret, window=window)
+    if lane_block_heads(h, h, d) is None:
+        grads = pallas_flash_attention_bwd(
+            qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], out, lse, g, mask, **tiles
         )
-        dq_operands.append(mask_arr)
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, block_k=block_k, scale=scale, fold_scale=fold_scale,
-            causal=causal, masked=masked, window=window,
-        ),
-        grid=(b * h, t // block_q),
-        in_specs=seq_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")
-        ),
-        interpret=interpret,
-        name="flash_attention_bwd_dq",
-    )(*dq_operands)
-
-    # dk/dv grid over (batch*kv_head, k-block, group-member). The group is
-    # innermost so the (1, BK, D) output block stays resident across the G
-    # visits; head g of kv-head j in batch b_i is folded-q row
-    # b_i*h + j*G + g.
-    def _q_row(r, g):
-        return (r // hkv) * h + (r % hkv) * group + g
-
-    kv_specs = [
-        pl.BlockSpec((1, t, d), lambda r, ki, g: (_q_row(r, g), 0, 0)),  # q
-        pl.BlockSpec((1, kv_block_k, d), lambda r, ki, g: (r, ki, 0)),  # k
-        pl.BlockSpec((1, kv_block_k, d), lambda r, ki, g: (r, ki, 0)),  # v
-        pl.BlockSpec((1, t, d), lambda r, ki, g: (_q_row(r, g), 0, 0)),  # do
-        pl.BlockSpec((1, 1, t), lambda r, ki, g: (_q_row(r, g), 0, 0)),  # lse
-        pl.BlockSpec((1, 1, t), lambda r, ki, g: (_q_row(r, g), 0, 0)),  # delta
-    ]
-    dkdv_operands = [qf, kf, vf, gf, lse3, delta3]
-    if masked:
-        kv_specs.append(
-            pl.BlockSpec((1, 1, kv_block_k), lambda r, ki, g: (r // hkv, 0, ki))
-        )
-        dkdv_operands.append(mask_arr)
-        # Full-length mask for the streamed queries' segment ids.
-        kv_specs.append(
-            pl.BlockSpec((1, 1, t), lambda r, ki, g: (r // hkv, 0, 0))
-        )
-        dkdv_operands.append(mask_arr)
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkdv_kernel, block_q=kv_block_q, scale=scale,
-            fold_scale=fold_scale, causal=causal, masked=masked, window=window,
-        ),
-        grid=(b * hkv, t // kv_block_k, group),
-        in_specs=kv_specs,
-        out_specs=[
-            pl.BlockSpec((1, kv_block_k, d), lambda r, ki, g: (r, ki, 0)),
-            pl.BlockSpec((1, kv_block_k, d), lambda r, ki, g: (r, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * hkv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((b * hkv, t, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((kv_block_k, d), jnp.float32),
-            pltpu.VMEM((kv_block_k, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        ),
-        interpret=interpret,
-        name="flash_attention_bwd_dkdv",
-    )(*dkdv_operands)
-
-    return _unfold(dq, b, h), _unfold(dk, b, hkv), _unfold(dv, b, hkv)
+        return jnp.stack(grads, axis=2)
+    lay = _handoff((b, t, h, d), (b, t, h, d), fused=True)
+    merged = lay.hand(qkv)
+    dqkv = _backward(lay, merged, merged, merged, lay.hand(out), lse, lay.hand(g), mask, **tiles)
+    return dqkv.reshape(qkv.shape)

@@ -259,10 +259,11 @@ class TestGroupedQueryAttention:
             np.asarray(a[:, : t + 1]), np.asarray(b[:, : t + 1]), atol=1e-6
         )
 
-    @pytest.mark.parametrize("kvh", [1, 2], ids=["mqa", "gqa2"])
+    @pytest.mark.parametrize("kvh", [1, 2, 0], ids=["mqa", "gqa2", "mha"])
     def test_flash_route_matches_dense(self, kvh):
         """The flash path consumes narrow K/V natively (no jnp.repeat in
-        the model); logits equal the dense-attention route."""
+        the model), and under MHA the fused projection's output whole;
+        logits and parameter gradients equal the dense-attention route."""
         dense = self._model(kvh)
         params = self._params(dense)
         flash = self._model(kvh, attention="flash")
@@ -270,6 +271,36 @@ class TestGroupedQueryAttention:
         a = dense.apply({"params": params}, ids, deterministic=True)
         b = flash.apply({"params": params}, ids, deterministic=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+        def loss(model):
+            return lambda p: jnp.sum(model.apply({"params": p}, ids, deterministic=True) ** 2)
+
+        ga, gb = jax.grad(loss(dense))(params), jax.grad(loss(flash))(params)
+        for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(ga), jax.tree.leaves(gb)):
+            np.testing.assert_allclose(
+                np.asarray(x), np.asarray(y), atol=2e-3, rtol=1e-4, err_msg=str(path)
+            )
+
+    @pytest.mark.parametrize("kvh", [0, 2], ids=["mha", "gqa2"])
+    def test_flash_route_keeps_the_parameter_tree_value_for_value(self, kvh):
+        """The flash branch forms its projections as one matrix product
+        each (``RowsDenseGeneral``); what it initialises is what
+        ``nn.DenseGeneral`` initialises on every other branch: the same
+        names, shapes, partitioning and values, so a checkpoint of one
+        loads into the other."""
+        import flax.linen as nn
+
+        ids = jnp.zeros((1, 16), jnp.int32)
+        stock = self._model(kvh).init(jax.random.key(3), ids, deterministic=True)
+        rows = self._model(kvh, attention="flash").init(
+            jax.random.key(3), ids, deterministic=True
+        )
+        assert nn.get_partition_spec(stock) == nn.get_partition_spec(rows)
+        a, b = nn.meta.unbox(stock), nn.meta.unbox(rows)
+        assert jax.tree.structure(a) == jax.tree.structure(b)
+        for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a), jax.tree.leaves(b)):
+            assert x.shape == y.shape and x.dtype == y.dtype, path
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y), err_msg=str(path))
 
     @pytest.mark.parametrize("kvh", [2, 0], ids=["gqa2", "mha"])
     def test_flash_route_applies_padding_mask(self, kvh):

@@ -221,16 +221,39 @@ class TestTiledKernels:
         "f32-window-unequal": ("float32", 8, 2, 2, 27, False, (16, 32), (16, 32), (32, 16)),
         "f32-segments": ("float32", 8, 2, 2, 0, True, (32, 16), (32, 16), (16, 32)),
         "bf16-gqa-window-segments": ("bfloat16", 16, 4, 2, 40, True, (32, 16), (32, 16), (16, 32)),
+        # Shapes with whole lane blocks: the kernels index (B, T, H*D) in
+        # place, two 64-wide heads a block or one head of a multiple of 128.
+        "f32-rows-d64-mha": ("float32", 64, 4, 4, 0, False, (32, 16), (32, 16), (16, 32)),
+        "bf16-rows-d64-mha": ("bfloat16", 64, 2, 2, 0, False, (32, 32), (32, 16), (16, 32)),
+        "f32-rows-d128-gqa": ("float32", 128, 4, 2, 0, False, (32, 16), (32, 16), (16, 32)),
+        "f32-rows-d64-mask": ("float32", 64, 2, 2, 0, "pad", (32, 16), (32, 16), (16, 32)),
+        "f32-rows-d64-segments": ("float32", 64, 2, 2, 0, True, (32, 16), (16, 32), (32, 16)),
+        "f32-rows-d64-window": ("float32", 64, 2, 2, 27, False, (16, 32), (16, 32), (32, 16)),
+        "bf16-rows-d128-mqa-window-segments": (
+            "bfloat16", 128, 2, 1, 40, True, (32, 16), (32, 16), (16, 32)),
+        # ...and 64-wide shapes without them, which keep the folded arrays.
+        "f32-folded-d64-gqa": ("float32", 64, 4, 2, 0, False, (32, 16), (32, 16), (16, 32)),
+        "f32-folded-d64-odd-heads": ("float32", 64, 3, 3, 0, True, (32, 16), (32, 16), (16, 32)),
+    }
+    # Heads a lane block holds in the cases that have lane blocks.
+    ROWS = {
+        "f32-rows-d64-mha": 2, "bf16-rows-d64-mha": 2, "f32-rows-d128-gqa": 1,
+        "f32-rows-d64-mask": 2, "f32-rows-d64-segments": 2, "f32-rows-d64-window": 2,
+        "bf16-rows-d128-mqa-window-segments": 1,
     }
 
     @pytest.mark.parametrize("case", list(CASES))
     def test_matches_dense(self, strip8, case):
         from llmtrain_tpu.ops.pallas_attention import (
+            lane_block_heads,
             pallas_flash_attention_bwd,
             pallas_flash_attention_fwd,
+            pallas_flash_attention_qkv_bwd,
+            pallas_flash_attention_qkv_fwd,
         )
 
         dtype, d, h, hkv, window, masked, fwd, dq_t, dkdv = self.CASES[case]
+        assert lane_block_heads(h, hkv, d) == self.ROWS.get(case)
         dtype = jnp.dtype(dtype)
         b, t = 2, self.T
         keys = jax.random.split(jax.random.key(17), 4)
@@ -239,6 +262,8 @@ class TestTiledKernels:
         v = jax.random.normal(keys[2], (b, t, hkv, d), dtype)
         g = jax.random.normal(keys[3], (b, t, h, d), dtype)
         mask = self._segments(b, t) if masked else None
+        if masked == "pad":  # a plain key-padding mask: one document, then padding
+            mask = jnp.asarray(np.arange(t)[None, :] < np.array([[t], [t - 37]]), jnp.int32)
         if masked:  # the model zeroes padded rows' output, so their cotangent
             g = g * (mask != 0)[:, :, None, None].astype(dtype)
 
@@ -274,6 +299,45 @@ class TestTiledKernels:
         np.testing.assert_allclose(f32(dq), f32(rq), **grad_tol)
         np.testing.assert_allclose(f32(dk), f32(rk), **grad_tol)
         np.testing.assert_allclose(f32(dv), f32(rv), **grad_tol)
+        if case not in self.ROWS or h != hkv:
+            return
+        # The same kernels reading q, k and v out of the ONE projection
+        # output and writing ONE gradient: the same numbers, bit for bit.
+        qkv = jnp.stack([q, k, v], axis=2)
+        out2, lse2 = pallas_flash_attention_qkv_fwd(
+            qkv, mask, block_q=fwd[0], block_k=fwd[1], window=window, interpret=True,
+        )
+        dqkv = pallas_flash_attention_qkv_bwd(
+            qkv, out2, lse2, g, mask, block_q=dq_t[0], block_k=dq_t[1],
+            dkdv_block_q=dkdv[0], dkdv_block_k=dkdv[1], window=window,
+            interpret=True,
+        )
+        assert dqkv.shape == qkv.shape and dqkv.dtype == dtype
+        np.testing.assert_array_equal(f32(out2), f32(out))
+        np.testing.assert_array_equal(np.asarray(lse2), np.asarray(lse))
+        for i, want in enumerate((dq, dk, dv)):
+            np.testing.assert_array_equal(f32(dqkv[:, :, i]), f32(want))
+
+    def test_a_fused_array_without_lane_blocks_is_sliced_and_its_gradients_stacked(self):
+        from llmtrain_tpu.ops.pallas_attention import (
+            lane_block_heads,
+            pallas_flash_attention_bwd,
+            pallas_flash_attention_fwd,
+            pallas_flash_attention_qkv_bwd,
+            pallas_flash_attention_qkv_fwd,
+        )
+
+        assert lane_block_heads(3, 3, 8) is None
+        qkv = jnp.stack(_qkv(b=1, t=16, h=3, d=8, seed=29), axis=2)
+        g = jax.random.normal(jax.random.key(30), (1, 16, 3, 8), jnp.float32)
+        tiles = dict(block_q=8, block_k=8, interpret=True)
+        out, lse = pallas_flash_attention_qkv_fwd(qkv, **tiles)
+        dqkv = pallas_flash_attention_qkv_bwd(qkv, out, lse, g, **tiles)
+        q, k, v = (qkv[:, :, i] for i in range(3))
+        want, want_lse = pallas_flash_attention_fwd(q, k, v, **tiles)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+        grads = pallas_flash_attention_bwd(q, k, v, want, want_lse, g, **tiles)
+        np.testing.assert_array_equal(np.asarray(dqkv), np.asarray(jnp.stack(grads, axis=2)))
 
     def test_auto_block_tiles_are_legal_for_the_schedules(self):
         """Whatever ``_auto_block`` picks divides T, and the statistics keep
@@ -296,6 +360,63 @@ class TestFlashDispatch:
         g = jax.grad(lambda q: flash_attention(q, k, v).sum())(q)
         g_ref = jax.grad(lambda q: _dense_ref(q, k, v).sum())(q)
         np.testing.assert_allclose(np.asarray(g), np.asarray(g_ref), atol=1e-4)
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+    @pytest.mark.parametrize("window", [0, 5], ids=["full", "window"])
+    def test_the_fused_projection_output_gives_what_its_slices_give(self, masked, window):
+        """``flash_attention_qkv`` on the (B, T, 3, H, D) array against
+        ``flash_attention`` on its three slices: output and the gradient of
+        the whole array (off the chip both are the blockwise twin)."""
+        from llmtrain_tpu.ops.flash_attention import flash_attention_qkv
+
+        qkv = jnp.stack(_qkv(t=16, seed=21), axis=2)
+        mask = jnp.asarray([[1] * 16, [1] * 11 + [0] * 5], jnp.int32) if masked else None
+        live = 1.0 if mask is None else mask[:, :, None, None].astype(jnp.float32)
+
+        def fused(qkv):
+            return flash_attention_qkv(qkv, attention_mask=mask, window=window) * live
+
+        def apart(qkv):
+            return flash_attention(
+                qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2], attention_mask=mask, window=window
+            ) * live
+
+        np.testing.assert_allclose(np.asarray(fused(qkv)), np.asarray(apart(qkv)), atol=1e-6)
+        got = jax.grad(lambda x: jnp.sum(fused(x) ** 2))(qkv)
+        want = jax.grad(lambda x: jnp.sum(apart(x) ** 2))(qkv)
+        assert got.shape == qkv.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["plain", "masked"])
+    def test_on_a_mesh_each_device_runs_its_own_batch_rows_and_heads(self, masked):
+        """Under ``{data: 2, tensor: 2}`` the call wraps itself in
+        ``shard_map`` (batch rows over data, heads over tensor, for q, k, v
+        apart and for the fused array's head axis alike) and gives what the
+        unsharded call gives, gradients included."""
+        from llmtrain_tpu.config.schemas import MeshConfig
+        from llmtrain_tpu.distributed import build_mesh
+        from llmtrain_tpu.ops.flash_attention import flash_attention_qkv
+
+        mesh = build_mesh(MeshConfig(data=2, tensor=2), jax.devices()[:4])
+        qkv = jnp.stack(_qkv(b=4, t=16, h=4, seed=23), axis=2)
+        mask = None
+        if masked:
+            mask = jnp.asarray(np.arange(16)[None, :] < np.array([16, 9, 16, 12])[:, None], jnp.int32)
+        live = 1.0 if mask is None else mask[:, :, None, None].astype(jnp.float32)
+
+        def fused(x):
+            return jnp.sum((flash_attention_qkv(x, attention_mask=mask) * live) ** 2)
+
+        def apart(x):
+            out = flash_attention(x[:, :, 0], x[:, :, 1], x[:, :, 2], attention_mask=mask)
+            return jnp.sum((out * live) ** 2)
+
+        want, want_grad = jax.value_and_grad(fused)(qkv)
+        for loss in (fused, apart):
+            with mesh:
+                got, got_grad = jax.jit(jax.value_and_grad(loss))(qkv)
+            np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+            np.testing.assert_allclose(np.asarray(got_grad), np.asarray(want_grad), atol=1e-5)
 
     def test_all_ones_mask_matches_unmasked(self):
         q, k, v = _qkv(t=16)

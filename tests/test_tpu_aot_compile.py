@@ -147,8 +147,9 @@ class TestFlashAttentionAtTheCellsShape:
         assert _custom_call_names(text) == {
             "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
         }
-        # The residual between forward and backward is one float32 a row...
-        assert re.search(r"f32\[384,(1,)?1024\]", text)
+        # The residual between forward and backward is one float32 a row
+        # and head...
+        assert re.search(r"f32\[32,12,1,1024\]", text)
         # ...and the lane-replicated (rows, 128) statistics never leave VMEM.
         assert not re.search(r"f32\[[\d,]*1024,128\]", text)
 
@@ -165,6 +166,119 @@ class TestFlashAttentionAtTheCellsShape:
         assert _custom_call_names(self._grad_text(one_chip, **shape)) == {
             "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
         }
+
+
+class TestAttentionBlockHandsOffRows:
+    """``jax.grad`` of the GPT-2-small block's attention, projections
+    included, at the train cell's shape: between the projections' matmuls
+    and the three kernels no array of an activation's size is copied,
+    transposed, sliced or concatenated, forward or backward (the parent paid
+    twelve 50 MB ``copy``s a layer and micro-batch there, 6.3% of the step).
+    The kernels read q, k and v out of the qkv projection's own
+    ``(B, T, 3*H*D)`` output and write one gradient of that shape."""
+
+    B, T, H, D = 32, 1024, 12, 64
+
+    @classmethod
+    def _grad_text(cls, sharding, params_sharding=None, *, b=B, h=H, d=D, **module):
+        import flax.linen as nn
+
+        from llmtrain_tpu.models.gpt import CausalSelfAttention
+
+        d_model = h * d
+        attn = CausalSelfAttention(
+            d_model=d_model, n_heads=h, n_layers=12, dropout=0.0, attention="flash",
+            dtype=jnp.bfloat16, param_dtype=jnp.float32, assume_packed=True, **module,
+        )
+        x = jax.ShapeDtypeStruct((b, cls.T, d_model), jnp.bfloat16, sharding=sharding)
+        boxed = jax.eval_shape(
+            lambda: attn.init(jax.random.key(0), jnp.zeros((1, 128, d_model), jnp.bfloat16))
+        )
+        placed = params_sharding(boxed) if params_sharding else jax.tree.map(
+            lambda _: sharding, nn.meta.unbox(boxed)
+        )
+        params = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            nn.meta.unbox(boxed), placed,
+        )
+
+        def loss(p, x, weight):
+            return jnp.sum((attn.apply(p, x) * weight).astype(jnp.float32))
+
+        return _compile(jax.grad(loss, argnums=(0, 1)), params, x, x)
+
+    @classmethod
+    def _moved_activations(cls, text: str, elements: int):
+        """Instructions outside fusions that only move ``elements`` values or
+        more: what a transposing ``copy`` is, or a slice or concatenation of
+        the projection's output."""
+        movers = {"copy", "transpose", "slice", "concatenate", "dynamic-slice", "pad"}
+        found = []
+        for _, body in _unfused_computations(text):
+            for op, type_text, _, line in _hlo_instructions(body):
+                if op in movers and _elements(type_text) >= elements:
+                    found.append(line.strip()[:160])
+        return found
+
+    def test_no_activation_sized_copy_on_either_side_of_the_three_kernels(self, one_chip):
+        text = self._grad_text(one_chip)
+        assert _custom_call_names(text) == {
+            "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
+        }
+        assert self._moved_activations(text, self.B * self.T * self.H * self.D) == []
+        # Nothing head-major or folded is left, in any order of its axes...
+        assert not re.search(
+            r"bf16\[(32,12,1024,64|32,1024,12,64|384,1024,64)\]\S* (copy|transpose)\(", text
+        )
+        # ...the forward reads the projection's output whole, and the two
+        # backward kernels write ONE gradient of its shape, in place.
+        assert re.search(
+            r"flash_attention_fwd[\w.]* = [^\n]*operand_layout_constraints=\{bf16\[32,1024,2304\]", text
+        )
+        assert re.search(r"flash_attention_bwd_dkdv[\w.]* = bf16\[32,1024,2304\]", text)
+
+    def test_a_rotated_block_with_grouped_heads_of_128_hands_the_kernels_rows(self, one_chip):
+        """The llama shape: RoPE on q and k, 4 K/V heads under 8 query heads
+        of 128. The kernels take q, k and v apart as ``(B, T, H*D)`` rows, a
+        query head's block mapped to its group's K/V block. (The rotation
+        itself is still re-laid-out by the compiler around its ``(..., 2,
+        D/2)`` shape, as at the parent: ``PERF.md`` section 7.)"""
+        text = self._grad_text(
+            one_chip, b=8, h=8, d=128, n_kv_heads=4, rope=True, use_bias=False
+        )
+        assert _custom_call_names(text) == {
+            "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
+        }
+        assert re.search(
+            r"flash_attention_fwd[\w.]* = [^\n]*operand_layout_constraints=\{"
+            r"bf16\[8,1024,1024\]\{2,1,0\}, bf16\[8,1024,512\]\{2,1,0\}, bf16\[8,1024,512\]",
+            text,
+        )
+
+    def test_heads_split_over_a_tensor_axis_still_find_whole_lane_blocks(self, topo):
+        """``{data: 2, tensor: 2}``: each chip's kernels see its own six
+        heads as three lane blocks of the local ``(B/2, T, 3*6*64)`` array."""
+        import flax.linen as nn
+
+        from llmtrain_tpu.distributed import MESH_AXES
+        from llmtrain_tpu.parallel.sharding import DEFAULT_LOGICAL_AXIS_RULES
+
+        mesh = Mesh(np.array(topo.devices).reshape(2, 1, 2, 1, 1, 1), MESH_AXES)
+
+        def place(boxed):
+            with nn.logical_axis_rules(DEFAULT_LOGICAL_AXIS_RULES):
+                specs = nn.logical_to_mesh(nn.get_partition_spec(boxed))
+            return jax.tree.map(lambda spec: NamedSharding(mesh, spec), specs)
+
+        with mesh, nn.logical_axis_rules(DEFAULT_LOGICAL_AXIS_RULES):
+            text = self._grad_text(NamedSharding(mesh, P("data")), place)
+        assert _custom_call_names(text) == {
+            "flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkdv",
+        }
+        assert re.search(
+            r"flash_attention_fwd[\w.]* = [^\n]*operand_layout_constraints=\{bf16\[16,1024,1152\]", text
+        )
+        assert re.search(r"flash_attention_bwd_dkdv[\w.]* = bf16\[16,1024,1152\]", text)
 
 
 class TestFusedCEOneChip:

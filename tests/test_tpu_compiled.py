@@ -390,6 +390,98 @@ class TestCompiledBackward:
         )
 
 
+class TestCompiledHandOffs:
+    """The three hand-offs between the projections and the kernels, compiled
+    and run through the dispatch (so with the tiles ``_auto_block`` picks):
+    ``(B, T, H*D)`` arrays indexed in place, two 64-wide heads a lane block
+    or one head of 128; the fused qkv projection output read as three column
+    ranges with ONE gradient array; and the folded ``(B*H, T, D)`` arrays a
+    64-wide head under GQA keeps. Output and gradients against the dense
+    reference on the same inputs, two runs bit for bit the same, and the
+    fused array's numbers bit for bit those of its slices."""
+
+    T = 512
+    CASES = {
+        # name: (h, hkv, d, window, mask: None / "pad" / "segments", heads a lane block)
+        "d64-mha": (4, 4, 64, 0, None, 2),
+        "d128-gqa": (4, 2, 128, 0, None, 1),
+        "d64-mask": (2, 2, 64, 0, "pad", 2),
+        "d64-segments": (2, 2, 64, 0, "segments", 2),
+        "d64-window": (2, 2, 64, 200, None, 2),
+        "d128-mqa-window-segments": (2, 1, 128, 200, "segments", 1),
+        "d64-gqa-folded": (4, 2, 64, 0, None, None),
+    }
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_matches_dense_and_repeats_bit_for_bit(self, case, dtype):
+        from llmtrain_tpu.models.gpt import dense_attention
+        from llmtrain_tpu.ops.flash_attention import flash_attention, flash_attention_qkv
+        from llmtrain_tpu.ops.pallas_attention import lane_block_heads
+
+        h, hkv, d, window, masking, heads_a_block = self.CASES[case]
+        assert lane_block_heads(h, hkv, d) == heads_a_block
+        dtype = jnp.dtype(dtype)
+        b, t = 2, self.T
+        ks = jax.random.split(jax.random.key(31), 4)
+        q = jax.random.normal(ks[0], (b, t, h, d), dtype)
+        k = jax.random.normal(ks[1], (b, t, hkv, d), dtype)
+        v = jax.random.normal(ks[2], (b, t, hkv, d), dtype)
+        g = jax.random.normal(ks[3], (b, t, h, d), dtype)
+        mask = None
+        if masking == "pad":
+            mask = jnp.asarray(np.arange(t)[None, :] < np.array([[t], [t - 150]]), jnp.int32)
+        elif masking == "segments":
+            seg = np.zeros((b, t), np.int32)
+            seg[:, :170], seg[:, 170:t - 70] = 1, 2
+            seg[0, :] = 1
+            mask = jnp.asarray(seg)
+        if mask is not None:
+            g = g * (mask != 0)[:, :, None, None].astype(dtype)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, attention_mask=mask, window=window)
+
+        def dense(q, k, v):
+            wide = lambda x: jnp.repeat(x, h // hkv, axis=2)  # noqa: E731
+            return dense_attention(q, wide(k), wide(v), attention_mask=mask, window=window)
+
+        f32 = lambda x: np.asarray(jax.device_get(x), np.float32)  # noqa: E731
+        # Full float32 MXU passes for float32 operands on both sides (the
+        # chip's default multiplies in bf16); bf16 operands go as they are.
+        precision = "highest" if dtype == jnp.float32 else None
+        with jax.default_matmul_precision(precision):
+            out, vjp = jax.vjp(flash, q, k, v)
+            grads = vjp(g)
+            again, vjp2 = jax.vjp(flash, q, k, v)
+            grads2 = vjp2(g)
+            ref, ref_vjp = jax.vjp(dense, q, k, v)
+            want = ref_vjp(g)
+        live = 1.0 if mask is None else f32(mask != 0)[:, :, None, None]
+        if dtype == jnp.float32:
+            fwd_tol, grad_tol = dict(atol=1e-4), dict(atol=1e-3)
+        else:
+            fwd_tol, grad_tol = dict(atol=2e-2), dict(atol=0.1, rtol=0.1)
+        np.testing.assert_allclose(f32(out) * live, f32(ref) * live, **fwd_tol)
+        np.testing.assert_array_equal(f32(out), f32(again))
+        for got, twice, ref_g in zip(grads, grads2, want):
+            assert got.dtype == dtype
+            np.testing.assert_allclose(f32(got), f32(ref_g), **grad_tol)
+            np.testing.assert_array_equal(f32(got), f32(twice))
+        if h != hkv:
+            return
+        qkv = jnp.stack([q, k, v], axis=2)
+        with jax.default_matmul_precision(precision):
+            fused, fused_vjp = jax.vjp(
+                lambda x: flash_attention_qkv(x, attention_mask=mask, window=window), qkv
+            )
+            (dqkv,) = fused_vjp(g)
+        assert dqkv.shape == qkv.shape and dqkv.dtype == dtype
+        np.testing.assert_array_equal(f32(fused), f32(out))
+        for i, apart in enumerate(grads):
+            np.testing.assert_array_equal(f32(dqkv[:, :, i]), f32(apart))
+
+
 class TestCompiledTrainStep:
     def test_gpt_flash_train_step_runs(self):
         """One real optimizer step of the flagship GPT with attention=flash,
