@@ -1,4 +1,5 @@
-"""Rotary position embeddings (RoPE), half-split ("rotate_half") layout.
+"""Rotary position embeddings (RoPE): the half-split ("rotate_half") layout,
+and the interleaved one beside it.
 
 Llama-family models encode position by rotating query/key pairs instead
 of adding learned position embeddings (GPT, models/gpt.py:497-515). The
@@ -12,6 +13,14 @@ past ~256 positions) and the rotation is two fused elementwise multiplies
 — XLA folds it into the surrounding projection, so RoPE adds no HBM
 round-trip. Everything is shape-static under jit; the ``positions``
 operand may be a traced value (decode offsets the cache cursor).
+
+**Interleaved pairs** (``interleaved=True``; GPT-J's layout, Cohere's
+``position_embedding_type: rope_gptj``): pair ``i`` is dimensions ``(2i,
+2i + 1)`` where the half-split layout pairs ``(i, i + d/2)``. The angles
+are the same, only which two values turn together differs; the tables are
+each frequency twice in a row and the partner of a value is its neighbour.
+A checkpoint of one layout is a fixed permutation of the other's q/k
+columns, and nothing but this switch tells them apart.
 
 **A head that is rotated in part.** Latent attention (models/latent_moe.py)
 splits a head into a part that carries no position and a part that does:
@@ -79,13 +88,15 @@ def rope_angles(
     *,
     theta: float = 10000.0,
     inv_freq: jax.Array | None = None,
+    interleaved: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """(cos, sin) tables, each ``positions.shape + (head_dim,)`` in f32.
 
     ``positions``: integer array of absolute token positions (any shape;
     typically (T,) at train time, (t,) offset by the cache cursor at
     decode time). ``inv_freq`` (head_dim / 2,) replaces ``theta``'s own
-    frequencies (:func:`yarn_inv_freq`).
+    frequencies (:func:`yarn_inv_freq`). ``interleaved``: frequency ``i`` at
+    dimensions ``2i`` and ``2i + 1`` (else at ``i`` and ``i + head_dim / 2``).
     """
     if head_dim % 2 != 0:
         raise ValueError(f"RoPE needs an even head_dim, got {head_dim}")
@@ -93,13 +104,19 @@ def rope_angles(
         exponent = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
         inv_freq = 1.0 / (theta**exponent)  # (head_dim/2,)
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq  # (..., d/2)
-    emb = jnp.concatenate([freqs, freqs], axis=-1)  # (..., d)
+    emb = jnp.repeat(freqs, 2, axis=-1) if interleaved else jnp.concatenate([freqs, freqs], axis=-1)  # (..., d)
     return jnp.cos(emb), jnp.sin(emb)
 
 
 def _rotate_half(x: jax.Array) -> jax.Array:
     half = x.shape[-1] // 2
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+
+
+def _rotate_pairs(x: jax.Array) -> jax.Array:
+    """``(-x1, x0, -x3, x2, ...)``: each value's partner in its interleaved pair."""
+    pairs = x.reshape(*x.shape[:-1], -1, 2)
+    return jnp.stack([-pairs[..., 1], pairs[..., 0]], axis=-1).reshape(x.shape)
 
 
 def apply_rope(
@@ -109,6 +126,7 @@ def apply_rope(
     *,
     theta: float = 10000.0,
     inv_freq: jax.Array | None = None,
+    interleaved: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Rotate q and k by their absolute positions.
 
@@ -119,8 +137,10 @@ def apply_rope(
     (B, T) PER-ROW positions — paged decode batches sequences at
     different depths, so each row rotates by its own offsets.
     Rotation runs in f32 and casts back to the input dtype.
+    ``interleaved`` rotates pairs ``(2i, 2i + 1)`` (module docstring).
     """
-    cos, sin = rope_angles(positions, q.shape[-1], theta=theta, inv_freq=inv_freq)
+    cos, sin = rope_angles(positions, q.shape[-1], theta=theta, inv_freq=inv_freq, interleaved=interleaved)
+    partner = _rotate_pairs if interleaved else _rotate_half
     if positions.ndim == 1:
         cos = cos[None, :, None, :]  # (1, T, 1, Dh)
         sin = sin[None, :, None, :]
@@ -134,7 +154,7 @@ def apply_rope(
 
     def rot(x: jax.Array) -> jax.Array:
         xf = x.astype(jnp.float32)
-        return (xf * cos + _rotate_half(xf) * sin).astype(x.dtype)
+        return (xf * cos + partner(xf) * sin).astype(x.dtype)
 
     return rot(q), rot(k)
 

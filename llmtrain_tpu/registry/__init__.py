@@ -28,6 +28,7 @@ _PLUGIN_MODULES = (
     "llmtrain_tpu.models.falcon_h1",
     "llmtrain_tpu.models.latent_moe",
     "llmtrain_tpu.models.indexed_moe",
+    "llmtrain_tpu.models.windowed_moe",
     "llmtrain_tpu.data.dummy_text",
     "llmtrain_tpu.data.hf_text",
     "llmtrain_tpu.data.local_text",

@@ -69,6 +69,28 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   prefill ``index_pairs`` (sum over the slab's positions ``p`` of ``p + 1``:
   (query, position) pairs scored) and ``selected_pairs`` (sum of ``min(p +
   1, topk)``: pairs attended). Other models' spans are what they were.
+* **A second pool for window layers.** A model whose window layers need no
+  block that fell out of the window says so in ONE attribute,
+  ``paged_window`` (the window in positions; models/windowed_moe.py). The
+  engine then sizes a second pool of ``1 + slots * ring`` blocks (``ring =
+  ceil(window / block_tokens) + 1``; serving/paged_kv.py "window blocks"),
+  asks the model for leaves of that size (``for_paged_decoding(
+  window_num_blocks=)``; the window layers' leaves must NOT have the global
+  pool's block count), stages each row's ring table beside its block table
+  (``window_tables``) and hands prefill ``true_len`` (the model then returns
+  the last true position's logits alone). The ``stage`` span also carries,
+  from positions alone: of a decode ``kv_window_tokens`` (sum over rows of
+  ``min(live, window)``: what ONE window layer must read, beside
+  ``kv_live_tokens`` for one global layer), ``kv_window_gathered_tokens``
+  (batch bucket x ring x ``block_tokens``: what one window layer gathers,
+  beside ``kv_gathered_tokens`` for a global one) and ``window_blocks_bound`` /
+  ``global_blocks_bound`` (the pool's own accounting at that tick); of a
+  prefill ``window_pairs`` (sum over the true prompt's positions ``p`` of
+  ``min(p + 1, window)``) and ``causal_pairs`` (sum of ``p + 1``). What
+  would read a window layer's earlier keys through the tables is refused by
+  name: the prefix cache (and with it the COW copy), chunked prefill and
+  ``verify``. Other models' cache trees, programs and spans are what they
+  were.
 """
 
 from __future__ import annotations
@@ -83,7 +105,7 @@ import numpy as np
 
 from ..telemetry.timeline import process_span
 from ..utils.logging import get_logger
-from .paged_kv import PagedKVPool
+from .paged_kv import PagedKVPool, window_ring_blocks
 
 logger = get_logger()
 
@@ -181,6 +203,7 @@ def _prefill_impl(
     top_ks: jax.Array,
     top_ps: jax.Array,
     state_rows: jax.Array | None = None,  # (1,) int32, models with state leaves
+    window_tables: jax.Array | None = None,  # (1, ring) int32, models with window layers
 ) -> tuple[Any, jax.Array]:
     # `offsets` starts the row mid-sequence: 0 for a whole prompt, the
     # reused-prefix length under shared-prefix reuse, the chunk start
@@ -188,6 +211,9 @@ def _prefill_impl(
     # the block table (cached K/V), exactly like a multi-token decode.
     # A recurrent state has to be told where the padding starts too.
     state = {} if state_rows is None else {"state_rows": state_rows, "true_len": true_len}
+    if window_tables is not None:
+        # A window layer must not write the padding: it has to know where it starts.
+        state.update(window_tables=window_tables, true_len=true_len)
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
         prompt,
@@ -198,10 +224,15 @@ def _prefill_impl(
         **state,
     )
     # Sample at the LAST REAL position; padded positions' K/V landed in
-    # the null block and padded-row logits are garbage nobody reads.
-    last = jnp.take_along_axis(
-        logits.astype(jnp.float32), (true_len - 1)[:, None, None], axis=1
-    )[:, 0]
+    # the null block and padded-row logits are garbage nobody reads. A model
+    # with window layers was handed `true_len` above and returns that
+    # position's logits alone, (1, 1, vocab).
+    if window_tables is not None:
+        last = logits[:, 0].astype(jnp.float32)
+    else:
+        last = jnp.take_along_axis(
+            logits.astype(jnp.float32), (true_len - 1)[:, None, None], axis=1
+        )[:, 0]
     tok = _sample_rows(
         last, seeds, jnp.zeros_like(true_len), temps, top_ks, top_ps
     )
@@ -221,8 +252,11 @@ def _decode_impl(
     top_ks: jax.Array,
     top_ps: jax.Array,
     state_rows: jax.Array | None = None,  # (B,) int32, models with state leaves
+    window_tables: jax.Array | None = None,  # (B, ring) int32, models with window layers
 ) -> tuple[Any, jax.Array]:
     state = {} if state_rows is None else {"state_rows": state_rows}
+    if window_tables is not None:
+        state["window_tables"] = window_tables
     counts_experts = bool(getattr(model, "expert_layers", 0))
     logits, mutated = model.apply(
         {"params": params, "cache": cache},
@@ -354,12 +388,25 @@ class PagedDecodeEngine:
                 f"prompt bucket ({self.prompt_buckets[-1]}) — chunks must "
                 "pad into an existing bucket (the bounded-compile contract)"
             )
+        # Window layers (docstring: a second pool): every slot can hold a
+        # full ring, + null. 0 everywhere for a model without them.
+        self.window_tokens = int(getattr(model, "paged_window", 0))
+        self.window_ring = window_ring_blocks(self.window_tokens, self.block_tokens)
+        window_num_blocks = 1 + self.max_batch_slots * self.window_ring if self.window_tokens else 0
+        if self.window_tokens and self.prefill_chunk:
+            raise ValueError(
+                "chunked prefill cannot serve a model with window layers: a later "
+                "chunk would read earlier keys through the window table, and a "
+                "slab attends only its own keys (models/windowed_moe.py)"
+            )
+        window = {"window_num_blocks": window_num_blocks} if self.window_tokens else {}
         # One state row a slot plus the null row 0, offered to every model;
         # only one with a recurrent state declares leaves that use them.
         self.decode_model = model.for_paged_decoding(
             num_blocks=num_blocks,
             block_tokens=self.block_tokens,
             state_rows=1 + self.max_batch_slots,
+            **window,
         )
 
         # Zero cache pytree from an eval_shape trace — no param init work
@@ -373,6 +420,10 @@ class PagedDecodeEngine:
                 deterministic=True,
                 positions=jnp.zeros((1,), jnp.int32),
                 block_tables=jnp.zeros((1, mb), jnp.int32),
+                **(
+                    {"window_tables": jnp.zeros((1, self.window_ring), jnp.int32)}
+                    if self.window_tokens else {}
+                ),
             )
         )
         self._cache_struct = var_shapes["cache"]
@@ -393,6 +444,19 @@ class PagedDecodeEngine:
             math.prod(leaf.shape[1:]) * leaf.dtype.itemsize for leaf in state_leaves
         )
         self._state_leaves = len(state_leaves)
+        if self.window_tokens:
+            # Every pool leaf is sized by one of the two pools, and the
+            # window layers' by the window pool's.
+            blocks = sorted(
+                leaf.shape[0]
+                for path, leaf in jax.tree_util.tree_leaves_with_path(self._cache_struct)
+                if not is_state_leaf(path)
+            )
+            if set(blocks) != {num_blocks, window_num_blocks}:
+                raise ValueError(
+                    f"a model with window layers must hold leaves of the global pool ({num_blocks} "
+                    f"blocks) and of the window pool ({window_num_blocks}); its leaves lead with {blocks}"
+                )
         self._scan_chunk = int(getattr(self.decode_model, "state_scan_chunk", 0))
         self._counts_experts = bool(getattr(self.decode_model, "expert_layers", 0))
         self._selects = int(getattr(self.decode_model, "selects_positions", 0))
@@ -402,6 +466,8 @@ class PagedDecodeEngine:
             self.block_tokens,
             prefix_cache=prefix_cache,
             state_rows=self.max_batch_slots if state_leaves else 0,
+            window_tokens=self.window_tokens,
+            window_num_blocks=window_num_blocks,
         )
         with process_span("startup/pool", num_blocks=int(num_blocks)) as counted:
             self._cache = jax.tree.map(
@@ -420,11 +486,11 @@ class PagedDecodeEngine:
         # cache and `_cache_size()` count other engines' programs. A fresh
         # function object per engine keeps the compile accounting local
         # (and the closed-over model off the static-argument hash path).
-        def _prefill_bound(params: Any, cache: Any, *rest: Any) -> Any:
-            return _prefill_impl(self.decode_model, params, cache, *rest)
+        def _prefill_bound(params: Any, cache: Any, *rest: Any, **named: Any) -> Any:
+            return _prefill_impl(self.decode_model, params, cache, *rest, **named)
 
-        def _decode_bound(params: Any, cache: Any, *rest: Any) -> Any:
-            return _decode_impl(self.decode_model, params, cache, *rest)
+        def _decode_bound(params: Any, cache: Any, *rest: Any, **named: Any) -> Any:
+            return _decode_impl(self.decode_model, params, cache, *rest, **named)
 
         def _verify_bound(params: Any, cache: Any, *rest: Any) -> Any:
             return _verify_impl(self.decode_model, params, cache, *rest)
@@ -511,6 +577,7 @@ class PagedDecodeEngine:
         offset: int = 0,  # absolute position of prompt_ids[0]
         params: Any | None = None,  # hot-swap: admitted-epoch params
         state_row: int = 0,  # the sequence's state row (models with state)
+        window_table: list[int] | None = None,  # the sequence's ring (models with window layers)
     ) -> int:
         """Run one joining sequence's prompt slab; returns the token
         sampled at its last real position (the first output token when
@@ -529,6 +596,16 @@ class PagedDecodeEngine:
                 seen = np.arange(int(offset), int(offset) + tp, dtype=np.int64) + 1  # positions a query may see
                 counted["index_pairs"] = int(seen.sum())
                 counted["selected_pairs"] = int(np.minimum(seen, self._selects).sum())
+            if self.window_tokens:
+                if offset:
+                    raise ValueError(
+                        "a model with window layers prefills a prompt whole, from position 0 "
+                        f"(got offset {offset}): a slab attends only its own keys"
+                    )
+                seen = np.arange(1, tp + 1, dtype=np.int64)  # keys a causal query at p sees: p + 1
+                counted["causal_pairs"] = int(seen.sum())
+                counted["window_pairs"] = int(np.minimum(seen, self.window_tokens).sum())
+            named = {}
             with self._span("stage", "prefill", **counted):
                 prompt = np.zeros((1, tb), np.int32)
                 prompt[0, :tp] = prompt_ids
@@ -544,10 +621,12 @@ class PagedDecodeEngine:
                 )
                 if self.state_bytes_per_row:
                     staged += (jnp.asarray([int(state_row)], jnp.int32),)
+                if self.window_tokens:
+                    named["window_tables"] = jnp.asarray([window_table], jnp.int32)
             try:
                 with self._span("dispatch", "prefill"):
                     cache, tok = self._prefill_jit(
-                        self.params if params is None else params, self._cache, *staged
+                        self.params if params is None else params, self._cache, *staged, **named
                     )
             except Exception:
                 self._recover_cache_after_error()
@@ -563,8 +642,9 @@ class PagedDecodeEngine:
 
         Each row dict: ``token`` (last emitted), ``position`` (its
         absolute position), ``table`` (padded physical ids), ``seed``,
-        ``emit_idx``, ``temperature``, ``top_k``, ``top_p``, and for a
-        model with state leaves ``state_row``. The batch is padded to a
+        ``emit_idx``, ``temperature``, ``top_k``, ``top_p``, for a
+        model with state leaves ``state_row`` and for one with window layers
+        ``window_table`` (the padded ring). The batch is padded to a
         batch bucket with null-table, null-state-row greedy rows whose
         output is discarded.
         """
@@ -594,6 +674,12 @@ class PagedDecodeEngine:
             if self._selects:
                 counted["kv_selected_tokens"] = sum(min(int(r["position"]) + 1, self._selects) for r in rows)
                 counted["rows_past_topk"] = sum(int(r["position"]) + 1 > self._selects for r in rows)
+            if self.window_tokens:
+                counted["kv_window_tokens"] = sum(min(int(r["position"]) + 1, self.window_tokens) for r in rows)
+                counted["kv_window_gathered_tokens"] = bb * self.window_ring * self.block_tokens
+                counted["window_blocks_bound"] = self.pool.window_allocated_blocks
+                counted["global_blocks_bound"] = self.pool.allocated_blocks
+            named = {}
             with self._span("stage", "decode", **counted):
                 tables = np.zeros((bb, mb), np.int32)
                 for i, r in enumerate(rows):
@@ -615,10 +701,15 @@ class PagedDecodeEngine:
                 )
                 if self.state_bytes_per_row:
                     staged += (jnp.asarray(col("state_row", 0, np.int32)),)
+                if self.window_tokens:
+                    rings = np.zeros((bb, self.window_ring), np.int32)
+                    for i, r in enumerate(rows):
+                        rings[i] = r["window_table"]
+                    named["window_tables"] = jnp.asarray(rings)
             try:
                 with self._span("dispatch", "decode"):
                     cache, tok = self._decode_jit(
-                        self.params if params is None else params, self._cache, *staged
+                        self.params if params is None else params, self._cache, *staged, **named
                     )
             except Exception:
                 self._recover_cache_after_error()
@@ -651,6 +742,12 @@ class PagedDecodeEngine:
                 "verify (speculative decoding) cannot serve a model with "
                 "recurrent state: rejected draft tokens would have moved the "
                 "state and there is no rollback of a state row yet"
+            )
+        if self.window_tokens:
+            raise ValueError(
+                "verify (speculative decoding) cannot serve a model with window "
+                "layers: a slab of draft tokens would read earlier keys through the "
+                "window table, and a rejected draft may already have reused a ring entry"
             )
         n = len(rows)
         if n == 0:
@@ -690,6 +787,11 @@ class PagedDecodeEngine:
         cache leaf. The pool's cow_last_shared() picks the pair; this is
         the write half of its contract (must run before the next pool
         mutation can recycle ``src``)."""
+        if self.window_tokens:
+            raise ValueError(
+                "cow_copy cannot serve a model with window layers: a block id names "
+                "a block of ONE of its two pools, and nothing shares a window block"
+            )
         self._cow_used = True
         with self._span("stage", "cow_copy"):
             staged = (jnp.asarray([src], jnp.int32), jnp.asarray([dst], jnp.int32))
@@ -767,6 +869,7 @@ class PagedDecodeEngine:
             sds((1,), jnp.int32),      # top_ks
             sds((1,), jnp.float32),    # top_ps
         ) + ((sds((1,), jnp.int32),) if self.state_bytes_per_row else ())
+        ring = self.window_ring
         decode_args = (
             param_structs,
             cache_structs,
@@ -780,10 +883,13 @@ class PagedDecodeEngine:
             sds((bb,), jnp.float32),   # top_ps
         ) + ((sds((bb,), jnp.int32),) if self.state_bytes_per_row else ())
         profiles: list[dict[str, Any]] = []
-        for name, jitted, args in (
-            (f"prefill_T{tb}", self._prefill_jit, prefill_args),
-            (f"decode_B{bb}", self._decode_jit, decode_args),
+        for name, jitted, args, rows in (
+            (f"prefill_T{tb}", self._prefill_jit, prefill_args, 1),
+            (f"decode_B{bb}", self._decode_jit, decode_args, bb),
         ):
+            if ring:  # the profilers take positional shapes: the ring tables go last
+                jitted = jax.jit(lambda *a, _f=jitted: _f(*a[:-1], window_tables=a[-1]))
+                args += (sds((rows, ring), jnp.int32),)
             if full:
                 prof = profiling.aot_profile(
                     jitted, args, name=name, peaks=peaks, top_k=top_k
@@ -831,6 +937,10 @@ class PagedDecodeEngine:
             stats["state_leaves"] = self._state_leaves
             stats["state_rows"] = self.pool.state_rows
             stats["state_bytes_per_row"] = self.state_bytes_per_row
+        if self.window_tokens:
+            stats["window_tokens"] = self.window_tokens
+            stats["window_ring_blocks"] = self.window_ring
+            stats["window_num_blocks"] = self.pool.window_num_blocks
         stats["within_budget"] = (
             stats["prefill_programs"]
             + stats["decode_programs"]

@@ -50,6 +50,29 @@ throughput/$ is decided):
   batch. Its contents are never cleared here: a prefill at offset 0 starts
   from zeros whatever the row held (the model's contract).
 
+* **window blocks** (only where the model has window layers,
+  ``window_tokens > 0``): a layer whose queries see the last
+  ``window_tokens`` positions only needs no block that has fallen wholly
+  out of the window. Such layers' leaves are a SECOND pool
+  (``window_num_blocks`` blocks, ids of their own, block 0 its null
+  block), and a sequence's window table is a RING of ``window_ring =
+  ceil(window_tokens / block_tokens) + 1`` entries: logical block ``b``
+  lives in entry ``b % window_ring``. The first ``window_ring`` logical
+  blocks bind a new window block each as the sequence reaches them
+  (lazily, like global blocks); from then on logical block ``b`` REUSES IN
+  PLACE the entry of block ``b - window_ring``, every position of which
+  lies ``window_tokens`` or more behind every query of block ``b``. So a
+  sequence never holds more than ``window_ring`` window blocks however
+  long it grows, its table does not change once it is full, and nothing is
+  freed and taken again a block later (the other way to do it: free the
+  oldest block and shift the table, which moves every entry of every
+  staged row each ``block_tokens`` steps for the same bound).
+  :meth:`try_reserve` reserves ``min(blocks needed, window_ring)`` window
+  blocks beside the global budget, both or neither; :meth:`release`
+  returns both. A token-block hash stands for the blocks of EVERY layer, and
+  a window layer has overwritten its own: the prefix cache is refused by
+  name with window blocks.
+
 Pure host-side Python (no jax): allocation is scheduler-thread-only and
 lock-free here — the scheduler serializes all calls.
 """
@@ -86,6 +109,13 @@ def chain_hashes(prompt_ids: Sequence[int], block_tokens: int) -> list[str]:
         )
         out.append(parent)
     return out
+
+
+def window_ring_blocks(window_tokens: int, block_tokens: int) -> int:
+    """Entries of a sequence's window table: the blocks a window of
+    ``window_tokens`` positions can touch, plus the one being written (0 for
+    no window). The one place the engine and the pool take it from."""
+    return -(-int(window_tokens) // int(block_tokens)) + 1 if window_tokens else 0
 
 
 @dataclass
@@ -128,6 +158,10 @@ class BlockTable:
     shared: int = 0
     # The sequence's recurrent-state row (0 = none: the null row).
     state_row: int = 0
+    # Window layers' blocks (ids of the window pool), entry b % ring holds
+    # logical block b: at most ``window_reserved`` of them, ever.
+    window_blocks: list[int] = field(default_factory=list)
+    window_reserved: int = 0
 
     @property
     def allocated(self) -> int:
@@ -142,6 +176,14 @@ class BlockTable:
                 f"({max_blocks})"
             )
         return self.blocks + [NULL_BLOCK] * (max_blocks - len(self.blocks))
+
+    def padded_window(self, ring: int) -> list[int]:
+        """The window ring padded with the null block to ``ring`` entries."""
+        if len(self.window_blocks) > ring:
+            raise ValueError(
+                f"window table holds {len(self.window_blocks)} blocks > ring ({ring})"
+            )
+        return self.window_blocks + [NULL_BLOCK] * (ring - len(self.window_blocks))
 
 
 class PagedKVPool:
@@ -160,6 +202,8 @@ class PagedKVPool:
         *,
         prefix_cache: bool = False,
         state_rows: int = 0,
+        window_tokens: int = 0,
+        window_num_blocks: int = 0,
     ) -> None:
         if num_blocks < 2:
             raise ValueError(
@@ -188,6 +232,29 @@ class PagedKVPool:
             )
         self.state_rows = int(state_rows)
         self._free_rows = list(range(self.state_rows, 0, -1))
+        # ---- window layers' pool (docstring: window blocks); LIFO, block 0
+        # excluded (its null block). Empty for a model without window layers.
+        if window_tokens < 0 or (window_tokens == 0) != (window_num_blocks == 0):
+            raise ValueError(
+                f"window_tokens ({window_tokens}) and window_num_blocks "
+                f"({window_num_blocks}) come together: both 0 or both > 0"
+            )
+        if window_tokens and window_num_blocks < 2:
+            raise ValueError(
+                f"window_num_blocks must be >= 2 (block 0 is the null block), got {window_num_blocks}"
+            )
+        if window_tokens and prefix_cache:
+            raise ValueError(
+                "prefix_cache cannot serve a model with window layers: a "
+                "token-block hash stands for a block of every layer, and a "
+                "window layer has reused the blocks that fell out of its window"
+            )
+        self.window_tokens = int(window_tokens)
+        self.window_num_blocks = int(window_num_blocks)
+        self.window_ring = window_ring_blocks(window_tokens, block_tokens)
+        self._window_free = list(range(self.window_num_blocks - 1, 0, -1))
+        self._window_available = max(0, self.window_num_blocks - 1)
+        self.peak_window_allocated = 0
         # ---- content-addressed prefix cache (docstring: shared prefixes)
         self.prefix_cache_enabled = bool(prefix_cache)
         self._index: dict[str, int] = {}  # chain hash -> physical block
@@ -235,6 +302,11 @@ class PagedKVPool:
         return (self.num_blocks - 1) - len(self._free) - len(self._evictable)
 
     @property
+    def window_allocated_blocks(self) -> int:
+        """Window blocks bound to a sequence right now."""
+        return max(0, self.window_num_blocks - 1) - len(self._window_free)
+
+    @property
     def cached_blocks(self) -> int:
         return len(self._evictable)
 
@@ -247,10 +319,18 @@ class PagedKVPool:
         have to evict mid-flight.
         """
         need = self.blocks_needed(total_tokens)
-        if need > self._available or (self.state_rows and not self._free_rows):
+        window_need = min(need, self.window_ring)
+        if (
+            need > self._available
+            or (self.state_rows and not self._free_rows)
+            or window_need > self._window_available
+        ):
             return None
         self._available -= need
-        table = BlockTable(reserved=need, block_tokens=self.block_tokens)
+        self._window_available -= window_need
+        table = BlockTable(
+            reserved=need, block_tokens=self.block_tokens, window_reserved=window_need
+        )
         if self.state_rows:
             table.state_row = self._free_rows.pop()
         self._tables.add(id(table))
@@ -303,6 +383,11 @@ class PagedKVPool:
         while table.allocated < need:
             table.blocks.append(self._take_block())
         self.peak_allocated = max(self.peak_allocated, self.allocated_blocks)
+        # Window layers: a new entry for each of the first `ring` logical
+        # blocks; block b >= ring reuses entry b % ring where it lies.
+        while len(table.window_blocks) < min(need, table.window_reserved):
+            table.window_blocks.append(self._window_free.pop())
+        self.peak_window_allocated = max(self.peak_window_allocated, self.window_allocated_blocks)
 
     def release(self, table: BlockTable) -> None:
         """Retire a sequence: free its owned blocks, unpin its shared
@@ -330,6 +415,10 @@ class PagedKVPool:
         if table.state_row:
             self._free_rows.append(table.state_row)
             table.state_row = 0
+        self._window_free.extend(table.window_blocks)
+        self._window_available += table.window_reserved
+        table.window_blocks = []
+        table.window_reserved = 0
         table.blocks = []
         table.reserved = 0
         table.shared = 0
@@ -526,6 +615,13 @@ class PagedKVPool:
         if self.state_rows:
             out["state_rows_free"] = len(self._free_rows)
             out["state_rows_in_use"] = self.state_rows - len(self._free_rows)
+        if self.window_tokens:
+            window_capacity = self.window_num_blocks - 1
+            out["window_ring_blocks"] = self.window_ring
+            out["window_capacity_blocks"] = window_capacity
+            out["window_allocated_blocks"] = self.window_allocated_blocks
+            out["window_reserved_blocks"] = window_capacity - self._window_available
+            out["window_peak_allocated_blocks"] = self.peak_window_allocated
         if self.prefix_cache_enabled:
             out["prefix_cached_blocks"] = self.cached_blocks
             out["prefix_hits"] = self.prefix_hits
@@ -549,4 +645,5 @@ __all__ = [
     "PrefixMatch",
     "chain_hashes",
     "hash_token_block",
+    "window_ring_blocks",
 ]

@@ -226,12 +226,22 @@ class ContinuousBatchingScheduler:
                 "the speculative policy cannot serve a model with recurrent "
                 "state (no rollback of a state row yet); use policy 'paged'"
             )
+        if policy == "speculative" and any(
+            getattr(eng, "window_tokens", 0) for eng in (engine, draft_engine)
+        ):
+            raise ValueError(
+                "the speculative policy cannot serve a model with window layers "
+                "(its verify slab would read earlier keys through the window "
+                "table); use policy 'paged'"
+            )
         if policy == "speculative" and engine is not None and engine.prefill_chunk:
             raise ValueError(
                 "chunked prefill is a paged-policy feature; the speculative "
                 "verify slab needs the whole prompt resident before drafting"
             )
         self.engine = engine
+        # Entries of a row's window table (0: the engine's model has no window layers).
+        self._window_ring = int(getattr(engine, "window_ring", 0))
         self.policy = policy
         self.registry = registry
         # Serving timeline: queue-wait/prefill/decode spans tagged with
@@ -675,6 +685,8 @@ class ContinuousBatchingScheduler:
         extra = {"rid": row.req.rid} if row.req.rid else {}
         # Only a table that owns a state row names one (paged_kv.py).
         state = {"state_row": row.table.state_row} if row.table.state_row else {}
+        if self._window_ring:
+            state["window_table"] = row.table.padded_window(self._window_ring)
         try:
             with self._traced_span(
                 row.req,
@@ -896,6 +908,8 @@ class ContinuousBatchingScheduler:
                             "state_row": r.table.state_row,
                         }
                     )
+                    if self._window_ring:
+                        rows[-1]["window_table"] = r.table.padded_window(self._window_ring)
                 rids = [r.req.rid for r in group if r.req.rid]
                 extra = {"rids": rids} if rids else {}
                 try:

@@ -1226,3 +1226,164 @@ class TestSelectionBehindTheEngine:
                 np.testing.assert_array_equal(np.asarray(leaf), after[name])
         profiles = engine.cost_profile(full=False)  # the AOT cost profile takes the three leaves as they are
         assert {p["name"] for p in profiles} == {"prefill_T32", "decode_B3"}
+
+
+# ---------------------------------------------------------------------------
+# window layers' ring beside the global pool (models/windowed_moe.py)
+# ---------------------------------------------------------------------------
+
+
+def _windowed_moe(**kw):
+    """4 layers (three window layers of 12 positions, then a global one), 4
+    query / 2 K/V heads of 8, 8 sigmoid-routed experts (3 a token) of which
+    this holder has experts 2-5, beside 2 averaged shared experts."""
+    from llmtrain_tpu.models.windowed_moe import WindowedMoE
+
+    base = dict(
+        vocab_size=VOCAB, block_size=64, d_model=32, n_layers=4, n_heads=4, num_key_value_heads=2, head_dim=8,
+        intermediate_size=16, num_experts=8, num_experts_per_tok=3, num_shared_experts=2, experts_held=(2, 4),
+        sliding_window=12, layer_types=("sliding_attention",) * 3 + ("full_attention",), rope_theta=50000.0,
+    )
+    return WindowedMoE(**{**base, **kw})
+
+
+@pytest.fixture(scope="module")
+def windowed_moe_model():
+    model = _windowed_moe()
+    return model, _shaken_params(model, 31)
+
+
+class TestWindowBlocks:
+    RING = 3  # ceil(12 / 8) + 1 blocks of 8 positions
+
+    def test_a_sequence_never_binds_more_than_its_ring_and_both_budgets_come_and_go_together(self):
+        pool = PagedKVPool(1 + 4 * 8, 8, window_tokens=12, window_num_blocks=1 + 4 * 3)
+        assert pool.window_ring == self.RING
+        start = pool.stats()
+        assert (start["window_capacity_blocks"], start["window_allocated_blocks"], start["window_reserved_blocks"]) == (12, 0, 0)
+        short, long = pool.try_reserve(10), pool.try_reserve(64)
+        # a short request reserves what it can reach, not a window's worth; a long one is capped at the ring
+        assert (short.reserved, short.window_reserved) == (2, 2) and (long.reserved, long.window_reserved) == (8, 3)
+        assert pool.stats()["window_reserved_blocks"] == 5 and pool.stats()["reserved_blocks"] == 10
+        for upto in range(1, 65):
+            pool.grow(long, upto)
+            assert len(long.window_blocks) == min(-(-upto // 8), self.RING)  # bound lazily, then no more, ever
+            assert len(long.blocks) == -(-upto // 8)
+        ring = list(long.window_blocks)
+        pool.grow(short, 10)
+        assert len(short.window_blocks) == 2 and not set(short.window_blocks) & set(ring)
+        assert long.padded_window(self.RING) == ring and short.padded_window(self.RING) == short.window_blocks + [0]
+        assert pool.stats()["window_allocated_blocks"] == 5 and pool.stats()["window_peak_allocated_blocks"] == 5
+        # reserve both or neither: the window pool is the one that runs out here (12 blocks, 5 + 3 + 3 reserved)
+        third, fourth = pool.try_reserve(64), pool.try_reserve(30)
+        assert third is not None and fourth is not None and pool.stats()["window_reserved_blocks"] == 11
+        before = pool.available_blocks
+        assert pool.try_reserve(20) is None and pool.available_blocks == before  # 2 window blocks wanted, 1 left
+        assert pool.try_reserve(8) is not None  # one of each is there
+        # the global pool running out refuses the window budget too
+        tight = PagedKVPool(1 + 4, 8, window_tokens=12, window_num_blocks=1 + 12)
+        assert tight.try_reserve(40) is None and tight.stats()["window_reserved_blocks"] == 0
+        # release returns both, and a drained pool is where it started
+        for table in (short, long, third, fourth):
+            pool.release(table)
+            assert table.window_blocks == [] and table.window_reserved == 0
+        pool.release(next(iter([t for t in [pool.try_reserve(8)] if t])))  # (and a fresh one goes round again)
+        assert pool.stats()["window_reserved_blocks"] == 1  # the table of 8 positions reserved above still holds its one
+        with pytest.raises(ValueError, match="released or foreign"):
+            pool.release(long)
+
+    def test_named_refusals_of_the_pool(self):
+        with pytest.raises(ValueError, match="prefix_cache cannot serve a model with window layers"):
+            PagedKVPool(64, 8, prefix_cache=True, window_tokens=12, window_num_blocks=13)
+        with pytest.raises(ValueError, match="come together"):
+            PagedKVPool(64, 8, window_tokens=12)
+        with pytest.raises(ValueError, match="come together"):
+            PagedKVPool(64, 8, window_num_blocks=13)
+        stats = PagedKVPool(8, 8).stats()  # a model without window layers: no second pool, no keys
+        assert not any(key.startswith("window_") for key in stats)
+        assert PagedKVPool(8, 8).try_reserve(8).window_blocks == []
+
+    def test_a_mixed_queue_is_served_as_the_full_forward_and_the_pools_return_to_their_start(self, windowed_moe_model):
+        """Eight requests on three slots, short and long in one queue:
+        prompts under, at and past the window of 12 (a prompt of 30 is
+        prefilled exactly from its own keys and leaves only its last ring in
+        the window leaves), answers that cross the window and wrap the ring
+        of 3 blocks several times; float32 throughout, so the served tokens
+        ARE the full forward's greedy tokens."""
+        from contextlib import nullcontext
+
+        model, params = windowed_moe_model
+        engine = _state_engine(model, params)
+        assert (engine.window_tokens, engine.window_ring, engine.pool.window_num_blocks) == (12, 3, 1 + 3 * 3)
+        leaves = {jax.tree_util.keystr(p): leaf.shape for p, leaf in jax.tree_util.tree_leaves_with_path(engine._cache)}
+        assert len(leaves) == 8  # K and V in each of four layers
+        # the window layers' leaves are sized by the window pool (3 slots x ring 3 + null), NOT by the global pool
+        assert {s for n, s in leaves.items() if "window_" in n} == {(10, 1, 8 * 16)}
+        assert {s for n, s in leaves.items() if "paged_" in n} == {(25, 1, 8 * 16)}
+        assert sum("window_" in n for n in leaves) == 6 and engine.compile_stats()["window_num_blocks"] == 10
+        start = engine.pool.stats()
+        spans = []
+        engine.span_factory = lambda n, **a: (spans.append((n, a)), nullcontext(a))[1]
+        scheduler = ContinuousBatchingScheduler(engine)
+        shapes = [(5, 6), (17, 9), (9, 30), (30, 12), (3, 40), (12, 5), (13, 20), (32, 32)]
+        reqs = _falcon_requests(np.random.default_rng(0), shapes)
+        for r in reqs:
+            scheduler.submit(r)
+        steps = 0
+        while not all(r.done.is_set() for r in reqs):
+            scheduler.step()
+            steps += 1
+            assert steps < 500
+            # never more than a ring a sequence, whatever it has grown to
+            assert engine.pool.window_allocated_blocks <= 3 * self.RING
+        for r in reqs:
+            assert r.finish_reason == "length", r.error
+            want, gap = _full_forward_tokens(model, params, r)
+            assert r.tokens == want and gap == 0.0
+        end = engine.pool.stats()
+        for key in ("allocated_blocks", "reserved_blocks", "window_allocated_blocks", "window_reserved_blocks"):
+            assert end[key] == start[key] == 0
+        assert end["window_peak_allocated_blocks"] == 3 * self.RING and engine.compile_stats()["within_budget"]
+        # What the window spares, from positions alone, on the stage span.
+        stage = [a for n, a in spans if n == "serve/engine.stage"]
+        prefill = sorted((a["prompt_tokens"], a["causal_pairs"], a["window_pairs"]) for a in stage if a["call"] == "prefill")
+        assert prefill == sorted((n, n * (n + 1) // 2, sum(min(p + 1, 12) for p in range(n))) for n, _ in shapes)
+        decode = [a for a in stage if a["call"] == "decode"]
+        assert decode and all(
+            {"kv_window_tokens", "window_blocks_bound", "global_blocks_bound", "kv_live_tokens"} <= set(a) for a in decode)
+        assert all(a["kv_window_tokens"] <= min(a["kv_live_tokens"], 3 * 12) for a in decode)
+        assert all(a["kv_window_gathered_tokens"] == 3 * self.RING * 8 < a["kv_gathered_tokens"] for a in decode)
+        assert any(a["kv_window_tokens"] < a["kv_live_tokens"] for a in decode)  # some rows lay past the window ...
+        assert all(a["window_blocks_bound"] <= min(a["global_blocks_bound"], 3 * self.RING) for a in decode)
+        assert any(a["window_blocks_bound"] < a["global_blocks_bound"] for a in decode)  # blocks really were spared
+        fetch = [a for n, a in spans if n == "serve/engine.fetch" and a["call"] == "decode"]
+        assert fetch and all({"expert_pairs", "experts_hit"} <= set(a) for a in fetch)  # the expert layers' counters ride along
+        profiles = engine.cost_profile(full=False)  # the AOT cost profile takes both tables
+        assert {p["name"] for p in profiles} == {"prefill_T32", "decode_B3"}
+
+    def test_what_would_read_a_window_layers_earlier_keys_is_refused_by_name(self, windowed_moe_model, latent_moe_model):
+        model, params = windowed_moe_model
+        with pytest.raises(ValueError, match="prefix_cache cannot serve a model with window layers"):
+            _state_engine(model, params, prefix_cache=True)
+        with pytest.raises(ValueError, match="chunked prefill cannot serve a model with window layers"):
+            _state_engine(model, params, prefill_chunk=8)
+        engine = _state_engine(model, params)
+        with pytest.raises(ValueError, match="verify .* cannot serve a model with window layers"):
+            engine.verify([{"tokens": [1, 2], "position": 0, "table": [0] * 8}], width=2)
+        with pytest.raises(ValueError, match="cow_copy cannot serve a model with window layers"):
+            engine.cow_copy(1, 2)
+        table = engine.pool.try_reserve(24)
+        engine.pool.grow(table, 9)
+        with pytest.raises(ValueError, match="prefills a prompt whole, from position 0"):
+            engine.prefill(np.arange(4, dtype=np.int32), table.padded(8), seed=0, temperature=0.0, top_k=None,
+                           top_p=None, offset=5, window_table=table.padded_window(3))
+        with pytest.raises(ValueError, match="speculative policy cannot serve a model with window layers"):
+            ContinuousBatchingScheduler(
+                engine, policy="speculative", model=model, params=params, draft_model=model, draft_params=params,
+                draft_engine=_state_engine(model, params))
+        # a model of this family with no window layer is one pool, as every family; so is every other model
+        plain = _windowed_moe(layer_types=("full_attention",) * 4)
+        one_pool = _state_engine(plain, _shaken_params(plain, 32))
+        assert one_pool.window_tokens == 0 and one_pool.pool.window_num_blocks == 0
+        assert "window_tokens" not in one_pool.compile_stats()
+        assert _state_engine(*latent_moe_model).window_ring == 0

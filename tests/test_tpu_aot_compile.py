@@ -788,13 +788,19 @@ class TestStateRowsUpdateInPlace:
 LATENT_SLOTS, LATENT_CONTEXT, LATENT_LAYERS = 96, 4096, 2
 
 
-def _cell_programs(one_chip, family: str, config: str, *, layers: int, slots: int, context: int, bucket: int):
+def _cell_programs(one_chip, family: str, config: str, *, layers: int, slots: int, context: int, bucket: int,
+                   overrides: dict | None = None, ring: int = 0, temporaries: dict | None = None):
     """``({"decode": text, "prefill": text}, [cache leaf shapes])``: the decode
     (``slots`` rows) and prefill (one prompt in ``bucket``) programs of a
     benchmark configuration at its published widths, its share of the experts
     and its slice of the vocabulary, ``slots`` x ``context`` positions,
     ``layers`` of its layers, as ``PagedDecodeEngine`` jits them; abstract
-    shapes only."""
+    shapes only. ``overrides`` replaces keys of the configuration; ``ring`` > 0
+    gives the model a window pool of ``slots`` rings and the programs the ring
+    tables as the engine's keyword; ``temporaries`` is filled with each
+    program's temporary bytes. The flash dispatch sees platform tpu while
+    this traces (a module's fixture is built before the test's own
+    ``_as_on_chip``), and nothing compiled here goes to the persistent cache."""
     import functools
     import importlib
     import json
@@ -812,6 +818,7 @@ def _cell_programs(one_chip, family: str, config: str, *, layers: int, slots: in
 
     cfg = json.loads((root / "benchmarks/configs" / config).read_text())
     cfg["num_hidden_layers"] = layers
+    cfg.update(overrides or {})
     initialize_registries()
     run = RunConfig.model_validate({
         "schema_version": 1, "run": {"name": "aot", "seed": 1, "device": "cpu"}, "model": ref.program_model(cfg),
@@ -820,17 +827,23 @@ def _cell_programs(one_chip, family: str, config: str, *, layers: int, slots: in
     })
     mb = context // POOL_BLOCK_TOKENS
     paged = build_adapter(run).build_model(run).for_paged_decoding(
-        num_blocks=1 + slots * mb, block_tokens=POOL_BLOCK_TOKENS
-    )
-    variables = jax.eval_shape(
-        lambda: paged.init(
-            jax.random.key(0), jnp.zeros((1, 1), jnp.int32), deterministic=True,
-            positions=jnp.zeros((1,), jnp.int32), block_tables=jnp.zeros((1, mb), jnp.int32),
-        )
+        num_blocks=1 + slots * mb, block_tokens=POOL_BLOCK_TOKENS,
+        **({"window_num_blocks": 1 + slots * ring} if ring else {}),
     )
 
     def on_chip(*shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def rings(rows):
+        return {"window_tables": on_chip(rows, ring)} if ring else {}
+
+    variables = jax.eval_shape(
+        lambda: paged.init(
+            jax.random.key(0), jnp.zeros((1, 1), jnp.int32), deterministic=True,
+            positions=jnp.zeros((1,), jnp.int32), block_tables=jnp.zeros((1, mb), jnp.int32),
+            **{k: jnp.zeros(v.shape, v.dtype) for k, v in rings(1).items()},
+        )
+    )
 
     # What the server holds: bf16 but the router, which the program declares float32.
     params = jax.tree.map(
@@ -846,13 +859,26 @@ def _cell_programs(one_chip, family: str, config: str, *, layers: int, slots: in
     seeds, *knobs = sampling(slots)
     shapes = {
         "decode": (functools.partial(engine._decode_impl, paged),
-                   (params, cache, on_chip(slots), on_chip(slots), on_chip(slots, mb), seeds, on_chip(slots), *knobs)),
+                   (params, cache, on_chip(slots), on_chip(slots), on_chip(slots, mb), seeds, on_chip(slots), *knobs),
+                   rings(slots)),
         "prefill": (functools.partial(engine._prefill_impl, paged),
-                    (params, cache, on_chip(1, bucket), on_chip(1), on_chip(1), on_chip(1, mb), *sampling(1))),
+                    (params, cache, on_chip(1, bucket), on_chip(1), on_chip(1), on_chip(1, mb), *sampling(1)),
+                    rings(1)),
     }
-    return {
-        name: jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text() for name, (fn, args) in shapes.items()
-    }, sorted(leaf.shape for leaf in jax.tree.leaves(cache))
+    texts = {}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            for name, (fn, args, named) in shapes.items():
+                compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args, **named).compile()
+                texts[name] = compiled.as_text()
+                if temporaries is not None:
+                    temporaries[name] = compiled.memory_analysis().temp_size_in_bytes
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+    return texts, sorted(leaf.shape for leaf in jax.tree.leaves(cache))
 
 
 @pytest.fixture(scope="module")
@@ -978,3 +1004,93 @@ class TestIndexedPoolKeepsItsLayout:
                 sizes = [int(d) for d in dims.split(",")]
                 assert not (INDEXED_BUCKET in sizes and INDEXED_CONTEXT in sizes), line.strip()[:300]
                 assert math.prod(sizes) <= 512 * 16 * INDEXED_CONTEXT or self.NUM_BLOCKS in sizes, line.strip()[:300]
+
+
+# -- window layers' ring beside the global pool (models/windowed_moe.py, PR 45) --
+
+WINDOWED_SLOTS, WINDOWED_CONTEXT, WINDOWED_BUCKET, WINDOW = 32, 8192, 6144, 4096
+WINDOWED_RING = WINDOW // POOL_BLOCK_TOKENS + 1  # 257
+WINDOWED_LAYERS = ("sliding_attention", "full_attention")
+
+
+@pytest.fixture(scope="module")
+def windowed_programs(one_chip):
+    """Decode (32 rows) and prefill (one prompt in the 6,144 bucket) of the
+    Command A+ configuration the benchmark runs, the cell's 32 slots x 8,192
+    positions, 2 layers (ONE window layer, ONE global layer):
+    ``({program: (text, temporary bytes)}, [cache leaf shapes])``."""
+    temporaries: dict = {}
+    texts, leaves = _cell_programs(
+        one_chip, "cohere2_moe", "command-a-plus.json", layers=len(WINDOWED_LAYERS), slots=WINDOWED_SLOTS,
+        context=WINDOWED_CONTEXT, bucket=WINDOWED_BUCKET, overrides={"layer_types": list(WINDOWED_LAYERS)},
+        ring=WINDOWED_RING, temporaries=temporaries,
+    )
+    return {name: (text, temporaries[name]) for name, text in texts.items()}, leaves
+
+
+class TestWindowRingKeepsItsLayout:
+    """Read before the first chip run of the window ring: the window layer's
+    K and V are leaves of the WINDOW pool's block count (32 x 257 + 1), not
+    the global pool's (32 x 512 + 1); all four leaves are row-major, donated
+    in place in one layout, and nothing as large as a leaf exists but the
+    leaf passed along and the scatter of the call's rows into it; the window
+    layer's decode gathers a ring's worth of positions a row (257 x 16 =
+    4,112), never the global table's 8,192; the prefill runs the Pallas flash
+    forward in both layers and holds no (queries, keys) score matrix; and the
+    prefill's temporaries stay under 3 GB beside 12.2 GB resident."""
+
+    GLOBAL_BLOCKS = 1 + WINDOWED_SLOTS * (WINDOWED_CONTEXT // POOL_BLOCK_TOKENS)  # 16,385
+    WINDOW_BLOCKS = 1 + WINDOWED_SLOTS * WINDOWED_RING  # 8,225
+
+    @pytest.mark.parametrize("program", ["decode", "prefill"])
+    def test_both_kinds_of_leaf_are_row_major_and_updated_in_place(self, windowed_programs, program):
+        texts, leaves = windowed_programs
+        assert leaves == sorted([(self.WINDOW_BLOCKS, 16, 1024)] * 2 + [(self.GLOBAL_BLOCKS, 16, 1024)] * 2)
+        text = texts[program][0]
+        params, results, aliased = _entry_layout(text)
+        for blocks in (self.WINDOW_BLOCKS, self.GLOBAL_BLOCKS):
+            pool = [i for i, p in enumerate(params) if f"[{blocks},16,1024]" in p]
+            assert len(pool) == 2  # K and V of the one layer of that kind
+            for i in pool:
+                assert "{2,1,0:" in params[i], f"a pool leaf is not row-major: {params[i]}"
+                assert i in aliased and results[aliased[i]] == params[i], "not donated in place in one layout"
+        sizes = {self.WINDOW_BLOCKS * 16 * 1024, self.GLOBAL_BLOCKS * 16 * 1024}
+        for op, result, called, line in _hlo_instructions(text):
+            if _elements(result) not in sizes:
+                continue
+            in_place = op in POOL_IN_PLACE or (
+                op == "fusion" and re.search(r" (scatter|dynamic-update-slice)\(", _computation(text, called))
+            )
+            assert in_place, f"pool-sized `{op}` in {program}: {line.strip()[:300]}"
+
+    def test_the_window_layers_gather_is_bounded_by_the_ring(self, windowed_programs):
+        decode = windowed_programs[0]["decode"][0]
+        ring_positions, table_positions = WINDOWED_RING * 16, WINDOWED_CONTEXT
+        by_scope = {"window_attention": set(), "global_attention": set()}
+        for _op, result, _called, line in _hlo_instructions(decode):
+            for scope, seen in by_scope.items():
+                if f"/{scope}/" in line:
+                    seen.update(int(d) for dims in re.findall(r"\w+\[([\d,]+)\]", result) for d in dims.split(","))
+        # the window layer reads 32 rows x 4,112 gathered positions and nothing of the table's length
+        assert ring_positions in by_scope["window_attention"] or WINDOWED_RING in by_scope["window_attention"]
+        assert table_positions not in by_scope["window_attention"]
+        assert table_positions in by_scope["global_attention"]  # the global layer gathers its whole table
+        assert "ragged-dot" in decode  # the held experts: grouped products over the sorted pairs
+
+    def test_prefill_attends_by_blocks_and_its_temporaries_fit(self, windowed_programs):
+        prefill, temporaries = windowed_programs[0]["prefill"]
+        # the Pallas flash forward once a layer, on the projections' own rows (grouped K/V read in place);
+        # the other custom calls are the held experts' grouped products
+        assert _custom_call_names(prefill) == {"flash_attention_fwd", "ragged-dot-metadata", "ragged-dot-none"}
+        assert len(re.findall(
+            r"%flash_attention_fwd[\w.]* = [^\n]*operand_layout_constraints=\{bf16\[1,6144,16384\]\{2,1,0\}, "
+            r"bf16\[1,6144,1024\]\{2,1,0\}, bf16\[1,6144,1024\]", prefill)) == len(WINDOWED_LAYERS)
+        for _op, result, _called, line in _hlo_instructions(prefill):
+            if "_attention/" not in line:
+                continue
+            for dims in re.findall(r"\w+\[([\d,]+)\]", result):
+                sizes = [int(d) for d in dims.split(",")]
+                assert sizes.count(WINDOWED_BUCKET) < 2, line.strip()[:300]  # no (queries, keys) scores
+        assert f"[1,{WINDOWED_BUCKET},32768]" not in prefill  # the head at the last true position alone
+        assert temporaries < 3e9, f"prefill temporaries {temporaries / 1e9:.2f} GB"
+        assert windowed_programs[0]["decode"][1] < 3e9

@@ -764,3 +764,83 @@ class TestCompiledRound5Serving:
             )(params, ids)
         )
         assert np.isfinite(np.asarray(logits, np.float32)).all()
+
+
+class TestCompiledWindowRing:
+    """Decode through the window layers' ring at the geometry a served cell
+    runs (a window of 4,096 over blocks of 16: a ring of 257 entries a row,
+    128-wide heads), on the chip: the benchmark's own ``correct`` cannot see
+    it (PERF.md section 6, PR 45: greedy tokens of random weights repeat with
+    a wide margin, and a rotated ring table passes its limits). Narrow widths,
+    float32 with full-precision products, queries and keys scaled up so that
+    attention is peaked and WHICH keys a row reads matters. Dense masked
+    scores on both sides: the Pallas forward's window is held by
+    TestCompiledSlidingWindow, and in float32 at 512 lanes a row its tiles
+    pass the scoped VMEM limit (17.59 MB of 16) at a slab of 4,096."""
+
+    WINDOW, BLOCK, STEPS = 4096, 16, 40
+    DEPTHS = ((100, 1536), (4090, 4096), (6000, 6144))  # (true prompt, its bucket): under, crossing, past the window
+
+    def _model(self, attention):
+        from llmtrain_tpu.models.windowed_moe import WindowedMoE
+
+        return WindowedMoE(
+            vocab_size=512, block_size=8192, d_model=256, n_layers=2, n_heads=4, num_key_value_heads=2, head_dim=128,
+            intermediate_size=128, num_experts=4, num_experts_per_tok=2, num_shared_experts=1,
+            sliding_window=self.WINDOW, layer_types=("sliding_attention", "full_attention"), rope_theta=50000.0,
+            attention=attention,
+        )
+
+    def test_decode_at_three_depths_matches_the_full_forward_and_a_rotated_ring_does_not(self):
+        from flax.core import meta as nn_meta
+
+        from llmtrain_tpu.serving.paged_kv import window_ring_blocks
+
+        dense = served = self._model("dense")
+        total = self.DEPTHS[-1][1]
+        ids = np.random.default_rng(45).integers(0, 512, (3, total)).astype(np.int32)
+        params = nn_meta.unbox(dense.init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+        for block in ("block_0", "block_1"):  # scores of a few units: a softmax that picks keys
+            for name in ("q_proj", "k_proj"):
+                params[block]["attn"][name]["kernel"] = params[block]["attn"][name]["kernel"] * 5.5
+        bt, mb, ring = self.BLOCK, 8192 // self.BLOCK, window_ring_blocks(self.WINDOW, self.BLOCK)
+        assert ring == 257
+        paged = served.for_paged_decoding(num_blocks=1 + 3 * mb, block_tokens=bt, window_num_blocks=1 + 3 * ring)
+        tables = jnp.asarray(1 + np.arange(3 * mb).reshape(3, mb), jnp.int32)
+        rings = jnp.asarray(1 + np.arange(3 * ring).reshape(3, ring), jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            full = jax.jit(lambda i: dense.apply({"params": params}, i, deterministic=True))
+            want = np.stack([np.asarray(full(jnp.asarray(ids[r : r + 1])))[0] for r in range(3)])  # (3, total, vocab)
+            tol = 2e-4 * float(np.abs(want).max())
+            call = jax.jit(lambda c, tok, pos, table, ring_, n: paged.apply(
+                {"params": params, "cache": c}, tok, positions=pos, block_tables=table, window_tables=ring_,
+                true_len=n, mutable=["cache"]))
+            shapes = jax.eval_shape(lambda: paged.init(
+                jax.random.key(0), jnp.zeros((1, 1), jnp.int32), positions=jnp.zeros((1,), jnp.int32),
+                block_tables=tables[:1], window_tables=rings[:1]))["cache"]
+            cache = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+            assert cache["block_0"]["attn"]["window_key"].shape[0] == 1 + 3 * ring
+            for r, (n, bucket) in enumerate(self.DEPTHS):  # each prompt alone, padded to its bucket
+                slab = np.zeros((1, bucket), np.int32)
+                slab[0, :n] = ids[r, :n]
+                logits, mutated = call(cache, jnp.asarray(slab), jnp.zeros((1,), jnp.int32), tables[r : r + 1],
+                                       rings[r : r + 1], jnp.asarray([n], jnp.int32))
+                cache = mutated["cache"]
+                assert np.abs(np.asarray(logits)[0, 0] - want[r, n - 1]).max() <= tol, (r, "prefill")
+
+            def decode(cache, rings_):
+                worst = 0.0
+                for step in range(self.STEPS):  # row 1 leaves the window at 4,096 and wraps its ring at 4,112
+                    pos = jnp.asarray([n + step for n, _ in self.DEPTHS], jnp.int32)
+                    tok = jnp.asarray([[ids[r, n + step]] for r, (n, _) in enumerate(self.DEPTHS)], jnp.int32)
+                    logits, mutated = call(cache, tok, pos, tables, rings_, None)
+                    cache = mutated["cache"]
+                    got = np.asarray(logits)[:, 0]
+                    worst = max(worst, max(
+                        float(np.abs(got[r] - want[r, n + step]).max()) for r, (n, _) in enumerate(self.DEPTHS)))
+                return worst
+
+            sound, rotated = decode(cache, rings), decode(cache, jnp.roll(rings, 1, axis=1))
+        print(f"window ring on {jax.default_backend()}: sound {sound:.3g}, rotated {rotated:.3g}, tolerance {tol:.3g}")
+        assert sound <= tol, (sound, tol)
+        assert rotated > 20 * tol, (rotated, tol)  # the comparison sees a ring read one entry off
