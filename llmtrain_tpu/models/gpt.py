@@ -65,6 +65,56 @@ def _scaled_init(n_layers: int) -> nn.initializers.Initializer:
     return nn.initializers.normal(stddev=0.02 / math.sqrt(2 * n_layers))
 
 
+@jax.custom_vjp
+def gelu_once(h: jax.Array) -> jax.Array:
+    """The dense MLP's activation, exact (erf) GELU, which under a gradient
+    is evaluated ONCE an element.
+
+    Written plainly between two matmuls, ``nn.gelu`` looks cheap to the
+    compiler, which keeps only the pre-activation ``h`` and puts the whole
+    erf polynomial back into each consumer: the input of ``mlp_proj``'s
+    forward product, the input of its dW product, and (with the density's
+    ``exp``) the derivative in the dX product. Each of those three fusions
+    is then bound by the vector unit at about 2.8 times its matmul's time
+    (``docs/perf.md``, "An activation between two matmuls").
+
+    Not differentiated (prefill, decode, eval) this is ``nn.gelu`` itself,
+    so those programs stay what they were.
+
+    Reverse mode only: a ``jax.custom_vjp`` has no forward-mode rule, so
+    ``jax.jvp``, ``jacfwd``, ``hessian`` and ``linearize`` through a dense
+    MLP raise ``TypeError``. Nothing in the package differentiates a model
+    forwards; whoever needs to can patch ``nn.gelu`` back in, as the parity
+    tests do.
+    """
+    return nn.gelu(h, approximate=False)
+
+
+def _gelu_once_fwd(h: jax.Array):
+    # One erf, in float32 whatever the compute dtype, gives both the value
+    # and the derivative Phi(h) + h phi(h); each is rounded once. The barrier
+    # makes the pair arrays in memory, so that no consumer recomputes them
+    # from ``h``; the compiler places the evaluation in ``mlp_fc``'s own
+    # fusion (tests/test_tpu_aot_compile.py::TestMLPActivationEvaluatedOnce).
+    # ``erf`` and not the primal's ``erfc``: a float32 ``erf`` reaches the
+    # TPU back end as one instruction, where ``erfc`` is expanded early into
+    # a two-branch polynomial the compiler then splits over two fusions with
+    # a float32 array between them.
+    h32 = h.astype(jnp.float32)
+    cdf = 0.5 * (1.0 + jax.lax.erf(h32 * math.sqrt(0.5)))
+    pdf = jnp.exp(-0.5 * h32 * h32) * (1.0 / math.sqrt(2.0 * math.pi))
+    return jax.lax.optimization_barrier(
+        ((h32 * cdf).astype(h.dtype), (cdf + h32 * pdf).astype(h.dtype))
+    )
+
+
+def _gelu_once_bwd(g: jax.Array, da: jax.Array):
+    return (da * g,)
+
+
+gelu_once.defvjp(_gelu_once_fwd, _gelu_once_bwd)
+
+
 class RowsDenseGeneral(nn.Module):
     """``nn.DenseGeneral`` over trailing input axes with the product formed
     as ONE matrix product: the same parameters (``kernel`` of shape
@@ -1062,7 +1112,7 @@ class TransformerBlock(nn.Module):
                 name="mlp_fc",
             )(h)
             h = nn.with_logical_constraint(h, ("batch", "length", "act_mlp"))
-            h = nn.gelu(h, approximate=False)
+            h = gelu_once(h)
             h = nn.Dense(
                 self.d_model,
                 dtype=self.dtype,

@@ -33,7 +33,7 @@ from flax import linen as nn
 from ..config.schemas import RunConfig
 from ..registry.models import register_model
 from .base import ModelAdapter, Params, lm_loss_components
-from .gpt import dense_attention
+from .gpt import dense_attention, gelu_once
 
 _INIT_STD = 0.02
 
@@ -119,7 +119,10 @@ def make_block_apply(
 
         hn = _layernorm(h, p["ln2_scale"], p["ln2_bias"])
         m = hn.astype(dtype) @ p["fc_kernel"].astype(dtype) + p["fc_bias"].astype(dtype)
-        m = nn.gelu(m, approximate=False)
+        # As in models/gpt.py's block: one erf evaluation an element under a
+        # gradient (a stage's scan body is differentiated, and rematerialised,
+        # like any other), ``nn.gelu`` itself where none is taken.
+        m = gelu_once(m)
         mlp = m @ p["proj_kernel"].astype(dtype)
         if tp_axis is not None:
             mlp = jax.lax.psum(mlp, tp_axis)

@@ -456,3 +456,151 @@ class TestGroupedQueryAttention:
             max_steps_override=1
         )
         assert result.final_step == 1
+
+
+class TestMLPActivationEvaluatedOnce:
+    """Under a gradient the dense MLP's erf GELU goes through
+    ``gelu_once``: one float32 evaluation leaves the value and the
+    derivative, each rounded once to the compute dtype, and the backward
+    multiplies. Against ``jax.grad`` of the plain ``nn.gelu(h,
+    approximate=False)`` composition, which is what the block was."""
+
+    D, FF, B, T = 32, 128, 2, 16
+
+    @classmethod
+    def _block(cls, dtype, **module):
+        from llmtrain_tpu.models.gpt import TransformerBlock
+
+        return TransformerBlock(
+            d_model=cls.D, n_heads=4, d_ff=cls.FF, n_layers=2, dropout=0.0,
+            dtype=dtype, param_dtype=jnp.float32, **module,
+        )
+
+    @classmethod
+    def _operands(cls, block, dtype):
+        import flax.linen as nn
+
+        keys = jax.random.split(jax.random.key(3), 4)
+        # Inputs and weights wide enough that pre-activations reach both tails.
+        x = (2.0 * jax.random.normal(keys[0], (cls.B, cls.T, cls.D))).astype(dtype)
+        weight = jax.random.normal(keys[1], (cls.B, cls.T, cls.D)).astype(dtype)
+        params = nn.meta.unbox(block.init(keys[2], x))
+        params = jax.tree.map(
+            lambda leaf: 8.0 * leaf if leaf.ndim == 2 else leaf + 0.1, params
+        )
+        return params, x, weight, keys[3]
+
+    @pytest.mark.parametrize(
+        "dtype,case",
+        [
+            (dtype, case)
+            for dtype in (jnp.float32, jnp.bfloat16)
+            for case in ("plain", "remat", "lora", "int8", "int8_act", "fp8")
+        ],
+        ids=lambda v: v if isinstance(v, str) else jnp.dtype(v).name,
+    )
+    def test_value_and_gradients_match_the_plain_composition(self, monkeypatch, dtype, case):
+        import flax.linen as nn
+
+        from llmtrain_tpu.models import gpt
+        from llmtrain_tpu.models.lora import LoraSpec, init_lora, merge_lora
+
+        module = {"matmul_precision": case} if case in ("int8", "int8_act", "fp8") else {}
+        block = self._block(dtype, **module)
+        params, x, weight, rng = self._operands(block, dtype)
+        spec = LoraSpec(rank=4, alpha=8.0, targets=("mlp_fc", "mlp_proj"))
+        if case == "lora":
+            factors = init_lora(params, spec, rng)
+            # A zero ``b`` would make every factor gradient but b's vanish.
+            factors = jax.tree.map(lambda leaf: leaf + 0.05, factors)
+            trained = factors
+        else:
+            trained = params["params"]
+
+        def apply(p, x):
+            return block.apply(p, x, deterministic=False)
+
+        if case == "remat":
+            apply = jax.checkpoint(apply)
+
+        def loss(trained, x):
+            p = merge_lora(params, trained, spec, freeze_base=True) if case == "lora" else {"params": trained}
+            y = apply(p, x).astype(jnp.float32)
+            return jnp.sum(y * y * weight.astype(jnp.float32)) / x.size, y
+
+        def run():
+            """Loss, the forward the gradient ran, gradients, the inference forward."""
+            (value, out), grads = jax.jit(
+                jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+            )(trained, x)
+            return value, out, grads, jax.jit(lambda trained, x: loss(trained, x)[1])(trained, x)
+
+        value, out, grads, inference = run()
+        monkeypatch.setattr(gpt, "gelu_once", lambda h: nn.gelu(h, approximate=False))
+        ref_value, _, ref_grads, ref_inference = run()
+
+        f32 = dtype == jnp.float32
+        # float32: the two differ by erf against erfc, a few units in the last
+        # place. bf16: by the plain form's roundings of erfc's argument and
+        # result and of the derivative's parts, each to 8 bits, where the new
+        # form rounds `a` and `g` once.
+        tol = 1e-6 if f32 else 2e-2
+        np.testing.assert_allclose(float(value), float(ref_value), rtol=tol)
+        flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+        ref_flat = jax.tree.leaves(ref_grads)
+        assert len(flat) == len(ref_flat)
+        mlp_leaves = 0
+        for (path, got), want in zip(flat, ref_flat):
+            got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+            assert np.isfinite(got).all(), path
+            gap = np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30)
+            assert gap <= tol, (jax.tree_util.keystr(path), gap)
+            mlp_leaves += "mlp_" in jax.tree_util.keystr(path) and np.linalg.norm(want) > 0
+        assert mlp_leaves >= 2  # mlp_fc and mlp_proj: kernels (and biases), or their factors
+        # Not under a gradient the block is the plain composition, bit for
+        # bit; the forward the gradient ran agrees with it up to erf against
+        # erfc in float32 and up to the roundings above in bf16.
+        out, inference = np.asarray(out, np.float32), np.asarray(inference, np.float32)
+        np.testing.assert_array_equal(inference, np.asarray(ref_inference, np.float32))
+        scale = np.abs(inference).max()
+        assert np.abs(out - inference).max() <= (1e-6 if f32 else 2.0**-7) * scale
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=lambda d: jnp.dtype(d).name)
+    def test_one_rounding_of_value_and_derivative(self, dtype):
+        """``a`` and ``g`` are the float64 GELU and its derivative rounded
+        once: within half a unit in the last place of the compute dtype (a
+        whole one allows for erf's own last place), over both tails."""
+        import math
+
+        from llmtrain_tpu.models.gpt import gelu_once
+
+        h = jnp.linspace(-9.0, 9.0, 4001).astype(dtype)
+        a, vjp = jax.vjp(gelu_once, h)
+        (g,) = vjp(jnp.ones_like(h))
+        h64 = np.asarray(h, np.float64)
+        cdf = 0.5 * np.vectorize(math.erfc)(-h64 / math.sqrt(2.0))
+        want_a = h64 * cdf
+        want_g = cdf + h64 * np.exp(-0.5 * h64 * h64) / math.sqrt(2.0 * math.pi)
+        eps = float(jnp.finfo(dtype).eps)
+        for got, want in ((a, want_a), (g, want_g)):
+            assert got.dtype == dtype
+            # 1 + erf loses the far lower tail's last places: an absolute
+            # term of one float32 unit at 1 beside the relative one.
+            err = np.abs(np.asarray(got, np.float64) - want)
+            assert (err <= eps * np.abs(want) + 2.0**-23 * np.maximum(1.0, np.abs(h64))).all()
+        # Not under a gradient it is nn.gelu itself.
+        np.testing.assert_array_equal(
+            np.asarray(gelu_once(h), np.float32),
+            np.asarray(jax.nn.gelu(h, approximate=False), np.float32),
+        )
+
+    def test_forward_mode_is_refused(self):
+        """A ``custom_vjp`` has no forward-mode rule: ``jvp`` (and what is
+        built on it: ``jacfwd``, ``hessian``, ``linearize``) raises, as the
+        docstring says, and does not silently differentiate something else."""
+        from llmtrain_tpu.models.gpt import gelu_once
+
+        h = jnp.linspace(-2.0, 2.0, 8)
+        with pytest.raises(TypeError, match="custom_vjp"):
+            jax.jvp(gelu_once, (h,), (jnp.ones_like(h),))
+

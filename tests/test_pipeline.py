@@ -462,6 +462,80 @@ class TestPipelineGPT:
         assert result.final_loss < result.first_step_loss
 
 
+class TestStageMLPActivationEvaluatedOnce:
+    """A stage's MLP goes through ``models/gpt.py:gelu_once`` like the
+    ``gpt`` block's: under a gradient the value and the derivative come
+    from one float32 erf. Against the same model with the plain
+    ``nn.gelu(m, approximate=False)`` the stage was written with: on one
+    device (the rematerialised ``scan``), through the ``shard_map`` ring,
+    and with the tensor-parallel shards inside a stage."""
+
+    MESHES = {
+        "scan": None,
+        "ring": {"pipeline": 4, "data": 2},
+        "ring_tp": {"pipeline": 2, "tensor": 2, "data": 2},
+    }
+
+    def setup_method(self):
+        initialize_registries()
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("layout", list(MESHES))
+    def test_value_and_gradients_match_the_plain_stage(self, monkeypatch, layout, dtype):
+        import contextlib
+
+        import flax.linen as nn
+
+        from llmtrain_tpu.models import gpt_pipeline
+
+        axes = self.MESHES[layout] or {"pipeline": 4, "data": 2}
+        cfg = _pp_cfg(
+            model={"dtype": dtype, "param_dtype": "float32"},
+            distributed={"enabled": False, "mesh": axes},
+        )
+        adapter = gpt_pipeline.PipelineGPTAdapter()
+        model = adapter.build_model(cfg)
+        params = adapter.init_params(model, cfg, jax.random.key(0))
+        # Pre-activations that reach both tails, as a trained model's do.
+        params = jax.tree_util.tree_map_with_path(
+            lambda path, leaf: 40.0 * leaf if "fc_kernel" in jax.tree_util.keystr(path) else leaf, params
+        )
+        tokens = jax.random.randint(jax.random.key(2), (8, 16), 0, 32)
+        batch = {"input_ids": tokens, "labels": tokens, "attention_mask": jnp.ones_like(tokens)}
+
+        def loss(p):
+            ls, tk = adapter.compute_loss_components(model, p, batch)
+            return jnp.sum(ls) / jnp.sum(tk)
+
+        def run():
+            mesh = contextlib.nullcontext()
+            if self.MESHES[layout]:
+                mesh = Mesh(np.array(jax.devices()[:8]).reshape(*axes.values()), tuple(axes))
+            with mesh:
+                return jax.jit(jax.value_and_grad(loss))(params), jax.jit(loss)(params)
+
+        (value, grads), inference = run()
+        # The gradient's program holds the forward rule's barrier; the plain one none.
+        assert "optimization_barrier" in str(jax.make_jaxpr(jax.grad(loss))(params))
+        monkeypatch.setattr(gpt_pipeline, "gelu_once", lambda h: nn.gelu(h, approximate=False))
+        (ref_value, ref_grads), ref_inference = run()
+        assert "optimization_barrier" not in str(jax.make_jaxpr(jax.grad(loss))(params))
+
+        f32 = dtype == "float32"
+        tol = 1e-5 if f32 else 2e-2
+        # No gradient taken: the plain stage, bit for bit.
+        assert float(inference) == float(ref_inference)
+        np.testing.assert_allclose(float(value), float(ref_value), rtol=tol)
+        np.testing.assert_allclose(float(value), float(inference), rtol=1e-6 if f32 else 2.0**-7)
+        for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(grads), jax.tree.leaves(ref_grads), strict=True
+        ):
+            got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+            assert np.isfinite(got).all(), path
+            gap = np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30)
+            assert gap <= tol, (jax.tree_util.keystr(path), gap)
+
+
 class TestInterleavedSchedule:
     """virtual_chunks > 1: the Megatron-style interleaved schedule, where
     each stage holds strided layer chunks and microbatches loop the ring

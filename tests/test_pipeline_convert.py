@@ -64,6 +64,35 @@ class TestConversion:
         b = gpt.apply({"params": converted}, ids, deterministic=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=lambda d: jnp.dtype(d).name)
+    def test_converted_params_train_gpt_with_the_same_gradients(self, dtype):
+        """The two modules share one activation (``gelu_once``), so the
+        forward a gradient runs and the gradients agree as the inference
+        logits do: a pipeline checkpoint goes on training as ``gpt``."""
+        pipe, params = _pipeline_params()
+        pipe = pipe.clone(dtype=dtype, remat=False)
+        gpt = GPT(dropout=0.0, dtype=dtype, **DIMS)
+        ids = jnp.asarray(np.random.default_rng(5).integers(0, 64, (2, 16)), jnp.int32)
+
+        def loss(logits):
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32))
+            return -jnp.mean(jnp.take_along_axis(logp, ids[..., None], axis=-1))
+
+        value, grads = jax.value_and_grad(lambda p: loss(pipe.apply({"params": p}, ids)))(params)
+        ref_value, ref_grads = jax.value_and_grad(
+            lambda p: loss(gpt.apply({"params": p}, ids, deterministic=False))
+        )(pipeline_params_to_gpt(params))
+        tol = 1e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(float(value), float(ref_value), rtol=tol)
+        for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(pipeline_params_to_gpt(grads)),
+            jax.tree.leaves(ref_grads),
+            strict=True,
+        ):
+            got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+            gap = np.linalg.norm(got - want) / max(np.linalg.norm(want), 1e-30)
+            assert gap <= tol, (jax.tree_util.keystr(path), gap)
+
     def test_is_pipeline_tree(self):
         _, params = _pipeline_params()
         assert is_pipeline_tree(params)

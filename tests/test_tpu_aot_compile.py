@@ -16,6 +16,7 @@ test file that describes a TPU topology.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 import re
@@ -279,6 +280,201 @@ class TestAttentionBlockHandsOffRows:
             r"flash_attention_fwd[\w.]* = [^\n]*operand_layout_constraints=\{bf16\[16,1024,1152\]", text
         )
         assert re.search(r"flash_attention_bwd_dkdv[\w.]* = bf16\[16,1024,1152\]", text)
+
+
+class TestMLPActivationEvaluatedOnce:
+    """``jax.grad`` through the GPT-2-small block at the train cell's shape
+    (32 x 1,024 x 768, d_ff 3,072, bf16 over float32 parameters): the MLP's
+    erf GELU is evaluated in ONE fusion a block, ``mlp_fc``'s own, which
+    writes the value ``a`` and the derivative ``g``; the three products that
+    read them (``mlp_proj`` forward, its dW, the dX through it) hold no
+    ``erf``, ``divide`` or ``exponential``. Written plainly, as the parent
+    wrote it, the compiler keeps only ``h`` and evaluates the polynomial in
+    all three (36 fusions in the 12-layer model, each bound by the vector
+    unit at about 2.8 times its matmul's time). The inference forward does
+    not change."""
+
+    B, T, D_MODEL, D_FF = 32, 1024, 768, 3072
+    WIDE = "bf16[32,1024,3072]"
+
+    @classmethod
+    @functools.cache  # two tests read the gradient's program: one compile
+    def _block_text(cls, sharding, *, grad: bool, activation=None):
+        import flax.linen as nn
+
+        from llmtrain_tpu.models import gpt
+
+        block = gpt.TransformerBlock(
+            d_model=cls.D_MODEL, n_heads=12, d_ff=cls.D_FF, n_layers=12, dropout=0.0, attention="flash",
+            dtype=jnp.bfloat16, param_dtype=jnp.float32, assume_packed=True,
+        )
+        boxed = jax.eval_shape(
+            lambda: block.init(jax.random.key(0), jnp.zeros((1, 128, cls.D_MODEL), jnp.bfloat16))
+        )
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), nn.meta.unbox(boxed)
+        )
+        x = jax.ShapeDtypeStruct((cls.B, cls.T, cls.D_MODEL), jnp.bfloat16, sharding=sharding)
+
+        def loss(p, x, weight):
+            # Not linear in the block's output, or `mlp_proj`'s forward product is dead code.
+            y = block.apply(p, x, deterministic=False).astype(jnp.float32)
+            return jnp.sum(y * y * weight)
+
+        def forward(p, x):
+            return block.apply(p, x, deterministic=True)
+
+        with pytest.MonkeyPatch.context() as patch:
+            if activation is not None:
+                patch.setattr(gpt, "gelu_once", activation)
+            if grad:
+                return _compile(jax.grad(loss, argnums=(0, 1)), params, x, x)
+            return _compile(forward, params, x)
+
+    @staticmethod
+    def _plain(h):
+        import flax.linen as nn
+
+        return nn.gelu(h, approximate=False)
+
+    @classmethod
+    def _fused_ops(cls, text: str, name: str) -> list[str]:
+        """Opcodes of a fused computation, nested fusions' included."""
+        ops = []
+        for op, _, called, _ in _hlo_instructions(_computation(text, name)):
+            ops.append(op)
+            if op in ("fusion", "call") and called:
+                ops.extend(cls._fused_ops(text, called))
+        return ops
+
+    @classmethod
+    def _matmul_fusions(cls, text: str):
+        """``(line, fused computation's text, opcodes)`` of every fusion,
+        outside fusions, that holds a ``convolution`` (how the chip's compiler
+        writes a matmul) or the erf polynomial."""
+        for _, body in _unfused_computations(text):
+            for op, _, called, line in _hlo_instructions(body):
+                if op == "fusion":
+                    ops = cls._fused_ops(text, called)
+                    if "convolution" in ops or cls._holds_erf(ops):
+                        yield line, _computation(text, called), ops
+
+    @staticmethod
+    def _holds_erf(ops: list[str]) -> bool:
+        # One instruction in float32; expanded to a rational polynomial
+        # (2 divides, about 30 multiplies) when written in bf16 or as erfc.
+        return "erf" in ops or ("divide" in ops and ops.count("multiply") > 20)
+
+    def test_one_fusion_a_block_holds_erf_and_the_three_consumers_are_bare(self, one_chip):
+        text = self._block_text(one_chip, grad=True)
+        fusions = list(self._matmul_fusions(text))
+        with_erf = [fusion for fusion in fusions if self._holds_erf(fusion[2])]
+        assert len(with_erf) == 1, [line.strip()[:200] for line, _, _ in with_erf]
+        line, fused, ops = with_erf[0]
+        # It is `mlp_fc`'s own fusion: the MXU's product hides under the vector unit's pass...
+        assert "mlp_fc/dot_general" in fused
+        assert (ops.count("convolution"), ops.count("erf"), ops.count("exponential")) == (1, 1, 1)
+        # ...and writes `a` and `g`, and neither `h` nor anything in float32.
+        result = line.split(" = ", 1)[1].split(" fusion(", 1)[0]
+        assert re.findall(r"\w+\[[\d,]*\]", result) == [self.WIDE] * 2, result
+        # The three products that read `a` or `g`: `mlp_proj` forward, its dW, the dX through it.
+        readers = [(line, ops) for line, fused, ops in fusions if "mlp_proj/dot_general" in fused]
+        assert len(readers) == 3
+        for line, ops in readers:
+            assert not {"erf", "divide", "exponential"} & set(ops), line.strip()[:200]
+            assert ops.count("multiply") <= 3, line.strip()[:200]
+
+    def test_two_wide_arrays_cross_to_the_backward_and_neither_is_the_preactivation(self, one_chip):
+        text = self._block_text(one_chip, grad=True)
+        # Every array of the hidden width that exists in memory (outside fusions)...
+        wide = [
+            (op, called, line) for _, body in _unfused_computations(text)
+            for op, result, called, line in _hlo_instructions(body)
+            if op not in ("get-tuple-element", "bitcast", "parameter", "tuple")
+            for _ in re.findall(re.escape(self.WIDE), result)
+        ]
+        forward = [(op, called, line) for op, called, line in wide if "transpose(jvp" not in line]
+        backward = [(op, called, line) for op, called, line in wide if "transpose(jvp" in line]
+        # ...is one of the forward's two, both outputs of the one fusion with erf, or the backward's dh.
+        assert len(forward) == 2 and len({called for _, called, _ in forward}) == 1
+        assert len(backward) == 1
+        ops = self._fused_ops(text, forward[0][1])
+        assert "erf" in ops
+        # `h` is the convolution plus its bias: each output is a value formed AFTER erf.
+        body = _computation(text, forward[0][1])
+        root = re.search(r"ROOT %[\w.\-]+ = [^\n]* tuple\(([^)]*)\)", body).group(1)
+        operands = {
+            name: re.findall(r"%([\w.\-]+)", line.split(" = ", 1)[1].split("(", 1)[1])
+            for name, line in (
+                (line.split(" = ", 1)[0].strip().removeprefix("ROOT ").lstrip("%"), line)
+                for line in body.splitlines()[1:] if " = " in line
+            )
+        }
+        erf = next(name for name in operands if re.match(r"erf[.\d]*$", name))
+
+        def reaches(name, seen):
+            if name == erf:
+                return True
+            seen.add(name)
+            return any(reaches(o, seen) for o in operands.get(name, []) if o not in seen)
+
+        for out in re.findall(r"%([\w.\-]+)", root):
+            assert reaches(out, set()), f"{out} does not come after erf: is it h?"
+
+    def test_the_plain_composition_evaluates_it_in_three_fusions(self, one_chip):
+        """The yardstick of the test above: the parent's block, by the same count."""
+        text = self._block_text(one_chip, grad=True, activation=self._plain)
+        with_erf = [ops for _, _, ops in self._matmul_fusions(text) if self._holds_erf(ops)]
+        assert len(with_erf) == 3 and all("convolution" in ops for ops in with_erf)
+
+    def test_a_pipeline_stage_evaluates_it_in_its_fc_product_too(self, one_chip):
+        """``gpt_pipeline``'s stage (a ``scan`` over stacked layers) runs the
+        same ``gelu_once``: one fusion of the forward loop's body holds erf,
+        with the ``fc`` product, and writes ``a`` and ``g`` for the stack of
+        residuals; the backward loop's body holds none. (Plain, a scan already
+        keeps value and derivative, since its residuals are arrays, but from a
+        stand-alone pass over the expanded ``erfc`` with two exponentials.)"""
+        from llmtrain_tpu.models.gpt_pipeline import make_stage_fn
+
+        layers, heads = 2, 12
+
+        def leaf(*shape, dtype=jnp.float32):
+            return jax.ShapeDtypeStruct((layers, *shape), dtype, sharding=one_chip)
+
+        d, ff = self.D_MODEL, self.D_FF
+        params = {
+            "ln1_scale": leaf(d), "ln1_bias": leaf(d), "ln2_scale": leaf(d), "ln2_bias": leaf(d),
+            "qkv_kernel": leaf(d, 3, heads, d // heads), "qkv_bias": leaf(3, heads, d // heads),
+            "out_kernel": leaf(heads, d // heads, d), "out_bias": leaf(d),
+            "fc_kernel": leaf(d, ff), "fc_bias": leaf(ff), "proj_kernel": leaf(ff, d), "proj_bias": leaf(d),
+        }
+        x = jax.ShapeDtypeStruct((self.B, self.T, d), jnp.bfloat16, sharding=one_chip)
+        stage = make_stage_fn(attention="flash", dtype=jnp.bfloat16)
+
+        def loss(p, x, weight):
+            y = stage(p, x).astype(jnp.float32)
+            return jnp.sum(y * y * weight)
+
+        text = _compile(jax.grad(loss, argnums=(0, 1)), params, x, x)
+        with_erf = [fusion for fusion in self._matmul_fusions(text) if self._holds_erf(fusion[2])]
+        assert len(with_erf) == 1, [line.strip()[:200] for line, _, _ in with_erf]
+        line, _, ops = with_erf[0]
+        assert (ops.count("convolution"), ops.count("erf"), ops.count("exponential")) == (1, 1, 1)
+        result = line.split(" = ", 1)[1].split(" fusion(", 1)[0]
+        assert re.findall(r"\w+\[[\d,]*\]", result) == [self.WIDE] * 2, result
+
+    def test_the_inference_forward_is_the_plain_compositions_program(self, one_chip):
+        """PR 26's digest: ``metadata={...}`` dropped, the instruction list hashed."""
+        import hashlib
+
+        def digest(text):
+            # Instructions only: the module's header tables name the traced Python functions.
+            text = re.sub(r", metadata=\{[^{}]*\}", "", text)
+            return hashlib.sha256("\n".join(line for *_, line in _hlo_instructions(text)).encode()).hexdigest()
+
+        served = self._block_text(one_chip, grad=False)
+        assert " erf(" not in served and "opt-barrier" not in served
+        assert digest(served) == digest(self._block_text(one_chip, grad=False, activation=self._plain))
 
 
 class TestFusedCEOneChip:
