@@ -52,7 +52,15 @@ from flax import linen as nn
 from ..config.schemas import RunConfig
 from ..ops.ssd import ssd_chunked_scan, ssd_step, ssm_conv
 from ..registry.models import register_model
-from .gpt import _DENSE_INIT, _EMBED_INIT, CausalSelfAttention, GPTAdapter, _scaled_init, scaled
+from .gpt import (
+    _DENSE_INIT,
+    _EMBED_INIT,
+    CausalSelfAttention,
+    GPTAdapter,
+    _scaled_init,
+    model_paged_kv_form,
+    scaled,
+)
 from .llama import RMSNorm, gated_mlp
 
 _VEC = ("norm",)  # logical axis of every small vector: replicated
@@ -418,6 +426,11 @@ class FalconH1(nn.Module):
         """Positions one chunk of the prefill scan covers (the engine counts
         ``scan_chunks`` of a prefill call with it)."""
         return self.mamba_chunk_size
+
+    def paged_kv_form(self, *, t: int) -> str:
+        """The form of the paged read a call of ``t`` tokens a row runs: the
+        engine's ``kv_form`` (models/gpt.py ``model_paged_kv_form``)."""
+        return model_paged_kv_form(self, t=t)
 
     def for_paged_decoding(
         self, *, num_blocks: int, block_tokens: int, state_rows: int = 0

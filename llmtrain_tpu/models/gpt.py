@@ -302,6 +302,54 @@ def paged_block_fold(block_tokens: int, width: int) -> int:
     return block_tokens
 
 
+# Query rows a gathered position may meet in the flat-row form of the
+# paged read (``paged_kv_form``): t x heads of one call.
+PAGED_ROWS_QUERY_LIMIT = 256
+
+
+def paged_kv_form(*, t: int, n_heads: int, kv_heads: int, head_dim: int, block_tokens: int) -> str:
+    """Which form ``_paged_decode_attention`` contracts the gathered blocks
+    in, from the call's static shapes alone: ``"rows"`` or ``"heads"``.
+
+    ``"rows"``: the gathered blocks stay the pool's own rows, ``(batch,
+    gathered, kv_heads * head_dim)``, and every query head is spread over
+    the row with zeros off its K/V head's lanes. Nothing of the gathered
+    leaf's size is written again, at ``kv_heads`` times the products'
+    FLOPs. ``"heads"``: the gathered blocks are re-tiled ``(batch,
+    gathered, kv_heads, head_dim)`` for a per-head product; with
+    ``kv_heads`` on the sublanes and a head narrower than a lane tile the
+    compiler writes the leaf again at up to 2.67 times its size
+    (``gpt2-small``'s 12 heads of 64) before any score is formed.
+
+    What the re-tile costs is bytes a gathered position, whatever ``t``;
+    what the zeros cost is MXU passes a gathered position, ``t * n_heads``
+    query rows against each row of keys. A decode call (t = 1, every
+    slot's whole table) is bound by the bytes; a prefill or chunk call
+    (one prompt of hundreds of positions) by the passes, and its re-tile
+    is one row's table. The switch is ``PAGED_ROWS_QUERY_LIMIT`` query
+    rows; the batch and the table's width scale both costs alike (PERF.md
+    section 6, PR 47, has the chip's readings on both sides). Where a pool
+    row holds several positions (``paged_block_fold`` > 1: a row under one
+    lane tile) the gathered blocks are not ``(batch, gathered, width)``
+    for free, and the per-head form stays.
+    """
+    if paged_block_fold(block_tokens, kv_heads * head_dim) > 1:
+        return "heads"
+    return "rows" if t * n_heads <= PAGED_ROWS_QUERY_LIMIT else "heads"
+
+
+def model_paged_kv_form(model: Any, *, t: int) -> str:
+    """``paged_kv_form`` of a call of ``t`` tokens a row of a model whose
+    attention layers are ``CausalSelfAttention`` (GPT, Llama, Falcon-H1),
+    from the model's own fields: what serving/engine.py writes on a decode
+    call's ``serve/engine.stage`` span as ``kv_form``."""
+    return paged_kv_form(
+        t=t, n_heads=model.n_heads, kv_heads=model.n_kv_heads or model.n_heads,
+        head_dim=getattr(model, "head_dim", 0) or model.d_model // model.n_heads,
+        block_tokens=model.paged_block_tokens,
+    )
+
+
 def paged_pool_writer(pos: jax.Array, block_tables: jax.Array, block_tokens: int, width: int):
     """``write(pool, rows)``: the ``(B, t, width)`` rows of this call's
     tokens, at absolute positions ``pos`` (B, t), set into a pool leaf
@@ -856,9 +904,20 @@ class CausalSelfAttention(nn.Module):
         the block-table gather and the engine's COW copy use, and the
         donated input aliases the output in it
         (``tests/test_tpu_aot_compile.py`` holds that at the benchmark's
-        pool shapes). The gathered blocks are still re-tiled per head for
-        the score einsum; that is a relayout of what was gathered, not of
-        the pool.
+        pool shapes).
+
+        **The read.** ``pool[block_tables]`` is ``(B, blocks, bt // fold,
+        fold * width)``. :func:`paged_kv_form` picks, from the call's
+        static shapes, how q meets it. A decode or verify call
+        (``"rows"``) sees it as ``(B, s, width)``, which moves nothing,
+        and spreads each query head over the row; no array of the
+        gathered leaf's size is written again
+        (``tests/test_tpu_aot_compile.py::
+        TestDecodeReadsGatheredBlocksAsRows``). A prefill or chunk call,
+        and any call on a pool whose rows fold positions, re-tiles what it
+        gathered per head (``"heads"``): a relayout of one row's table.
+        Both give the same sums: float32 accumulation, the same roundings
+        of scores and ``probs``.
         """
         if positions is None or block_tables is None:
             raise ValueError(
@@ -908,20 +967,40 @@ class CausalSelfAttention(nn.Module):
             )
 
         s = block_tables.shape[1] * bt
+        scale = 1.0 / math.sqrt(head_dim)
+        g = n_heads // kv_width  # grouped-query read, like the linear path
+        big_neg = jnp.finfo(jnp.float32).min
+        form = paged_kv_form(t=t, n_heads=n_heads, kv_heads=kv_width, head_dim=head_dim, block_tokens=bt)
+        if form == "rows":
+            # The gathered blocks as the pool's own rows: (B, blocks, bt,
+            # width) -> (B, s, width) moves nothing. Head h's query sits on
+            # the lanes of its K/V head with zeros on the others, so a
+            # product over the whole row adds nothing to its score, and of
+            # the value product's row it keeps its own head's lanes.
+            keys = paged_key.value[block_tables].reshape(batch, s, width)
+            values = paged_value.value[block_tables].reshape(batch, s, width)
+            own = (jnp.arange(n_heads) // g)[:, None, None] == jnp.arange(kv_width)[:, None]
+            q_rows = jnp.where(own, q[:, :, :, None, :], 0).reshape(batch, t, n_heads, width)
+            scores = jnp.einsum("bqhw,bsw->bhqs", q_rows, keys) * scale
+            scores = scores.astype(jnp.float32)
+            # Logical slot index IS the absolute position (block i covers
+            # positions [i*bt, (i+1)*bt)): causal liveness is col <= row.
+            live = jnp.arange(s)[None, None, None, :] <= pos[:, None, :, None]
+            probs = jax.nn.softmax(jnp.where(live, scores, big_neg), axis=-1).astype(q.dtype)
+            out_rows = jnp.einsum("bhqs,bsw->bqhw", probs, values)
+            out_rows = out_rows.reshape(batch, t, n_heads, kv_width, head_dim)
+            return jnp.where(own, out_rows, 0).sum(axis=3)
         keys = paged_key.value[block_tables].reshape(batch, s, kv_width, head_dim)
         values = paged_value.value[block_tables].reshape(
             batch, s, kv_width, head_dim
         )
-        scale = 1.0 / math.sqrt(head_dim)
-        g = n_heads // kv_width  # grouped-query read, like the linear path
         qg = q.reshape(batch, t, kv_width, g, head_dim)
         scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, keys) * scale
         scores = scores.astype(jnp.float32)
-        # Logical slot index IS the absolute position (block i covers
-        # positions [i*bt, (i+1)*bt)): causal liveness is col <= row.
+        # The same liveness rule on the per-head scores (b, k, g, q, s).
         row = pos[:, None, None, :, None]  # (B, 1, 1, t, 1)
         col = jnp.arange(s)[None, None, None, None, :]
-        scores = jnp.where(col <= row, scores, jnp.finfo(jnp.float32).min)
+        scores = jnp.where(col <= row, scores, big_neg)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         out = jnp.einsum("bkgqs,bskd->bqkgd", probs, values)
         return out.reshape(batch, t, n_heads, head_dim)
@@ -1214,6 +1293,11 @@ class GPT(nn.Module):
     # quality-sensitive ends of the stack and a rounding error of the
     # matmul byte budget. Param tree and checkpoints are unchanged.
     matmul_precision: str = "f32"
+
+    def paged_kv_form(self, *, t: int) -> str:
+        """The form of the paged read a call of ``t`` tokens a row runs: the
+        engine's ``kv_form`` (``model_paged_kv_form``)."""
+        return model_paged_kv_form(self, t=t)
 
     def for_paged_decoding(
         self, *, num_blocks: int, block_tokens: int, state_rows: int = 0

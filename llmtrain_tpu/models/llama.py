@@ -42,6 +42,7 @@ from .gpt import (
     CausalSelfAttention,
     GPTAdapter,
     _scaled_init,
+    model_paged_kv_form,
     scaled,
 )
 from .gpt_moe import GPTMoEAdapter as _GPTMoEAdapter
@@ -326,6 +327,11 @@ class Llama(nn.Module):
     capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
     router_top_k: int = 1
+
+    def paged_kv_form(self, *, t: int) -> str:
+        """The form of the paged read a call of ``t`` tokens a row runs: the
+        engine's ``kv_form`` (models/gpt.py ``model_paged_kv_form``)."""
+        return model_paged_kv_form(self, t=t)
 
     def for_paged_decoding(
         self, *, num_blocks: int, block_tokens: int, state_rows: int = 0

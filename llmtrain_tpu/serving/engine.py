@@ -31,7 +31,10 @@ WHEN sequences join/leave; this module owns HOW a step runs):
   naming the program. The ``stage`` span also carries what the call is
   about to waste, counted where the padding happens: ``prompt_tokens`` and
   ``bucket`` of a prefill, ``kv_live_tokens`` and ``kv_gathered_tokens``
-  of a decode. Without a factory nothing is recorded.
+  of a decode, and for a model that declares it (``paged_kv_form``: GPT,
+  Llama, Falcon-H1) ``kv_form``, the form of the paged read the decode
+  program runs (``"rows"`` or ``"heads"``, models/gpt.py). Without a
+  factory nothing is recorded.
 * **Two kinds of cache leaf, told apart in the tree.** A model with a
   recurrent mixer (models/falcon_h1.py) keeps, beside the block pool,
   leaves whose leading axis is a sequence's *state row* (their variable
@@ -460,6 +463,10 @@ class PagedDecodeEngine:
         self._scan_chunk = int(getattr(self.decode_model, "state_scan_chunk", 0))
         self._counts_experts = bool(getattr(self.decode_model, "expert_layers", 0))
         self._selects = int(getattr(self.decode_model, "selects_positions", 0))
+        # The form of the paged read a decode call (one token a row) runs;
+        # None for a model whose attention is its own (no ``kv_form`` on its spans).
+        declared = getattr(self.decode_model, "paged_kv_form", None)
+        self._kv_form = declared(t=1) if declared is not None else None
         # Raises by name on prefix_cache with state rows (paged_kv.py).
         self.pool = PagedKVPool(
             num_blocks,
@@ -667,6 +674,8 @@ class PagedDecodeEngine:
                 "kv_live_tokens": sum(int(r["position"]) + 1 for r in rows),
                 "kv_gathered_tokens": bb * mb * self.block_tokens,
             }
+            if self._kv_form is not None:
+                counted["kv_form"] = self._kv_form
             if self.state_bytes_per_row:
                 # What the call must move: each real row's state, read and written.
                 counted["state_rows"] = n

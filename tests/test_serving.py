@@ -370,14 +370,15 @@ class TestTickSpanTree:
             {"tick": 1, "parent": "serve/tick", "batch": 2, "param_epoch": 0},
             {"tick": 2, "parent": "serve/tick", "batch": 1, "param_epoch": 0},
         ]
-        # The engine counts the gather where it pads the rows.
+        # The engine counts the gather where it pads the rows. A row of 32
+        # lanes folds four positions, so the per-head form reads it.
         stages = [e["args"] for e in self._named(events, "serve/engine.stage")
                   if e["args"]["call"] == "decode"]
         assert stages == [
             {"tick": 1, "parent": "serve/decode", "call": "decode",
-             "kv_live_tokens": 11, "kv_gathered_tokens": 32},
+             "kv_live_tokens": 11, "kv_gathered_tokens": 32, "kv_form": "heads"},
             {"tick": 2, "parent": "serve/decode", "call": "decode",
-             "kv_live_tokens": 5, "kv_gathered_tokens": 16},
+             "kv_live_tokens": 5, "kv_gathered_tokens": 16, "kv_form": "heads"},
         ]
 
     def test_prefills_nest_in_admit_and_their_calls_count_the_bucket(self, events):

@@ -985,7 +985,12 @@ class TestModelsWithoutState:
         ]
         stage = [a for n, a in spans if n == "serve/engine.stage"]
         assert {k for a in stage for k in a} == {
-            "call", "prompt_tokens", "bucket", "kv_live_tokens", "kv_gathered_tokens"
+            "call", "prompt_tokens", "bucket", "kv_live_tokens", "kv_gathered_tokens", "kv_form"
+        }
+        # The form of the paged read a decode call ran: a row of 32 lanes
+        # folds four positions; a row of 128 is read as it lies.
+        assert {a["kv_form"] for a in stage if a["call"] == "decode"} == {
+            {"gpt-row32-fold4": "heads", "llama-gqa-row128": "rows"}[name]
         }
         assert "state_leaves" not in engine.compile_stats() and "state_rows_free" not in engine.pool.stats()
 

@@ -979,6 +979,94 @@ class TestStateRowsUpdateInPlace:
         assert writers == STATE_LAYERS
 
 
+# -- the form of the paged read (models/gpt.py paged_kv_form, PR 47) ---------
+
+RELAYOUTS = {"reshape", "copy", "transpose"}
+# Inside a fusion these move nothing by themselves.
+_FREE = {"parameter", "bitcast", "constant", "tuple", "get-tuple-element"}
+
+
+def _relayouts(text: str, elements: int) -> list[str]:
+    """Every ``reshape``, ``copy`` or ``transpose`` instruction, and every
+    fusion that is only one, whose result holds ``elements`` or more,
+    outside fused computations: an array written again in another tiling."""
+    found = []
+    for _name, body in _unfused_computations(text):
+        for op, result, called, line in _hlo_instructions(body):
+            if _elements(result) < elements:
+                continue
+            if op == "fusion":
+                inner = {o for o, *_ in _hlo_instructions(_computation(text, called))} - _FREE
+                if not inner or not inner <= RELAYOUTS:
+                    continue
+            elif op not in RELAYOUTS:
+                continue
+            found.append(line.strip()[:200])
+    return found
+
+
+def _row_width(spec: dict) -> int:
+    return (spec.get("n_kv_heads") or spec["n_heads"]) * (spec["d_model"] // spec["n_heads"])
+
+
+class TestDecodeReadsGatheredBlocksAsRows:
+    """A decode call contracts q against the gathered K/V blocks in the
+    layout the gather leaves them in (models/gpt.py ``paged_kv_form``,
+    ``"rows"``): nothing as large as one layer's gathered blocks (``slots x
+    1,024 x width``) is reshaped, copied or transposed. The per-head form
+    re-tiled each of a layer's two gathered leaves ``(slots, 1024,
+    kv_heads, 64)``, 2.67 times its size with 12 heads of 64 on the two
+    minor dimensions: 34% and 44% of the two GPT-2 serving cells' device
+    time (PERF.md section 6, PR 47). A prefill call keeps the per-head form
+    (one row's table; ``kv_heads`` times the FLOPs of a slab would cost
+    more) and holds no more such instructions than it did."""
+
+    # Shapes the rule sends the new way; ``llama-mqa`` folds two positions
+    # into a pool row and keeps the per-head form.
+    SHAPES = ("gpt2-small", "gpt2-xl", "llama-gqa")
+    # Instructions of one row's gathered table (``1,024 x width``) or more
+    # in the prefill program of 256 positions, at PR 46: the re-tile of K
+    # and of V in each of the two layers; at ``gpt2-xl`` also the copies of
+    # the two embedding tables (1,024 and 2,048 rows of 1,600) into fast
+    # memory.
+    PREFILL_RELAYOUTS = {"gpt2-small": 4, "gpt2-xl": 6, "llama-gqa": 4, "llama-mqa": 4}
+
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_decode_program_holds_no_relayout_of_a_gathered_leaf(self, one_chip, shape):
+        from llmtrain_tpu.models.gpt import paged_kv_form
+
+        spec = POOL_SHAPES[shape]
+        kv_heads = spec.get("n_kv_heads") or spec["n_heads"]
+        assert paged_kv_form(
+            t=1, n_heads=spec["n_heads"], kv_heads=kv_heads, head_dim=spec["d_model"] // spec["n_heads"],
+            block_tokens=POOL_BLOCK_TOKENS,
+        ) == "rows"
+        programs, _leaf = _pool_programs(shape, one_chip)
+        fn, shapes = programs["decode"]
+        text = jax.jit(fn, donate_argnums=(1,)).lower(*shapes).compile().as_text()
+        gathered = spec["slots"] * POOL_CONTEXT * _row_width(spec)
+        assert _relayouts(text, gathered) == []
+        # The gathered blocks are there, as the pool's rows.
+        assert f"bf16[{spec['slots']},{POOL_CONTEXT},{_row_width(spec)}]" in text
+
+    @pytest.mark.parametrize("shape", list(POOL_SHAPES))
+    def test_prefill_program_holds_no_more_relayouts_than_it_did(self, one_chip, shape):
+        programs, _leaf = _pool_programs(shape, one_chip)
+        fn, shapes = programs["prefill"]
+        text = jax.jit(fn, donate_argnums=(1,)).lower(*shapes).compile().as_text()
+        found = _relayouts(text, POOL_CONTEXT * _row_width(POOL_SHAPES[shape]))
+        assert len(found) <= self.PREFILL_RELAYOUTS[shape], found
+
+    def test_falcon_h1_decode_program_holds_no_relayout_of_a_gathered_leaf(self, state_programs):
+        """20 query heads over 4 K/V heads of 128, rows of 512: the same
+        function, the same rule (``falcon-h1-34b.serve-batch``)."""
+        shapes, _state = state_programs
+        fn, args = shapes["decode"]
+        text = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile().as_text()
+        assert _relayouts(text, STATE_SLOTS * POOL_CONTEXT * 512) == []
+        assert f"bf16[{STATE_SLOTS},{POOL_CONTEXT},512]" in text
+
+
 # -- the latent pool and the held experts (models/latent_moe.py, PR 31) -------
 
 LATENT_SLOTS, LATENT_CONTEXT, LATENT_LAYERS = 96, 4096, 2
